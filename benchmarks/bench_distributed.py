@@ -12,10 +12,13 @@ on CPU — the same harness the mesh-parity tests use):
                                      query: O(k·|model|), independent of N
   * distributed/balance            — max/mean per-rank scan bytes (skew)
 
-jax must see the virtual device pool BEFORE first import, so ``main()``
-re-execs this module as a subprocess with XLA_FLAGS set when the current
-process has too few devices, and re-emits the child's CSV rows into the
-suite (BENCH_distributed.json still lands in the parent).
+Under ``JAX_PLATFORMS=cpu`` jax must see the virtual device pool before it
+initializes, so ``main()`` re-execs this module as a subprocess with
+XLA_FLAGS set and re-emits the child's CSV rows into the suite
+(BENCH_distributed.json still lands in the parent). The decision reads only
+the environment: a parent that asked jax for its devices would hold an
+accelerator the child then needs, so anywhere else (a TPU host) the suite
+runs in-process over the devices there are.
 """
 from __future__ import annotations
 
@@ -107,16 +110,14 @@ def _run() -> None:
 
 
 def main() -> None:
-    import jax
-
-    if len(jax.devices()) >= DEVICES:
+    pool_flag = f"--xla_force_host_platform_device_count={DEVICES}"
+    xla_flags = os.environ.get("XLA_FLAGS", "")
+    if os.environ.get("JAX_PLATFORMS") != "cpu" or pool_flag in xla_flags:
         _run()
         return
-    # jax is already initialized single-device: re-exec with the virtual pool
+    # host-only run: re-exec with the virtual pool
     env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") + f" --xla_force_host_platform_device_count={DEVICES}"
-    ).strip()
+    env["XLA_FLAGS"] = f"{xla_flags} {pool_flag}".strip()
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     env.get("PYTHONPATH", "")] if p

@@ -26,6 +26,9 @@ def main() -> None:
         os.environ["REPRO_BENCH_FAST"] = "1"
         args = args or SMOKE_SUITES
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_distributed, bench_engine, bench_fault, bench_fig4_5, bench_fig6,
         bench_fig7, bench_kernels, bench_perf, bench_service, bench_store,

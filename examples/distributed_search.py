@@ -25,6 +25,9 @@ from repro.core.ivf import IVFIndex  # noqa: E402
 from repro.core.planner import batch_search_ivf  # noqa: E402
 from repro.core.predicates import Contains, evaluate_filter, make_filter  # noqa: E402
 from repro.launch.mesh import make_test_mesh  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 n, d, m, k = 64_000, 32, 512, 10

@@ -18,6 +18,9 @@ from repro.store import init_store, list_generations, pinned_generations
 from repro.store.snapshot import current_generation
 from repro.service import ServiceConfig
 from repro.tuner import Tuner, TunerConfig
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

@@ -12,6 +12,9 @@ import numpy as np
 
 from repro.core import PreFilterIndex, exhaustive_search, recall_at_k, tune_nprobe
 from repro.core.workload import lp_style
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 db, workload = lp_style(n=30_000, d=32, n_queries=1_500)
 truth = exhaustive_search(db, workload)

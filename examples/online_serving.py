@@ -13,6 +13,9 @@ from repro.core import (
     Column, Contains, HQIConfig, HQIIndex, NotNull, VectorDatabase, make_filter,
 )
 from repro.service import HQIService, ServiceConfig
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

@@ -8,6 +8,9 @@ from repro.core import (
     Column, Contains, HQIConfig, HQIIndex, NotNull, VectorDatabase, Workload,
     exhaustive_search, make_filter, recall_at_k,
 )
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

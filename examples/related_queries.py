@@ -25,6 +25,9 @@ from repro.models import api
 from repro.models.transformer import lm_hidden_embed
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import TrainConfig, init_train_state, make_train_step
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- 1. train a small LM (the embedding producer) ---------------------------
 cfg = get_reduced("minicpm-2b")

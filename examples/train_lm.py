@@ -18,6 +18,9 @@ from repro.models.transformer import ModelConfig
 from repro.train.fault_tolerance import LoopConfig, TrainLoop
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import TrainConfig
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--tiny", action="store_true")

@@ -58,5 +58,7 @@ from .baselines import (  # noqa: F401
     RangeIndex,
     exhaustive_search,
 )
-from .metrics import per_template_recall, recall_at_k, tune_nprobe  # noqa: F401
+from .metrics import (  # noqa: F401
+    per_template_recall, recall_at_k, results_mismatch, tune_nprobe,
+)
 from .workload import kg_style, lp_style, synthetic_bigann_style  # noqa: F401

@@ -38,13 +38,27 @@ from .types import SearchResult, VectorDatabase, Workload
 # ---------------------------------------------------------------------------
 
 
-def exhaustive_search(db: VectorDatabase, workload: Workload, *, chunk: int = 4096) -> SearchResult:
-    """Exact hybrid search: bitmap per template + full masked scan (jit'd)."""
+# one chunk's [chunk, n] f32 score matrix stays under this many bytes
+SCORE_BUDGET_BYTES = 1 << 30
+
+
+def exhaustive_search(
+    db: VectorDatabase, workload: Workload, *, score_budget_bytes: int = SCORE_BUDGET_BYTES
+) -> SearchResult:
+    """Exact hybrid search: bitmap per template + full masked scan (jit'd).
+
+    Queries are scanned in chunks whose [chunk, n] f32 scores fit
+    ``score_budget_bytes`` (at n = 1M, 268 queries per GiB). A template's
+    chunks are zero-padded to the next power of two of its query count, up
+    to ``chunk``, so the scan compiles for O(log chunk) shapes and pads at
+    most twice the rows it scores.
+    """
     m, k = workload.m, workload.k
     out_s = np.full((m, k), -np.inf, np.float32)
     out_i = np.full((m, k), -1, np.int64)
     scanned = 0
     v = jnp.asarray(db.vectors)
+    chunk = max(1, min(m, score_budget_bytes // (4 * max(db.n, 1))))
     for ti, filt in enumerate(workload.templates):
         qidx = workload.queries_for_template(ti)
         if len(qidx) == 0:
@@ -52,13 +66,16 @@ def exhaustive_search(db: VectorDatabase, workload: Workload, *, chunk: int = 40
         bitmap = evaluate_filter(filt, db)
         scanned += db.n * len(qidx)
         valid = jnp.asarray(bitmap)
-        for s in range(0, len(qidx), chunk):
-            qs = qidx[s : s + chunk]
+        width = min(chunk, 1 << (min(len(qidx), chunk) - 1).bit_length())
+        for s in range(0, len(qidx), width):
+            qs = qidx[s : s + width]
+            q_pad = np.zeros((width, db.d), np.float32)
+            q_pad[: len(qs)] = workload.vectors[qs]
             sc, ix = kops.masked_topk(
-                jnp.asarray(workload.vectors[qs]), v, valid, k, metric=db.metric, use_pallas=False
+                jnp.asarray(q_pad), v, valid, k, metric=db.metric, use_pallas=False
             )
-            out_s[qs] = np.asarray(sc)
-            out_i[qs] = np.asarray(ix).astype(np.int64)
+            out_s[qs] = np.asarray(sc)[: len(qs)]
+            out_i[qs] = np.asarray(ix)[: len(qs)].astype(np.int64)
     return SearchResult(ids=out_i, scores=out_s, tuples_scanned=scanned)
 
 

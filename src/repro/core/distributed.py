@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..distributed.sharding import shard_map_compat
 from ..kernels import ref as kref
 from .arena import ShardedArena
 from .ivf import ScanStats
@@ -167,11 +166,12 @@ def make_roofline_search_step(mesh: Mesh, *, k: int, metric: str = "ip", db_tile
         top_i = jnp.take_along_axis(cat_i, pos, axis=1)
         return top_s, top_i
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P("model"), P(baxes, None)),
         out_specs=(P(baxes, None), P(baxes, None)),
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -21,10 +21,19 @@ from ..kernels import ops as kops
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
-def _assign(vectors: jax.Array, centroids: jax.Array, metric: str) -> jax.Array:
-    """Nearest-centroid assignment. vectors [n,d], centroids [k,d] -> int32[n]."""
-    scores = kops.pairwise_scores(vectors, centroids, metric=metric)  # [n, k] best=max
+def _assign(
+    vectors: jax.Array, centroids: jax.Array, k: jax.Array, metric: str
+) -> jax.Array:
+    """Nearest-centroid assignment among the first ``k`` rows of ``centroids``
+    (the rest are padding and never win). vectors [n,d], centroids [kp,d] ->
+    int32[n]."""
+    scores = _scores_of_first(vectors, centroids, k, metric)  # [n, kp] best=max
     return jnp.argmax(scores, axis=1).astype(jnp.int32)
+
+
+def _scores_of_first(q, centroids, k, metric):
+    scores = kops.pairwise_scores(q, centroids, metric=metric)
+    return jnp.where(jnp.arange(centroids.shape[0]) < k, scores, -jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -36,17 +45,37 @@ def _update(vectors: jax.Array, assign: jax.Array, k: int) -> Tuple[jax.Array, j
     return sums / jnp.maximum(counts, 1.0)[:, None], counts
 
 
+@functools.partial(jax.jit, static_argnames=("m", "metric"))
+def _topm(q: jax.Array, centroids: jax.Array, k: jax.Array, m: int, metric: str) -> jax.Array:
+    return jax.lax.top_k(_scores_of_first(q, centroids, k, metric), m)[1]
+
+
+def _pow2(n: int, lo: int) -> int:
+    return max(lo, 1 << (n - 1).bit_length())
+
+
 def _pow2_pad(x: np.ndarray, lo: int = 256) -> np.ndarray:
     """Pad rows to the next power of two (repeating rows) so the jit'd
 
     k-means steps specialize on O(log n) shapes instead of one per
     partition — index build time is dominated by compiles otherwise."""
     n = x.shape[0]
-    target = max(lo, 1 << (n - 1).bit_length())
+    target = _pow2(n, lo)
     if target == n:
         return x
     reps = np.resize(np.arange(n), target - n)
     return np.concatenate([x, x[reps]], axis=0)
+
+
+def _pad_centroids(centroids: np.ndarray) -> jax.Array:
+    """Centroids zero-padded to a power-of-two count: with ``k`` passed as
+    data, a program serves every partition whose count rounds to the same
+    size (an index build otherwise compiles its k-means and probe programs
+    once per partition)."""
+    k, d = centroids.shape
+    out = np.zeros((_pow2(k, 8), d), np.float32)
+    out[:k] = centroids
+    return jnp.asarray(out)
 
 
 def train_kmeans(
@@ -70,20 +99,21 @@ def train_kmeans(
     # padding with duplicate rows does not change cluster means materially
     # and keeps the jit cache small across many differently-sized partitions
     x = _pow2_pad(np.asarray(x, dtype=np.float32))
-    x = jnp.asarray(x, dtype=jnp.float32)
     # k-means++-lite init: random distinct points.
     init_idx = rng.choice(x.shape[0], size=k, replace=False)
-    centroids = x[jnp.asarray(init_idx)]
+    centroids = _pad_centroids(x[init_idx])
+    kp = centroids.shape[0]
+    x_dev = jnp.asarray(x)
     for _ in range(iters):
-        assign = _assign(x, centroids, metric)
-        centroids, counts = _update(x, assign, k)
+        assign = _assign(x_dev, centroids, k, metric)
+        centroids, counts = _update(x_dev, assign, kp)
         # Re-seed empty clusters from random points (host-side; rare).
-        empty = np.asarray(counts == 0)
+        empty = np.asarray(counts)[:k] == 0
         if empty.any():
             c = np.array(centroids)  # writable copy
-            c[empty] = np.asarray(x)[rng.choice(x.shape[0], size=int(empty.sum()), replace=False)]
+            c[:k][empty] = x[rng.choice(x.shape[0], size=int(empty.sum()), replace=False)]
             centroids = jnp.asarray(c)
-    return np.asarray(centroids, dtype=np.float32)
+    return np.asarray(centroids, dtype=np.float32)[:k]
 
 
 def assign_kmeans(vectors: np.ndarray, centroids: np.ndarray, *, metric: str = "l2", chunk: int = 65_536) -> np.ndarray:
@@ -92,17 +122,21 @@ def assign_kmeans(vectors: np.ndarray, centroids: np.ndarray, *, metric: str = "
     the tail chunk is pow2-padded so jit sees O(log n) shapes)."""
     n = vectors.shape[0]
     out = np.empty(n, dtype=np.int32)
-    cents = jnp.asarray(centroids)
+    cents = _pad_centroids(np.asarray(centroids, dtype=np.float32))
+    k = centroids.shape[0]
     for s in range(0, n, chunk):
         e = min(s + chunk, n)
         block = _pow2_pad(np.asarray(vectors[s:e], dtype=np.float32), lo=256)
-        out[s:e] = np.asarray(_assign(jnp.asarray(block), cents, metric))[: e - s]
+        out[s:e] = np.asarray(_assign(jnp.asarray(block), cents, k, metric))[: e - s]
     return out
 
 
 def topm_centroids(query_vectors: np.ndarray, centroids: np.ndarray, m: int, *, metric: str = "l2") -> np.ndarray:
     """m nearest centroids per query — int32 [nq, m] (Section 4.1.1 / Alg.3 line 6)."""
-    scores = kops.pairwise_scores(jnp.asarray(query_vectors), jnp.asarray(centroids), metric=metric)
-    m = int(min(m, centroids.shape[0]))
-    _, idx = jax.lax.top_k(scores, m)
-    return np.asarray(idx, dtype=np.int32)
+    nq, k = query_vectors.shape[0], centroids.shape[0]
+    m = int(min(m, k))
+    if nq == 0:
+        return np.zeros((0, m), np.int32)
+    q = _pow2_pad(np.asarray(query_vectors, dtype=np.float32), lo=8)
+    idx = _topm(jnp.asarray(q), _pad_centroids(np.asarray(centroids, dtype=np.float32)), k, m, metric)
+    return np.asarray(idx, dtype=np.int32)[:nq]

@@ -36,6 +36,36 @@ def recall_at_k(result: SearchResult, truth: SearchResult) -> float:
     return float(hits.sum()) / max(int(totals.sum()), 1)
 
 
+def results_mismatch(
+    a_s: np.ndarray, a_i: np.ndarray, b_s: np.ndarray, b_i: np.ndarray
+) -> Optional[str]:
+    """The engine-parity rule: None when two (scores, ids) result sets agree.
+
+    Scores must be allclose (rtol = atol = 1e-4, absent entries compared as
+    -1e30), and each query must return the same set of ids — exact ties may
+    come back in either order. Otherwise, a description of the first
+    disagreement. Tests assert it (``conftest.assert_same_results``) and
+    ``chip_smoke.py`` checks it on the chip.
+    """
+    a_s, b_s = np.asarray(a_s), np.asarray(b_s)
+    if a_s.shape != b_s.shape or np.shape(a_i) != np.shape(b_i):
+        return f"shapes differ: {a_s.shape}/{np.shape(a_i)} vs {b_s.shape}/{np.shape(b_i)}"
+    fa = np.where(np.isfinite(a_s), a_s, -1e30)
+    fb = np.where(np.isfinite(b_s), b_s, -1e30)
+    bad = ~np.isclose(fa, fb, rtol=1e-4, atol=1e-4)
+    if bad.any():
+        r, c = np.argwhere(bad)[0]
+        return (
+            f"{int(bad.sum())} scores differ; first at query {r}, rank {c}: "
+            f"{fa[r, c]!r} vs {fb[r, c]!r}"
+        )
+    for r in range(a_s.shape[0]):
+        ra, rb = a_i[r][a_i[r] >= 0], b_i[r][b_i[r] >= 0]
+        if set(ra.tolist()) != set(rb.tolist()):
+            return f"query {r}: ids {sorted(ra.tolist())} vs {sorted(rb.tolist())}"
+    return None
+
+
 def per_template_recall(result: SearchResult, truth: SearchResult, workload: Workload) -> Dict[int, float]:
     hits, totals = _hits_totals(result, truth)
     out = {}

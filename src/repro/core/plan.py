@@ -37,7 +37,8 @@ class PlanConfig:
     tq_unit: int = 64  # queries per work unit
     min_list_pad: int = 32  # smallest padded list bucket
     max_bucket_shapes: int = 8  # compile-shape budget: max distinct padded lengths
-    use_pallas: Optional[bool] = None  # None = ops default
+    # None = the platform's choice (kernels/ops.resolve_backend)
+    use_pallas: Optional[bool] = None
     interpret: Optional[bool] = None
     # adaptive executor (paper §6.5): below this group size the per-query
     # scan beats batched matmuls (Fig. 7a's crossover ≈ 100 at paper scale)

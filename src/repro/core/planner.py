@@ -19,8 +19,9 @@ This module executes that plan:
      reduction (``ops.segmented_merge_topk`` / ``ops.merge_topk``) — Alg. 3
      line 12 for the whole workload, replacing the per-(template ×
      partition) numpy merge loop. Both layouts are bit-identical: the
-     segmented merge's stable sort reproduces ``lax.top_k``'s tie rule over
-     the same slot-major candidate order (tests/test_engine_segmented.py).
+     segmented merge's first-in-order tie break reproduces ``lax.top_k``'s
+     tie rule over the same slot-major candidate order
+     (tests/test_engine_segmented.py).
 
 Compressed execution (``PlanConfig.scan_mode="pq"``): the scan stage reads
 the arena's uint8 PQ codes instead of raw f32 vectors — each bucket is one
@@ -54,7 +55,7 @@ widest query's slots; on the sharded path each rank contributes only its
 REAL segments to the pre-gather merge (Σ per-rank segments·k, vs the dense
 [R, m, n_slots, k] stack). The pq path additionally keeps the workload's
 ADC tables resident as one [U, M, 256] array and indexes them from inside
-the kernel (``workunit_pq_topk_resident`` / the scalar-prefetch streamed
+the kernel (``workunit_pq_topk_resident`` / the DMA-streamed Pallas
 grid), never materializing the per-bucket [W, TQ, M, 256] expansion the
 dense layout pays (``DispatchStats.lut_expand_bytes`` stays 0). Remaining
 dense-stacking tax: sharded scan *operands* still ship [R, W, ...] per
@@ -440,7 +441,7 @@ def _execute_plan_pq(
     # executor may have routed most of the workload to host-side extras),
     # shipped to the device ONCE as a resident [U, M, 256] array. The
     # segmented layout indexes it directly from the dispatch (per-unit-slot
-    # LUT rows via scalar-prefetch on the Pallas path) so no per-bucket
+    # LUT rows DMA-streamed on the Pallas path) so no per-bucket
     # [W, tq, M, 256] operand ever materializes; the dense layout keeps the
     # device-side gather expansion as the comparison baseline, which
     # ``DispatchStats.lut_expand_bytes`` meters.
@@ -831,7 +832,11 @@ def _assemble_bucket_stacked(
     R = sharded.n_shards
     tq, d = splan.plan.tq, q_vecs.shape[1]
     unit_lists = [splan.rank_buckets[r].get(lp, []) for r in range(R)]
-    W = _next_pow2(max(len(u) for u in unit_lists), 1)
+    # XLA turns a batch of one unit into a plain matmul, which the CPU
+    # backend rounds differently from a batched one: stack a single unit
+    # only where the single-device bucket holds one too, so both round alike
+    n_units = sum(len(u) for u in unit_lists)
+    W = _next_pow2(max(len(u) for u in unit_lists), 1 if n_units == 1 else 2)
     valid = np.zeros((R, W, lp), dtype=bool)
     qrow_of = np.full((R, W, tq), -1, dtype=np.int64)
     slot_of = np.zeros((R, W, tq), dtype=np.int64)

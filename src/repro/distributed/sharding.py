@@ -91,19 +91,6 @@ class ShardingRules:
         return v
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_vma vs check_rep kwarg)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.6
-
-        fn = _sm.shard_map if hasattr(_sm, "shard_map") else _sm
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except (TypeError, ImportError):
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
-
 def set_rules(rules: Optional[ShardingRules]) -> None:
     _STATE.rules = rules
 

@@ -371,7 +371,9 @@ def _kill_writer_phase(
         stdout=subprocess.PIPE,
         stderr=subprocess.DEVNULL,
         text=True,
-        env=os.environ.copy(),
+        # the writer tests WAL durability, not the device: keep it off the
+        # accelerator this (parent) process already holds
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     # let it commit a few batches, then kill without warning (SIGKILL —
     # no atexit, no flush, the genuine crash signature)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import default_interpret
+
 NEG_INF = float(-3.0e38)
 
 
@@ -90,7 +92,7 @@ def flash_attention_pallas(
     window: int = 0,  # 0 = global
     bq: int = 128,
     bk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, s, hq, dh = q.shape
     t, hkv = k.shape[1], k.shape[2]
@@ -124,6 +126,6 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(qp, kp, vp)
     return out[:, :s]

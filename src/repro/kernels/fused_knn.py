@@ -28,7 +28,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import default_interpret
+
 NEG_INF = float(-3.4e38)
+# f32 contractions at full f32 precision on the MXU, in the kernels and in
+# their jnp references alike, so both paths score identically on a TPU
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def fit_tile(cap: int, n: int) -> int:
+    """Tile length for an axis of ``n`` rows: the power of two covering
+    ``n`` (at least 8, the f32 sublane count), never above ``cap`` — a
+    32-row posting list scans as one 32-row tile, not a padded 512-row one."""
+    return min(cap, max(8, 1 << max(0, n - 1).bit_length()))
 
 
 def _merge_topk(run_s, run_i, tile_s, tile_i, k: int):
@@ -50,7 +62,10 @@ def _merge_topk(run_s, run_i, tile_s, tile_i, k: int):
         sel = pos == first
         out_s.append(m[:, 0])
         out_i.append(jnp.sum(jnp.where(sel, cat_i, 0), axis=1))
+        # a taken slot becomes an absent one: once only sentinels remain, a
+        # later pass may pick it again, and must not return its id twice
         cat_s = jnp.where(sel, NEG_INF, cat_s)
+        cat_i = jnp.where(sel, -1, cat_i)
     return jnp.stack(out_s, axis=1), jnp.stack(out_i, axis=1).astype(jnp.int32)
 
 
@@ -78,7 +93,8 @@ def _fused_knn_kernel(
     q = q_ref[...].astype(jnp.float32)  # [TQ, D]
     v = v_ref[...].astype(jnp.float32)  # [TV, D]
     ip = jax.lax.dot_general(
-        q, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, v, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # [TQ, TV] on the MXU
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)  # [TQ,1]
@@ -115,8 +131,6 @@ def _fused_knn_db_stationary_kernel(
     tq: int,
     tv: int,
     metric: str,
-    nq_tiles: int,
-    nv_tiles: int,
 ):
     """DB-stationary grid (v outer, q inner): each DB tile is read ONCE from
 
@@ -134,7 +148,10 @@ def _fused_knn_db_stationary_kernel(
 
     q = q_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    ip = jax.lax.dot_general(q, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    ip = jax.lax.dot_general(
+        q, v, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1, keepdims=True)
         vn = jnp.sum(v * v, axis=1)[None, :]
@@ -151,11 +168,11 @@ def _fused_knn_db_stationary_kernel(
     new_s, new_i = _merge_topk(run_s, run_i, scores, gidx, k)
     acc_s_ref[pl.ds(i * tq, tq), :] = new_s
     acc_i_ref[pl.ds(i * tq, tq), :] = new_i
-
-    @pl.when(j == nv_tiles - 1)
-    def _flush():
-        out_s_ref[...] = new_s
-        out_i_ref[...] = new_i
+    # the output block of query tile i is revisited (and written back) at
+    # every v tile, so it always holds the running top-k — never an
+    # unwritten buffer; the last v tile's write-back is the final answer
+    out_s_ref[...] = new_s
+    out_i_ref[...] = new_i
 
 
 @functools.partial(
@@ -171,7 +188,7 @@ def fused_knn_db_stationary(
     metric: str = "ip",
     tq: int = 128,
     tv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """DB-stationary variant — preferred when NV ≫ NQ (see kernel docstring).
 
@@ -180,6 +197,7 @@ def fused_knn_db_stationary(
     nq, d = q.shape
     nv = v.shape[0]
     k = int(k)
+    tq, tv = fit_tile(tq, nq), fit_tile(tv, nv)
     nq_p = max(tq, ((nq + tq - 1) // tq) * tq)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
     q_p = jnp.zeros((nq_p, d), q.dtype).at[:nq].set(q)
@@ -189,7 +207,7 @@ def fused_knn_db_stationary(
 
     kernel = functools.partial(
         _fused_knn_db_stationary_kernel,
-        k=k, tq=tq, tv=tv, metric=metric, nq_tiles=nq_tiles, nv_tiles=nv_tiles,
+        k=k, tq=tq, tv=tv, metric=metric,
     )
     call = pl.pallas_call(
         kernel,
@@ -211,7 +229,7 @@ def fused_knn_db_stationary(
             pltpu.VMEM((nq_p, k), jnp.float32),
             pltpu.VMEM((nq_p, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )
     out_s, out_i = call(q_p, v_p, valid_p)
     return out_s[:nq], out_i[:nq]
@@ -230,7 +248,7 @@ def fused_knn(
     metric: str = "ip",
     tq: int = 128,
     tv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (scores f32 [NQ,k] best-first, idx i32 [NQ,k]; -1 = none).
 
@@ -240,6 +258,7 @@ def fused_knn(
     nq, d = q.shape
     nv = v.shape[0]
     k = int(k)
+    tq, tv = fit_tile(tq, nq), fit_tile(tv, nv)
     nq_p = max(tq, ((nq + tq - 1) // tq) * tq)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
     q_p = jnp.zeros((nq_p, d), q.dtype).at[:nq].set(q)
@@ -271,7 +290,7 @@ def fused_knn(
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )
     out_s, out_i = call(q_p, v_p, valid_p)
     return out_s[:nq], out_i[:nq]

@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers for the kernels package.
 
-Every op has two implementations: the pure-jnp reference (``ref.py``) used on
-CPU / in the dry-run, and a Pallas TPU kernel. Selection is per-call
-(``use_pallas``) with a process-wide default settable via
-``set_default_backend``. On this CPU container the Pallas path runs in
-interpret mode (tests); on a real TPU fleet ``interpret=False``.
+Every op has two implementations: the pure-jnp reference (``ref.py``) and a
+Pallas TPU kernel. The platform picks the default (``resolve_backend``): on a
+TPU every dispatch runs the compiled Pallas kernels; elsewhere the jnp path
+runs, and a dispatch that asks for Pallas (per call, or process-wide with
+``REPRO_USE_PALLAS=1`` — the CPU test jobs' switch) runs it in interpret
+mode, the only way Pallas runs off a TPU.
 """
 from __future__ import annotations
 
@@ -16,20 +17,25 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from . import platform as _platform
 from . import ref as _ref
 
-_DEFAULT_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_DEFAULT_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+# off a TPU only: run the Pallas kernels (in interpret mode) by default
+_CPU_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 
 # NV/NQ ratio above which the db-stationary grid wins (each DB tile read once
 # from HBM while every query tile's top-k stays resident in VMEM scratch)
 _DB_STATIONARY_RATIO = 4
 
 
-def set_default_backend(use_pallas: bool, interpret: bool = True) -> None:
-    global _DEFAULT_PALLAS, _DEFAULT_INTERPRET
-    _DEFAULT_PALLAS = use_pallas
-    _DEFAULT_INTERPRET = interpret
+def resolve_backend(
+    use_pallas: bool | None, interpret: bool | None
+) -> tuple[bool, bool]:
+    """(use_pallas, interpret) for one dispatch; ``None`` means "the
+    platform's choice": compiled Pallas on a TPU, jnp elsewhere."""
+    if use_pallas is None:
+        use_pallas = _platform.on_tpu() or _CPU_PALLAS
+    return bool(use_pallas), _platform.default_interpret(interpret)
 
 
 @dataclasses.dataclass
@@ -162,8 +168,7 @@ def masked_topk(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused masked similarity top-k. See fused_knn.py for the TPU kernel."""
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
     if use_pallas:
         from .fused_knn import fused_knn
 
@@ -244,8 +249,7 @@ def workunit_topk(
     grid otherwise (``_unit_scan_fn``).
     """
     _DISPATCH.record_knn((q.shape[0], q.shape[1], v.shape[1], int(k)))
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
     if use_pallas:
         return _unit_scan_fn(int(k), metric, True, interpret)(q, v, valid)
     return _batched_masked_topk_jnp(q, v, valid, k, metric)
@@ -271,8 +275,7 @@ def workunit_pq_topk(
     _DISPATCH.record_knn(
         ("pq", luts.shape[0], luts.shape[1], codes.shape[1], int(k))
     )
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
     if use_pallas:
         from .pq_scan import workunit_pq_scan
 
@@ -301,16 +304,15 @@ def workunit_pq_topk_resident(
     an operand the caller must materialize per bucket. This entry point takes
     the workload's resident [U, M, 256] table once plus per-slot row indices:
     on the Pallas path the kernel streams each unit's LUT rows from HBM into
-    VMEM via scalar-prefetch index maps (``workunit_pq_scan_streamed``), so
-    no [W, TQ, M, 256] array ever exists; on the jnp path the row gather
-    happens inside the jit (fused by XLA, never a caller-visible operand).
+    VMEM by DMA, addressed per unit from SMEM (``workunit_pq_scan_streamed``), so
+    no [W, TQ, M, 256] array ever exists; on the jnp path each score gathers
+    its M table entries directly (``ref.workunit_pq_topk_resident_ref``).
     Numerics match ``workunit_pq_topk`` over ``take(table, lut_idx)`` exactly.
     """
     _DISPATCH.record_knn(
         ("pq-res", lut_idx.shape[0], lut_idx.shape[1], codes.shape[1], int(k))
     )
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
     if use_pallas:
         from .pq_scan import workunit_pq_scan_streamed
 
@@ -322,8 +324,7 @@ def workunit_pq_topk_resident(
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _workunit_pq_topk_resident_jnp(table, lut_idx, codes, valid, k):
-    luts = jnp.take(table, lut_idx, axis=0)  # fused into the scan by XLA
-    return _ref.workunit_pq_topk_ref(luts, codes, valid, k)
+    return _ref.workunit_pq_topk_resident_ref(table, lut_idx, codes, valid, k)
 
 
 # --------------------------------------------------------------------------
@@ -337,29 +338,18 @@ def _workunit_pq_topk_resident_jnp(table, lut_idx, codes, valid, k):
 # ``sharded_merge_topk``'s all-gather of per-query top-k candidates —
 # O(k · |model|) floats+ids per query, never distance rows.
 
-_SHARDED_FN_CACHE: dict = {}
-
-
-def _sharded_cached(key, build):
-    fn = _SHARDED_FN_CACHE.get(key)
-    if fn is None:
-        fn = _SHARDED_FN_CACHE[key] = build()
-    return fn
-
-
 def _shard_map(local, mesh, axis, n_in, n_out, *, out_sharded: bool):
     """shard_map sharding the leading (rank) dim of every operand; outputs
     are rank-major sharded (scan stages) or replicated (the gather merge)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.sharding import shard_map_compat
-
     out = P(axis) if out_sharded else P(None)
-    return shard_map_compat(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(P(axis) for _ in range(n_in)),
         out_specs=tuple(out for _ in range(n_out)),
+        check_vma=False,
     )
 
 
@@ -384,20 +374,21 @@ def sharded_workunit_topk(
     """
     R = q.shape[0]
     _DISPATCH.record_knn(("sh", R, q.shape[1], q.shape[2], v.shape[2], int(k)))
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
-    key = ("wu", mesh, axis, q.shape, v.shape, int(k), metric, use_pallas, interpret)
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
+    fn = _sharded_scan_fn(mesh, axis, int(k), metric, use_pallas, interpret)
+    return fn(q, v, valid)
 
-    def build():
-        scan = _unit_scan_fn(int(k), metric, use_pallas, interpret)
 
-        def local(ql, vl, validl):  # leading dim R/R == 1 per rank
-            s, i = scan(ql[0], vl[0], validl[0])
-            return s[None], i[None]
+@functools.lru_cache(maxsize=None)
+def _sharded_scan_fn(mesh, axis: str, k: int, metric: str, use_pallas: bool, interpret: bool):
+    """The jitted program behind ``sharded_workunit_topk``."""
+    scan = _unit_scan_fn(k, metric, use_pallas, interpret)
 
-        return jax.jit(_shard_map(local, mesh, axis, 3, 2, out_sharded=True))
+    def local(ql, vl, validl):  # leading dim R/R == 1 per rank
+        s, i = scan(ql[0], vl[0], validl[0])
+        return s[None], i[None]
 
-    return _sharded_cached(key, build)(q, v, valid)
+    return jax.jit(_shard_map(local, mesh, axis, 3, 2, out_sharded=True))
 
 
 def sharded_workunit_pq_topk(
@@ -419,53 +410,49 @@ def sharded_workunit_pq_topk(
     (the dense merge layout) each rank expands its per-unit [W, TQ, M, 256]
     LUT operand with an on-device gather before the scan. With ``stream=True``
     (the segmented layout) the rank's kernel indexes the resident table
-    directly — the Pallas kernel DMA-streams LUT rows via scalar-prefetch
-    index maps, the jnp path fuses the row gather into the jitted scan — so
-    the expanded operand never exists. Collective-free either way.
+    directly — the Pallas kernel DMA-streams LUT rows addressed from a
+    per-unit SMEM index block, the jnp path gathers table entries per score —
+    so the expanded operand never exists. Collective-free either way.
     """
     R = codes.shape[0]
     _DISPATCH.record_knn(("sh-pq", R, codes.shape[1], lut_idx.shape[2], codes.shape[2], int(k)))
-    use_pallas = _DEFAULT_PALLAS if use_pallas is None else use_pallas
-    interpret = _DEFAULT_INTERPRET if interpret is None else interpret
-    key = (
-        "pq", mesh, axis, luts.shape, lut_idx.shape, codes.shape,
-        int(k), use_pallas, interpret, bool(stream),
-    )
+    use_pallas, interpret = resolve_backend(use_pallas, interpret)
+    fn = _sharded_pq_fn(mesh, axis, int(k), use_pallas, interpret, bool(stream))
+    return fn(luts, lut_idx, codes, valid)
 
-    def build():
-        from jax.sharding import PartitionSpec as P
 
-        from ..distributed.sharding import shard_map_compat
+@functools.lru_cache(maxsize=None)
+def _sharded_pq_fn(mesh, axis: str, k: int, use_pallas: bool, interpret: bool, stream: bool):
+    """The jitted program behind ``sharded_workunit_pq_topk``."""
+    from jax.sharding import PartitionSpec as P
 
-        def local(luts_l, idx_l, codes_l, valid_l):
-            if stream and use_pallas:
-                from .pq_scan import workunit_pq_scan_streamed
+    def local(luts_l, idx_l, codes_l, valid_l):
+        if stream and use_pallas:
+            from .pq_scan import workunit_pq_scan_streamed
 
-                s, i = workunit_pq_scan_streamed(
-                    luts_l, idx_l[0].astype(jnp.int32), codes_l[0], valid_l[0],
-                    k=int(k), interpret=interpret,
-                )
-                return s[None], i[None]
+            s, i = workunit_pq_scan_streamed(
+                luts_l, idx_l[0].astype(jnp.int32), codes_l[0], valid_l[0],
+                k=k, interpret=interpret,
+            )
+        elif stream:
+            s, i = _ref.workunit_pq_topk_resident_ref(luts_l, idx_l[0], codes_l[0], valid_l[0], k)
+        elif use_pallas:
+            from .pq_scan import workunit_pq_scan
+
             per_unit = jnp.take(luts_l, idx_l[0], axis=0)  # [W, TQ, M, 256]
-            if use_pallas:
-                from .pq_scan import workunit_pq_scan
+            s, i = workunit_pq_scan(per_unit, codes_l[0], valid_l[0], k=k, interpret=interpret)
+        else:
+            per_unit = jnp.take(luts_l, idx_l[0], axis=0)
+            s, i = _ref.workunit_pq_topk_ref(per_unit, codes_l[0], valid_l[0], k)
+        return s[None], i[None]
 
-                s, i = workunit_pq_scan(
-                    per_unit, codes_l[0], valid_l[0], k=int(k), interpret=interpret
-                )
-            else:
-                s, i = _ref.workunit_pq_topk_ref(per_unit, codes_l[0], valid_l[0], int(k))
-            return s[None], i[None]
-
-        fn = shard_map_compat(
-            local,
-            mesh=mesh,
-            in_specs=(P(), P(axis), P(axis), P(axis)),
-            out_specs=(P(axis), P(axis)),
-        )
-        return jax.jit(fn)
-
-    return _sharded_cached(key, build)(luts, lut_idx, codes, valid)
+    return jax.jit(jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(), P(axis), P(axis), P(axis)),
+        out_specs=(P(axis), P(axis)),
+        check_vma=False,
+    ))
 
 
 def sharded_merge_topk(
@@ -485,25 +472,27 @@ def sharded_merge_topk(
     distance rows never cross ranks.
     """
     _DISPATCH.record_merge()
-    key = ("mg", mesh, axis, scores.shape, idx.dtype, int(k))
+    return _sharded_merge_fn(mesh, axis, int(k))(scores, idx)
 
-    def build():
-        def local(sl, il):  # [1, m, C] per rank
-            top, pos = jax.lax.top_k(sl[0], int(k))
-            li = jnp.take_along_axis(il[0], pos.astype(il.dtype), axis=1)
-            top, li = _ref.normalize_merge_sentinels(top, li)
-            all_s = jax.lax.all_gather(top, axis)  # [R, m, k] — THE comm step
-            all_i = jax.lax.all_gather(li, axis)
-            m = sl.shape[1]
-            cat_s = jnp.moveaxis(all_s, 0, 1).reshape(m, -1)
-            cat_i = jnp.moveaxis(all_i, 0, 1).reshape(m, -1)
-            t, p = jax.lax.top_k(cat_s, int(k))
-            oi = jnp.take_along_axis(cat_i, p.astype(cat_i.dtype), axis=1)
-            return _ref.normalize_merge_sentinels(t, oi)
 
-        return jax.jit(_shard_map(local, mesh, axis, 2, 2, out_sharded=False))
+@functools.lru_cache(maxsize=None)
+def _sharded_merge_fn(mesh, axis: str, k: int):
+    """The jitted program behind ``sharded_merge_topk``."""
 
-    return _sharded_cached(key, build)(scores, idx)
+    def local(sl, il):  # [1, m, C] per rank
+        top, pos = jax.lax.top_k(sl[0], k)
+        li = jnp.take_along_axis(il[0], pos.astype(il.dtype), axis=1)
+        top, li = _ref.normalize_merge_sentinels(top, li)
+        all_s = jax.lax.all_gather(top, axis)  # [R, m, k] — THE comm step
+        all_i = jax.lax.all_gather(li, axis)
+        m = sl.shape[1]
+        cat_s = jnp.moveaxis(all_s, 0, 1).reshape(m, -1)
+        cat_i = jnp.moveaxis(all_i, 0, 1).reshape(m, -1)
+        t, p = jax.lax.top_k(cat_s, k)
+        oi = jnp.take_along_axis(cat_i, p.astype(cat_i.dtype), axis=1)
+        return _ref.normalize_merge_sentinels(t, oi)
+
+    return jax.jit(_shard_map(local, mesh, axis, 2, 2, out_sharded=False))
 
 
 def merge_topk(
@@ -551,4 +540,47 @@ def segmented_merge_topk(
 
 @functools.partial(jax.jit, static_argnames=("n_segments", "k"))
 def _segmented_merge_topk_jnp(flat_s, flat_i, seg_of, n_segments, k):
-    return _ref.segmented_merge_topk_ref(flat_s, flat_i, seg_of, n_segments, k)
+    """``ref.segmented_merge_topk_ref`` without its sort: k rounds, each
+    taking every segment's best remaining candidate (the first in candidate
+    order among equal scores, as the reference's stable sort ranks them)
+    with two segment reductions over row maxima. The TPU compiler takes
+    about a minute over the reference's sort of a few million candidates,
+    and a flush or search compiles one merge per shape; these rounds
+    compile in seconds and stay in the [C, kk] layout."""
+    C, kk = flat_s.shape
+    if n_segments == 0 or C * kk == 0:
+        return _ref.segmented_merge_topk_ref(flat_s, flat_i, seg_of, n_segments, k)
+    s = flat_s.astype(jnp.float32)
+    # pad rows (seg >= n_segments) reduce into one extra, discarded segment
+    seg = jnp.minimum(seg_of.astype(jnp.int32), n_segments)
+    pos = jnp.arange(C * kk, dtype=jnp.int32).reshape(C, kk)
+    absent = jnp.int32(C * kk)
+
+    def segment(reduce, x):
+        return reduce(x, seg, num_segments=n_segments + 1, indices_are_sorted=True)
+
+    def take_best(r, carry):
+        alive, out_s, out_i = carry
+        live_s = jnp.where(alive, s, -jnp.inf)
+        best = segment(jax.ops.segment_max, live_s.max(axis=1))
+        hit = alive & (live_s == best[seg][:, None])
+        first = segment(jax.ops.segment_min, jnp.where(hit, pos, absent).min(axis=1))
+        first = first[:n_segments]
+        found = first < absent
+        # a segment with nothing left points past the last row, each at its
+        # own row so the scatter's indices stay unique: its gathers clamp
+        # (and are masked), its scatter is dropped
+        row = jnp.where(found, first // kk, C + jnp.arange(n_segments, dtype=jnp.int32))
+        col = jnp.where(found, first % kk, 0)
+        out_s = out_s.at[:, r].set(jnp.where(found, s.at[row, col].get(mode="clip"), -jnp.inf))
+        out_i = out_i.at[:, r].set(jnp.where(found, flat_i.at[row, col].get(mode="clip"), -1))
+        alive = alive.at[row, col].set(False, mode="drop", unique_indices=True)
+        return alive, out_s, out_i
+
+    out = (
+        jnp.ones((C, kk), bool),
+        jnp.full((n_segments, k), -jnp.inf, jnp.float32),
+        jnp.full((n_segments, k), -1, flat_i.dtype),
+    )
+    _, out_s, out_i = jax.lax.fori_loop(0, k, take_best, out)
+    return _ref.normalize_merge_sentinels(out_s, out_i)

@@ -7,8 +7,8 @@ Two grids over compressed (PQ) code storage:
   * ``workunit_pq_scan`` — the engine's batched work-unit grid ``[W, TQ, TV]``:
     each unit carries TQ per-query LUTs ([TQ, M, 256] f32 ≤ 64 KB·TQ/256,
     VMEM-resident across that unit's whole code sweep) and scans uint8 code
-    tiles with a SINGLE one-hot MXU contraction — the per-subspace Python loop
-    of ``pq_scan`` is flattened into one ``[TQ, M·256] @ [M·256, TV]`` matmul.
+    tiles as M one-hot MXU contractions ``[TQ, 256] @ [256, TV]``, one per
+    subspace, added in the jnp reference's order (``_adc_unit_scores``).
     Results fold into the same running-top-k VMEM scratch as fused_knn.
 
 HBM traffic per scanned row is the CODE bytes (d·4/M× less than raw vectors —
@@ -32,7 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused_knn import NEG_INF, _merge_topk
+from .fused_knn import HIGHEST, NEG_INF, _merge_topk, fit_tile
+from .platform import default_interpret
+from .ref import adc_sum
 
 
 def _pq_scan_kernel(
@@ -65,7 +67,8 @@ def _pq_scan_kernel(
             codes[:, sub][:, None] == jax.lax.broadcasted_iota(jnp.int32, (tv, 256), 1)
         ).astype(jnp.float32)
         scores = scores + jax.lax.dot_general(
-            onehot, lut[sub], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            onehot, lut[sub], (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32,
         )
     valid = valid_ref[0, :] != 0
     scores = jnp.where(valid, scores, NEG_INF)[None, :]  # [1, TV]
@@ -90,7 +93,7 @@ def pq_scan(
     *,
     k: int,
     tv: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     nv, m = codes.shape
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
@@ -120,7 +123,7 @@ def pq_scan(
             pltpu.VMEM((1, k), jnp.float32),
             pltpu.VMEM((1, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )
     s, i = call(lut, codes_p, valid_p)
     return s[0], i[0]
@@ -131,10 +134,40 @@ def pq_scan(
 # ---------------------------------------------------------------------------
 
 
+def _pad_unit_tiles(codes, valid, nv_p):
+    """Pad each unit's code rows to ``nv_p`` (uint8) and lay its valid mask
+    out as [W, 1, nv_p] int32, so the mask's (1, 1, TV) block keeps the
+    last two block dims at (full, TV) as the TPU lowering requires."""
+    w, nv, m = codes.shape
+    codes_p = jnp.zeros((w, nv_p, m), jnp.uint8).at[:, :nv].set(codes.astype(jnp.uint8))
+    valid_p = jnp.zeros((w, 1, nv_p), jnp.int32).at[:, 0, :nv].set(valid.astype(jnp.int32))
+    return codes_p, valid_p
+
+
+def _adc_unit_scores(lut, codes, tv: int, m: int):
+    """[TQ, TV] ADC scores of one unit: lut [TQ, M*256] f32, codes [TV, M]
+    int32. The one-hot [TV, M*256] is built once; each subspace's lane-aligned
+    256-column slice is one MXU contraction that selects exactly one table
+    entry per (query, row), and the M partial scores are added in
+    ``ref.adc_sum``'s order, so the kernel matches the jnp reference bit for
+    bit."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (tv, m, 256), 2)
+    onehot = (codes[:, :, None] == iota).astype(jnp.float32).reshape(tv, m * 256)
+
+    def term(j):
+        cols = slice(j * 256, (j + 1) * 256)
+        return jax.lax.dot_general(
+            lut[:, cols], onehot[:, cols], (((1,), (1,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32,
+        )
+
+    return adc_sum(term, m)
+
+
 def _workunit_pq_kernel(
     lut_ref,  # [1, TQ, M*256] f32 — this unit's per-query tables, flattened
     codes_ref,  # [1, TV, M] uint8
-    valid_ref,  # [1, TV] int32
+    valid_ref,  # [1, 1, TV] int32
     out_s_ref,  # [1, TQ, K]
     out_i_ref,  # [1, TQ, K]
     acc_s_ref,  # scratch f32 [TQ, K]
@@ -153,15 +186,8 @@ def _workunit_pq_kernel(
         acc_i_ref[...] = jnp.full(acc_i_ref.shape, -1, jnp.int32)
 
     codes = codes_ref[0].astype(jnp.int32)  # [TV, M] — uint8 widened in-register
-    # one-hot over ALL subspaces at once: [TV, M, 256] -> [TV, M*256]; the
-    # whole ADC gather is then ONE MXU contraction instead of an M-long loop
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tv, m, 256), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32).reshape(tv, m * 256)
-    lut = lut_ref[0]  # [TQ, M*256]
-    scores = jax.lax.dot_general(
-        lut, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [TQ, TV]
-    valid = valid_ref[0, :] != 0
+    scores = _adc_unit_scores(lut_ref[0], codes, tv, m)  # [TQ, TV]
+    valid = valid_ref[0, 0, :] != 0
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     gidx = jnp.where(valid[None, :], col + j * tv, -1)
@@ -184,7 +210,7 @@ def workunit_pq_scan(
     *,
     k: int,
     tv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Work-unit grid [W, TQ, TV] over compressed codes.
 
@@ -196,12 +222,9 @@ def workunit_pq_scan(
     assert nbook == 256, "PQ codebooks are 8-bit (256 entries)"
     nv = codes.shape[1]
     k = int(k)
-    # shrink the tile to the (pow2-padded) list length so short posting lists
-    # don't pay a full 512-row sweep
-    tv = min(tv, max(8, 1 << max(0, nv - 1).bit_length()))
+    tv = fit_tile(tv, nv)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
-    codes_p = jnp.zeros((w, nv_p, m), jnp.uint8).at[:, :nv].set(codes.astype(jnp.uint8))
-    valid_p = jnp.zeros((w, nv_p), jnp.int32).at[:, :nv].set(valid.astype(jnp.int32))
+    codes_p, valid_p = _pad_unit_tiles(codes, valid, nv_p)
     luts_f = luts.reshape(w, tq, m * nbook)
     nv_tiles = nv_p // tv
 
@@ -214,7 +237,7 @@ def workunit_pq_scan(
         in_specs=[
             pl.BlockSpec((1, tq, m * nbook), lambda w_, j: (w_, 0, 0)),
             pl.BlockSpec((1, tv, m), lambda w_, j: (w_, j, 0)),
-            pl.BlockSpec((1, tv), lambda w_, j: (w_, j)),
+            pl.BlockSpec((1, 1, tv), lambda w_, j: (w_, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, tq, k), lambda w_, j: (w_, 0, 0)),
@@ -228,7 +251,7 @@ def workunit_pq_scan(
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )
     return call(luts_f, codes_p, valid_p)
 
@@ -239,13 +262,13 @@ def workunit_pq_scan(
 
 
 def _workunit_pq_streamed_kernel(
-    idx_ref,  # SMEM i32 [W, TQ] — scalar-prefetched LUT row per unit slot
-    table_ref,  # HBM f32 [U, M*256] — the resident ADC table, NEVER expanded
+    idx_ref,  # SMEM i32 [1, 1, TQ] — this unit's LUT row per query slot
+    table_ref,  # HBM f32 [U, M, 256] — the resident ADC table, NEVER expanded
     codes_ref,  # [1, TV, M] uint8
-    valid_ref,  # [1, TV] int32
+    valid_ref,  # [1, 1, TV] int32
     out_s_ref,  # [1, TQ, K]
     out_i_ref,  # [1, TQ, K]
-    lut_vmem,  # scratch f32 [TQ, M*256] — this unit's streamed LUT rows
+    lut_vmem,  # scratch f32 [TQ, M, 256] — this unit's streamed LUT rows
     acc_s_ref,  # scratch f32 [TQ, K]
     acc_i_ref,  # scratch i32 [TQ, K]
     sem,  # DMA completion semaphore
@@ -256,7 +279,6 @@ def _workunit_pq_streamed_kernel(
     m: int,
     nv_tiles: int,
 ):
-    w = pl.program_id(0)
     j = pl.program_id(1)  # code tile (inner) — w outer, so scratch is per-unit
 
     @pl.when(j == 0)
@@ -264,23 +286,24 @@ def _workunit_pq_streamed_kernel(
         acc_s_ref[...] = jnp.full(acc_s_ref.shape, NEG_INF, jnp.float32)
         acc_i_ref[...] = jnp.full(acc_i_ref.shape, -1, jnp.int32)
         # gather this unit's TQ LUT rows HBM -> VMEM, addressed through the
-        # prefetched index vector: the per-unit [TQ, M*256] block is BUILT in
-        # VMEM by DMA, so no [W, TQ, M, 256] operand is ever materialized
-        for t in range(tq):
-            dma = pltpu.make_async_copy(
-                table_ref.at[idx_ref[w, t]], lut_vmem.at[t], sem
-            )
+        # unit's index block in SMEM: the per-unit [TQ, M, 256] block is BUILT
+        # in VMEM by DMA, so no [W, TQ, M, 256] operand is ever materialized.
+        # Each copy lands a whole [M, 256] slab at a leading-dim offset (a
+        # single row of a 2-D [TQ, M*256] buffer would cut the (8, 128) VMEM
+        # tiling); all TQ copies are in flight before the first wait
+        dmas = [
+            pltpu.make_async_copy(table_ref.at[idx_ref[0, 0, t]], lut_vmem.at[t], sem)
+            for t in range(tq)
+        ]
+        for dma in dmas:
             dma.start()
+        for dma in dmas:
             dma.wait()
 
     codes = codes_ref[0].astype(jnp.int32)  # [TV, M] — uint8 widened in-register
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tv, m, 256), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32).reshape(tv, m * 256)
-    scores = jax.lax.dot_general(
-        lut_vmem[...], onehot, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [TQ, TV] — same contraction as _workunit_pq_kernel
-    valid = valid_ref[0, :] != 0
+    lut = lut_vmem[...].reshape(tq, m * 256)
+    scores = _adc_unit_scores(lut, codes, tv, m)  # [TQ, TV]
+    valid = valid_ref[0, 0, :] != 0
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     gidx = jnp.where(valid[None, :], col + j * tv, -1)
@@ -304,63 +327,59 @@ def workunit_pq_scan_streamed(
     *,
     k: int,
     tv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Work-unit ADC grid that streams LUT rows straight out of the resident
     table.
 
     Same [W, TV] sweep and one-hot MXU contraction as ``workunit_pq_scan``,
     but the per-unit LUT block is assembled in VMEM by per-row DMA from the
-    [U, M·256] HBM table, addressed through a scalar-prefetched index vector
-    (``PrefetchScalarGridSpec``). The [W, TQ, M, 256] expansion — W·TQ/U×
+    [U, M, 256] HBM table, addressed through the unit's [TQ] index block,
+    which the grid pipelines into SMEM unit by unit (prefetching all W·TQ
+    indices at once would outgrow SMEM at W in the tens of thousands). The
+    [W, TQ, M, 256] expansion — W·TQ/U×
     redundant HBM traffic plus its allocation — is gone; each unit reads
     exactly the TQ rows it scans with.
 
     Returns (scores f32 [W, TQ, k] best-first, idx i32 [W, TQ, k]; -1 = none).
     """
-    u, m, nbook = table.shape
+    _, m, nbook = table.shape
     assert nbook == 256, "PQ codebooks are 8-bit (256 entries)"
     w, tq = lut_idx.shape
     nv = codes.shape[1]
     k = int(k)
-    # shrink the tile to the (pow2-padded) list length so short posting lists
-    # don't pay a full 512-row sweep (same rule as workunit_pq_scan)
-    tv = min(tv, max(8, 1 << max(0, nv - 1).bit_length()))
+    tv = fit_tile(tv, nv)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
-    codes_p = jnp.zeros((w, nv_p, m), jnp.uint8).at[:, :nv].set(codes.astype(jnp.uint8))
-    valid_p = jnp.zeros((w, nv_p), jnp.int32).at[:, :nv].set(valid.astype(jnp.int32))
-    table_f = table.reshape(u, m * nbook)
+    codes_p, valid_p = _pad_unit_tiles(codes, valid, nv_p)
     nv_tiles = nv_p // tv
 
     kernel = functools.partial(
         _workunit_pq_streamed_kernel, k=k, tv=tv, tq=tq, m=m, nv_tiles=nv_tiles
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # lut_idx rides ahead of the grid in SMEM
-        grid=(w, nv_tiles),  # unit outer, code tile inner
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # table stays in HBM
-            pl.BlockSpec((1, tv, m), lambda w_, j, idx: (w_, j, 0)),
-            pl.BlockSpec((1, tv), lambda w_, j, idx: (w_, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tq, k), lambda w_, j, idx: (w_, 0, 0)),
-            pl.BlockSpec((1, tq, k), lambda w_, j, idx: (w_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, m * nbook), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.float32),
-            pltpu.VMEM((tq, k), jnp.int32),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
     call = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(w, nv_tiles),  # unit outer, code tile inner
+        in_specs=[
+            pl.BlockSpec((1, 1, tq), lambda w_, j: (w_, 0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
+            pl.BlockSpec((1, tv, m), lambda w_, j: (w_, j, 0)),
+            pl.BlockSpec((1, 1, tv), lambda w_, j: (w_, 0, j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, tq, k), lambda w_, j: (w_, 0, 0)),
+            pl.BlockSpec((1, tq, k), lambda w_, j: (w_, 0, 0)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((w, tq, k), jnp.float32),
             jax.ShapeDtypeStruct((w, tq, k), jnp.int32),
         ],
-        interpret=interpret,
+        scratch_shapes=[
+            pltpu.VMEM((tq, m, nbook), jnp.float32),
+            pltpu.VMEM((tq, k), jnp.float32),
+            pltpu.VMEM((tq, k), jnp.int32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        interpret=default_interpret(interpret),
     )
-    return call(lut_idx.astype(jnp.int32), table_f, codes_p, valid_p)
+    idx = lut_idx.astype(jnp.int32).reshape(w, 1, tq)
+    return call(idx, table, codes_p, valid_p)

@@ -83,7 +83,9 @@ def pairwise_scores_ref(q: jax.Array, v: jax.Array, metric: str = "ip") -> jax.A
     """
     q = q.astype(jnp.float32)
     v = v.astype(jnp.float32)
-    ip = q @ v.T
+    # full f32 on a TPU's MXU too (its default rounds f32 operands to bf16),
+    # matching the Pallas kernels' contraction
+    ip = jnp.matmul(q, v.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return ip
     if metric == "l2":
@@ -113,6 +115,17 @@ def masked_topk_ref(
     return top, idx
 
 
+def adc_sum(term, m: int):
+    """Σ_j term(j) over the M subspaces, added one subspace at a time in
+    order. Each term is an exact table entry, so every ADC path that adds in
+    this order — these references and the Pallas kernels — scores bit for
+    bit alike, on any backend."""
+    scores = term(0)
+    for j in range(1, m):
+        scores = scores + term(j)
+    return scores
+
+
 def adc_topk_ref(
     luts: jax.Array,  # f32 [nq, M, 256] — per-query ADC lookup tables
     codes: jax.Array,  # uint8/int32 [nv, M]
@@ -126,9 +139,7 @@ def adc_topk_ref(
     masked-out or absent entries are (-inf-ish, -1).
     """
     c = codes.astype(jnp.int32)  # [nv, M]
-    m = luts.shape[1]
-    # fancy-gather per subspace: luts[q, m, c[v, m]] -> [nq, nv, M], then sum
-    scores = luts[:, jnp.arange(m)[None, :], c].sum(axis=-1)  # [nq, nv]
+    scores = adc_sum(lambda j: luts[:, j, c[:, j]], luts.shape[1])  # [nq, nv]
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     top, idx = jax.lax.top_k(scores, k)
     idx = jnp.where(top <= NEG_INF / 2, -1, idx).astype(jnp.int32)
@@ -143,6 +154,28 @@ def workunit_pq_topk_ref(
 ) -> tuple[jax.Array, jax.Array]:
     """Batched work-unit ADC oracle: adc_topk_ref vmapped over the unit dim."""
     return jax.vmap(lambda l, c, v: adc_topk_ref(l, c, v, k))(luts, codes, valid)
+
+
+def workunit_pq_topk_resident_ref(
+    table: jax.Array,  # f32 [U, M, 256] — resident ADC tables
+    lut_idx: jax.Array,  # int [W, TQ] — table row per unit slot
+    codes: jax.Array,  # uint8/int32 [W, TV, M]
+    valid: jax.Array,  # bool [W, TV]
+    k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """``workunit_pq_topk_ref`` over ``table[lut_idx]``, without that
+    [W, TQ, M, 256] operand: each score gathers its M table entries straight
+    from the resident table (the expansion is W·TQ·8 KB — 8 GB at 16k units
+    of 64 slots, more than a chip holds). Same sum, same scores."""
+    c = codes.astype(jnp.int32)
+    rows = lut_idx[:, :, None]  # [W, TQ, 1]
+    # one [W, TQ, TV] term per subspace: an [..., M] minor axis would pad M
+    # to the TPU's 128 lanes (16x at M = 8)
+    scores = adc_sum(lambda j: table[rows, j, c[:, None, :, j]], table.shape[1])
+    scores = jnp.where(valid[:, None, :], scores, NEG_INF)  # [W, TQ, TV]
+    top, idx = jax.lax.top_k(scores, k)
+    idx = jnp.where(top <= NEG_INF / 2, -1, idx).astype(jnp.int32)
+    return top, idx
 
 
 def flash_attention_ref(

@@ -16,7 +16,6 @@ Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link IC
 from __future__ import annotations
 
 import dataclasses
-import os
 import re
 from typing import Dict, Optional, Tuple
 
@@ -40,38 +39,35 @@ class HardwareTerms:
         return dataclasses.asdict(self)
 
 
-# The cpu profile is deliberately conservative (a few-core container running
-# interpret mode): the roofline *fractions* the profiler reports are only
-# meaningful relative to a fixed denominator, so any stable figure works for
-# regression tracking — what matters is that the same baseline always divides
-# by the same terms.
-_HW_PROFILES: Dict[str, HardwareTerms] = {
-    "tpu-v5e": HardwareTerms("tpu-v5e", PEAK_FLOPS, HBM_BW, LINK_BW),
-    "cpu": HardwareTerms("cpu", 5e11, 5e10, 1e10),
+# Published per-chip peaks, keyed by JAX's ``device_kind``. Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s of chip-to-chip interconnect (4 links of ~50 GB/s). A TPU whose kind
+# is not listed here is an error, never mapped onto another chip's peaks.
+TPU_PEAKS: Dict[str, HardwareTerms] = {
+    "TPU v5 lite": HardwareTerms("TPU v5 lite", PEAK_FLOPS, HBM_BW, LINK_BW),
 }
+
+# Off a TPU (CPU, interpret mode) the profiler's roofline fractions are only
+# regression-tracking ratios against a fixed denominator, not device metrics.
+CPU_TERMS = HardwareTerms("cpu", 5e11, 5e10, 1e10)
 
 
 def current_hardware() -> HardwareTerms:
-    """Hardware terms for the machine running now.
+    """Peak terms of the device JAX runs on: ``TPU_PEAKS[device_kind]`` on a
+    TPU (raises for a kind with no published peaks), ``CPU_TERMS`` elsewhere."""
+    from ..kernels import platform
 
-    ``REPRO_HW`` names a profile explicitly; otherwise a TPU jax backend maps
-    to tpu-v5e and anything else (CPU / interpret mode) to the cpu profile.
-    """
-    name = os.environ.get("REPRO_HW")
-    if name:
-        try:
-            return _HW_PROFILES[name]
-        except KeyError:
-            raise ValueError(
-                f"unknown REPRO_HW={name!r}; one of {sorted(_HW_PROFILES)}"
-            ) from None
+    if not platform.on_tpu():
+        return CPU_TERMS
+    dev = platform.device()
     try:
-        import jax
+        return TPU_PEAKS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device_kind {dev.device_kind!r}; "
+            f"known kinds: {sorted(TPU_PEAKS)}"
+        ) from None
 
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax is a hard dep everywhere else
-        backend = "cpu"
-    return _HW_PROFILES["tpu-v5e" if backend == "tpu" else "cpu"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -228,8 +228,6 @@ def _ep_local(xt, router, w_gate, w_up, w_down, cfg: MoEConfig, dp: int, cap_e: 
 def moe_layer_ep(p, x, cfg: MoEConfig, rules) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.sharding import shard_map_compat
-
     mesh = rules.mesh
     dp = mesh.shape["data"]  # expert shards live on the data axis
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -244,7 +242,7 @@ def moe_layer_ep(p, x, cfg: MoEConfig, rules) -> Tuple[jax.Array, Dict[str, jax.
     cap_e = int(max(1, (t_loc * cfg.top_k * cfg.capacity_factor) // cfg.n_experts))
 
     # tokens flattened per shard; weights: E over data, F over model
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda xt, r, wg, wu, wd: _ep_local(xt, r, wg, wu, wd, cfg, dp, cap_e),
         mesh=mesh,
         in_specs=(
@@ -255,6 +253,7 @@ def moe_layer_ep(p, x, cfg: MoEConfig, rules) -> Tuple[jax.Array, Dict[str, jax.
             P("data", "model", None),
         ),
         out_specs=(P(batch_axes, None), P(), P(), P()),
+        check_vma=False,
     )
     xt = x.reshape(b * s, d)
     yt, lb, zl, dr = fn(

@@ -15,7 +15,7 @@ profiler attributes the dispatch:
   occupancy       real vs padded work units and rows per bucket — the
                   padding-waste % the bucket ladder trades for few dispatches
   roofline        achieved GB/s and GFLOP/s as a fraction of the
-                  launch/roofline.py hardware terms (REPRO_HW selectable)
+                  launch/roofline.py peaks of the device in use
 
 Aggregation is per (phase, mode, bucket shape) — phases: scan / merge /
 rerank / gather — plus a per-mesh-rank table for the sharded path fed from

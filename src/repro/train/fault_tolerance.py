@@ -158,8 +158,6 @@ def dp_train_step_compressed(model_cfg: ModelConfig, opt_cfg: OptConfig, mesh):
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.sharding import shard_map_compat
-
     def local_step(params, opt_state, residual, batch):
         def loss(p):
             l, _ = api.loss_fn(p, model_cfg, batch)
@@ -175,10 +173,11 @@ def dp_train_step_compressed(model_cfg: ModelConfig, opt_cfg: OptConfig, mesh):
 
     batch_spec = {"tokens": P("data"), "labels": P("data")}
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(), P(), P(), batch_spec),
             out_specs=(P(), P(), P(), P()),
+            check_vma=False,
         )
     )

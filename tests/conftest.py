@@ -1,6 +1,7 @@
 import numpy as np
 import pytest
 
+from repro.core.metrics import results_mismatch
 from repro.core.predicates import Between, Cmp, Contains, In, NotNull, make_filter
 from repro.core.types import Column, VectorDatabase, Workload
 
@@ -8,15 +9,9 @@ from repro.core.types import Column, VectorDatabase, Workload
 def assert_same_results(a_s, a_i, b_s, b_i):
     """Scores allclose (with -inf normalized) and per-row candidate-set
     equality modulo exact-tie ordering — the engine-parity assertion shared
-    by the engine/service/pq suites."""
-    np.testing.assert_allclose(
-        np.where(np.isfinite(a_s), a_s, -1e30),
-        np.where(np.isfinite(b_s), b_s, -1e30),
-        rtol=1e-4,
-        atol=1e-4,
-    )
-    for r in range(a_i.shape[0]):
-        assert set(a_i[r][a_i[r] >= 0].tolist()) == set(b_i[r][b_i[r] >= 0].tolist()), r
+    by the engine/service/pq suites (``repro.core.metrics.results_mismatch``)."""
+    msg = results_mismatch(a_s, a_i, b_s, b_i)
+    assert msg is None, msg
 
 
 def small_db(n=2000, d=16, seed=0, metric="l2"):

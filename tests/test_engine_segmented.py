@@ -3,8 +3,8 @@ to the dense slot-rectangular layout it replaces.
 
 The acceptance bar is exact equality of ids AND scores (``np.array_equal``,
 not the tie-tolerant conftest helper): the segmented scatter preserves each
-query's slot-major candidate order and the segmented merge's stable sort
-reproduces ``lax.top_k``'s smallest-index tie rule, so nothing — not even
+query's slot-major candidate order and the segmented merge's first-in-order
+tie break reproduces ``lax.top_k``'s smallest-index tie rule, so nothing — not even
 exact-tie ordering — may diverge.
 
 Covered: {ip, l2} × {f32, pq} engine parity with forced score ties and
@@ -256,10 +256,12 @@ def _random_segments(rng, m, kk):
     return counts, flat_s, flat_i, seg_of
 
 
-def test_segmented_merge_matches_dense_merge():
-    """segmented_merge_topk == the dense scatter + lax.top_k emulation,
-    bit-for-bit, over random ragged shapes with ties and both sentinel
-    flavors (incl. empty segments and k > width)."""
+@pytest.mark.parametrize("merge", [ops.segmented_merge_topk, ref.segmented_merge_topk_ref])
+def test_segmented_merge_matches_dense_merge(merge):
+    """segmented_merge_topk (and the sort-based oracle it replaces on the
+    device) == the dense scatter + lax.top_k emulation, bit-for-bit, over
+    random ragged shapes with ties and both sentinel flavors (incl. empty
+    segments and k > width)."""
     rng = np.random.default_rng(1)
     for trial in range(60):
         m = int(rng.integers(1, 6))
@@ -267,7 +269,7 @@ def test_segmented_merge_matches_dense_merge():
         kk = int(rng.integers(1, 4))
         counts, flat_s, flat_i, seg_of = _random_segments(rng, m, kk)
         want_s, want_i = _dense_merge_emulation(flat_s, flat_i, counts, k)
-        got_s, got_i = ops.segmented_merge_topk(
+        got_s, got_i = merge(
             jnp.asarray(flat_s), jnp.asarray(flat_i), jnp.asarray(seg_of), m, k
         )
         assert np.array_equal(np.asarray(got_i), want_i), trial
@@ -288,7 +290,7 @@ def test_segmented_merge_pad_rows_dropped():
 
 
 def test_pq_streamed_kernel_matches_ref():
-    """The scalar-prefetch streamed ADC grid == the expanded-LUT reference:
+    """The DMA-streamed ADC grid == the expanded-LUT reference:
     per-row DMA from the resident table must not change a single score."""
     from repro.core.pq import PQIndex, adc_tables
     from repro.kernels import pq_scan

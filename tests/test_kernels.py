@@ -37,6 +37,23 @@ def test_fused_knn_all_invalid():
     assert (np.asarray(i) == -1).all()
 
 
+@pytest.mark.parametrize("kernel", ["fused_knn", "fused_knn_db_stationary"])
+def test_fused_knn_fewer_valid_than_k_across_tiles(kernel):
+    """A query with fewer valid rows than k, spread over several v tiles:
+    the slots past the last valid row stay absent (idx -1), never a repeat
+    of a row already returned."""
+    from repro.kernels import fused_knn as fk
+
+    q = jnp.asarray(RNG.normal(size=(4, 8)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(2000, 8)), jnp.float32)
+    valid = jnp.zeros(2000, bool).at[jnp.array([5, 700, 1900])].set(True)
+    s1, i1 = getattr(fk, kernel)(q, v, valid, k=5, tv=512, interpret=True)
+    s2, i2 = ref.masked_topk_ref(q, v, valid, 5, "ip")
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    assert (np.asarray(i1)[:, 3:] == -1).all()
+    np.testing.assert_allclose(np.asarray(s1)[:, :3], np.asarray(s2)[:, :3], rtol=1e-5)
+
+
 @pytest.mark.parametrize(
     "b,s,hq,hkv,dh,causal,window",
     [
