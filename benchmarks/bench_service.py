@@ -145,7 +145,7 @@ def main_obs() -> None:
     enabled/disabled median ratio — the number ci.yml gates at 1.05 via
     ``benchmarks/check_obs.py``. The enabled arm runs tracing AND the kernel
     dispatch profiler together (the gate covers the full observability
-    stack, and the trace must carry the ``profile.dispatch`` instants
+    stack, and the trace must carry the executor's ``scan.*`` spans
     check_obs requires). The enabled pass also exports ``trace.json``
     (Chrome trace, schema-validated here) and feeds the drift monitor a
     template shift at the stream midpoint that ``obs/drift_shift`` must see.

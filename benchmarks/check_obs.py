@@ -28,11 +28,11 @@ from repro.obs.trace import validate_chrome_trace
 
 # every serving trace must show these stages end-to-end; dispatch/merge span
 # names carry stage suffixes (dispatch.scan, merge.segmented, merge.final,
-# merge.gather) so those two are prefix-matched. profile.* instants come
-# from the kernel profiler, which main_obs runs alongside tracing in the
-# enabled arm — their absence means the profiler lost its dispatch hook.
+# merge.gather) so those two are prefix-matched. scan.* spans are the
+# executor's host stages (assemble, gather, h2d, d2h, remap) — their absence
+# means the executor lost its host-side instrumentation.
 REQUIRED_SPANS = ["queue.wait", "flush", "wal.fsync"]
-REQUIRED_PREFIXES = ["dispatch.", "merge.", "profile."]
+REQUIRED_PREFIXES = ["dispatch.", "merge.", "scan."]
 
 
 def check(bench_path: str, trace_path: str, max_ratio: float) -> list:

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from ..obs.trace import to_device, to_host
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
@@ -67,7 +68,7 @@ def _pow2_pad(x: np.ndarray, lo: int = 256) -> np.ndarray:
     return np.concatenate([x, x[reps]], axis=0)
 
 
-def _pad_centroids(centroids: np.ndarray) -> jax.Array:
+def _pad_centroids(centroids: np.ndarray) -> np.ndarray:
     """Centroids zero-padded to a power-of-two count: with ``k`` passed as
     data, a program serves every partition whose count rounds to the same
     size (an index build otherwise compiles its k-means and probe programs
@@ -75,7 +76,7 @@ def _pad_centroids(centroids: np.ndarray) -> jax.Array:
     k, d = centroids.shape
     out = np.zeros((_pow2(k, 8), d), np.float32)
     out[:k] = centroids
-    return jnp.asarray(out)
+    return out
 
 
 def train_kmeans(
@@ -101,7 +102,7 @@ def train_kmeans(
     x = _pow2_pad(np.asarray(x, dtype=np.float32))
     # k-means++-lite init: random distinct points.
     init_idx = rng.choice(x.shape[0], size=k, replace=False)
-    centroids = _pad_centroids(x[init_idx])
+    centroids = jnp.asarray(_pad_centroids(x[init_idx]))
     kp = centroids.shape[0]
     x_dev = jnp.asarray(x)
     for _ in range(iters):
@@ -122,7 +123,7 @@ def assign_kmeans(vectors: np.ndarray, centroids: np.ndarray, *, metric: str = "
     the tail chunk is pow2-padded so jit sees O(log n) shapes)."""
     n = vectors.shape[0]
     out = np.empty(n, dtype=np.int32)
-    cents = _pad_centroids(np.asarray(centroids, dtype=np.float32))
+    cents = jnp.asarray(_pad_centroids(np.asarray(centroids, dtype=np.float32)))
     k = centroids.shape[0]
     for s in range(0, n, chunk):
         e = min(s + chunk, n)
@@ -138,5 +139,7 @@ def topm_centroids(query_vectors: np.ndarray, centroids: np.ndarray, m: int, *, 
     if nq == 0:
         return np.zeros((0, m), np.int32)
     q = _pow2_pad(np.asarray(query_vectors, dtype=np.float32), lo=8)
-    idx = _topm(jnp.asarray(q), _pad_centroids(np.asarray(centroids, dtype=np.float32)), k, m, metric)
-    return np.asarray(idx, dtype=np.int32)[:nq]
+    cents = _pad_centroids(np.asarray(centroids, dtype=np.float32))
+    q_dev, c_dev = to_device("probe.h2d", q, cents)
+    (idx,) = to_host("probe.d2h", _topm(q_dev, c_dev, k, m, metric))
+    return idx.astype(np.int32, copy=False)[:nq]

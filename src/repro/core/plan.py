@@ -24,6 +24,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..obs.trace import get_tracer
 from .arena import PackedArena, ShardedArena
 from .ivf import ScanStats
 
@@ -123,59 +124,66 @@ def build_plan(
     Each query receives one output *slot* per probed posting list (slot ids
     are dense per query, across all tasks); the executor scatters unit top-ks
     into a [m, n_slots, k] candidate tensor and reduces it in one device op.
+
+    Per task, the quantizer call is a ``plan.probe`` span and the grouping
+    that follows a ``plan.group`` span; the shape coalescing is one more.
     """
     cfg = PlanConfig() if cfg is None else cfg
     tq = cfg.tq_unit
     next_slot = np.zeros(m, dtype=np.int64)
     raw: Dict[int, List[WorkUnit]] = {}
+    tracer = get_tracer()
 
     for t_id, task in enumerate(tasks):
         mt = len(task.qrows)
         if mt == 0:
             continue
-        probes = arena.probe(task.part, q_vecs[task.qrows], task.nprobe)  # [mt, np_eff]
-        np_eff = probes.shape[1]
-        slot_base = next_slot[task.qrows].copy()
-        next_slot[task.qrows] += np_eff
+        with tracer.span("plan.probe"):
+            probes = arena.probe(task.part, q_vecs[task.qrows], task.nprobe)  # [mt, np_eff]
+        with tracer.span("plan.group"):
+            np_eff = probes.shape[1]
+            slot_base = next_slot[task.qrows].copy()
+            next_slot[task.qrows] += np_eff
 
-        # invert (query, probe-slot) -> per-list query groups
-        flat_list = probes.reshape(-1).astype(np.int64)
-        flat_q = np.repeat(np.arange(mt, dtype=np.int64), np_eff)
-        flat_slot = np.tile(np.arange(np_eff, dtype=np.int64), mt)
-        sort = np.argsort(flat_list, kind="stable")
-        flat_list, flat_q, flat_slot = flat_list[sort], flat_q[sort], flat_slot[sort]
-        uniq, group_starts = np.unique(flat_list, return_index=True)
-        group_ends = np.append(group_starts[1:], len(flat_list))
+            # invert (query, probe-slot) -> per-list query groups
+            flat_list = probes.reshape(-1).astype(np.int64)
+            flat_q = np.repeat(np.arange(mt, dtype=np.int64), np_eff)
+            flat_slot = np.tile(np.arange(np_eff, dtype=np.int64), mt)
+            sort = np.argsort(flat_list, kind="stable")
+            flat_list, flat_q, flat_slot = flat_list[sort], flat_q[sort], flat_slot[sort]
+            uniq, group_starts = np.unique(flat_list, return_index=True)
+            group_ends = np.append(group_starts[1:], len(flat_list))
 
-        part_row0 = int(arena.part_row[task.part])
-        for g, gs, ge in zip(uniq, group_starts, group_ends):
-            llen = int(arena.list_len[g])
-            if llen == 0:
-                continue
-            nq_group = int(ge - gs)
-            if task.packed_bitmap is not None:
-                s0 = int(arena.list_start[g]) - part_row0
-                n_live = int(task.packed_bitmap[s0 : s0 + llen].sum())
-            else:
-                n_live = llen
-            if stats is not None:
-                stats.tuples_scanned += llen * nq_group
-                stats.dists_computed += n_live * nq_group
-            if n_live == 0:
-                continue  # bitmap kills the whole list: scanned, no distances
-            lp = _next_pow2(llen, cfg.min_list_pad)
-            qs, slots = flat_q[gs:ge], flat_slot[gs:ge]
-            for cs in range(0, nq_group, tq):
-                raw.setdefault(lp, []).append(
-                    WorkUnit(
-                        task=t_id,
-                        glist=int(g),
-                        qrows=task.qrows[qs[cs : cs + tq]],
-                        slots=slot_base[qs[cs : cs + tq]] + slots[cs : cs + tq],
+            part_row0 = int(arena.part_row[task.part])
+            for g, gs, ge in zip(uniq, group_starts, group_ends):
+                llen = int(arena.list_len[g])
+                if llen == 0:
+                    continue
+                nq_group = int(ge - gs)
+                if task.packed_bitmap is not None:
+                    s0 = int(arena.list_start[g]) - part_row0
+                    n_live = int(task.packed_bitmap[s0 : s0 + llen].sum())
+                else:
+                    n_live = llen
+                if stats is not None:
+                    stats.tuples_scanned += llen * nq_group
+                    stats.dists_computed += n_live * nq_group
+                if n_live == 0:
+                    continue  # bitmap kills the whole list: scanned, no distances
+                lp = _next_pow2(llen, cfg.min_list_pad)
+                qs, slots = flat_q[gs:ge], flat_slot[gs:ge]
+                for cs in range(0, nq_group, tq):
+                    raw.setdefault(lp, []).append(
+                        WorkUnit(
+                            task=t_id,
+                            glist=int(g),
+                            qrows=task.qrows[qs[cs : cs + tq]],
+                            slots=slot_base[qs[cs : cs + tq]] + slots[cs : cs + tq],
+                        )
                     )
-                )
 
-    buckets = _coalesce_shapes(raw, cfg.max_bucket_shapes)
+    with tracer.span("plan.group"):
+        buckets = _coalesce_shapes(raw, cfg.max_bucket_shapes)
     return ExecutionPlan(
         tasks=tasks,
         buckets=buckets,

@@ -76,7 +76,7 @@ import numpy as np
 
 from ..kernels import ops as kops
 from ..obs.profile import get_profiler
-from ..obs.trace import fence, get_tracer
+from ..obs.trace import fence, get_tracer, to_device, to_host
 from .arena import PackedArena, ShardedArena
 from .ivf import IVFIndex, ScanStats
 from .plan import (
@@ -153,25 +153,26 @@ def _assemble_bucket(
     tq = plan.tq
     n_packed = arena.n
     W = _next_pow2(len(units), 1) if w_pad is None else w_pad
-    Vrows = np.zeros((W, lp), dtype=np.int64)
-    valid = np.zeros((W, lp), dtype=bool)
-    qrow_of = np.full((W, tq), -1, dtype=np.int64)
-    slot_of = np.zeros((W, tq), dtype=np.int64)
-    for w, u in enumerate(units):
-        s0 = int(arena.list_start[u.glist])
-        llen = int(arena.list_len[u.glist])
-        rows = np.minimum(np.arange(lp) + s0, n_packed - 1)
-        Vrows[w] = rows
-        v_ok = np.arange(lp) < llen
-        task = plan.tasks[u.task]
-        if task.packed_bitmap is not None:
-            pb = task.packed_bitmap
-            local = np.minimum(rows - int(arena.part_row[task.part]), len(pb) - 1)
-            v_ok = v_ok & pb[local]
-        valid[w] = v_ok
-        nq = len(u.qrows)
-        qrow_of[w, :nq] = u.qrows
-        slot_of[w, :nq] = u.slots
+    with get_tracer().span("scan.assemble"):
+        Vrows = np.zeros((W, lp), dtype=np.int64)
+        valid = np.zeros((W, lp), dtype=bool)
+        qrow_of = np.full((W, tq), -1, dtype=np.int64)
+        slot_of = np.zeros((W, tq), dtype=np.int64)
+        for w, u in enumerate(units):
+            s0 = int(arena.list_start[u.glist])
+            llen = int(arena.list_len[u.glist])
+            rows = np.minimum(np.arange(lp) + s0, n_packed - 1)
+            Vrows[w] = rows
+            v_ok = np.arange(lp) < llen
+            task = plan.tasks[u.task]
+            if task.packed_bitmap is not None:
+                pb = task.packed_bitmap
+                local = np.minimum(rows - int(arena.part_row[task.part]), len(pb) - 1)
+                v_ok = v_ok & pb[local]
+            valid[w] = v_ok
+            nq = len(u.qrows)
+            qrow_of[w, :nq] = u.qrows
+            slot_of[w, :nq] = u.slots
     return Vrows, valid, qrow_of, slot_of
 
 
@@ -214,11 +215,12 @@ def execute_plan(
     out_scores = np.full((m, n_slots, k), -np.inf, dtype=np.float32)
     out_idx = np.full((m, n_slots, k), -1, dtype=np.int64)
     _account_candidates(stats, out_scores.nbytes + out_idx.nbytes)
-    d = q_vecs.shape[1]
+    tracer = get_tracer()
 
     for kk, qr, sl, s_w, gidx_w in _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
-        out_scores[qr, sl, :kk] = s_w
-        out_idx[qr, sl, :kk] = gidx_w
+        with tracer.span("merge.scatter"):
+            out_scores[qr, sl, :kk] = s_w
+            out_idx[qr, sl, :kk] = gidx_w
 
     return _fold_extras_and_merge(out_scores, out_idx, extra, plan.n_slots, k)
 
@@ -227,35 +229,40 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
     """Run the f32 scan stage bucket by bucket (one ``workunit_topk`` dispatch
     each), yielding (kk, qrows, slots, scores [n, kk], gids [n, kk]) for the
     real unit slots — the scatter destination is the only thing the dense and
-    segmented layouts disagree on, so the scan math lives here once."""
+    segmented layouts disagree on, so the scan math lives here once. Each
+    bucket's host stages are spans of their own (``scan.assemble``,
+    ``scan.gather``, ``scan.h2d``, ``dispatch.scan``, ``scan.d2h``,
+    ``scan.remap``); the caller's scatter is ``merge.scatter``."""
     m, k, tq = plan.m, plan.k, plan.tq
     d = q_vecs.shape[1]
     prof = get_profiler()
+    tracer = get_tracer()
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         W = Vrows.shape[0]
-        Q = np.zeros((W, tq, d), dtype=np.float32)
-        wmask = qrow_of >= 0  # [W, tq]
-        Q[wmask] = q_vecs[qrow_of[wmask]]
-        V = arena.packed[Vrows]  # [W, lp, d] — one gather across all partitions
+        with tracer.span("scan.gather"):
+            Q = np.zeros((W, tq, d), dtype=np.float32)
+            wmask = qrow_of >= 0  # [W, tq]
+            Q[wmask] = q_vecs[qrow_of[wmask]]
+            V = arena.packed[Vrows]  # [W, lp, d] — one gather across all partitions
         if stats is not None:
             # real work units only (pow2 pad excluded), so the figure is
             # comparable across configurations — the sharded executor counts
             # the same way per rank
             stats.bytes_scanned += len(units) * lp * d * 4
+        operands = to_device("scan.h2d", Q, V, valid)
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span("dispatch.scan", mode="f32", lp=lp, units=len(units)):
+        with tracer.span("dispatch.scan", mode="f32", lp=lp, units=len(units)):
             s, i_loc = kops.workunit_topk(
-                jnp.asarray(Q),
-                jnp.asarray(V),
-                jnp.asarray(valid),
+                *operands,
                 min(k, lp),
                 metric=arena.metric,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)  # device time is real iff tracing is on
+        del operands  # one bucket's operands on the device at a time
         if prof.enabled:
             # real distance work: 2·d MACs per (query, live row) pair within
             # each unit; padded work covers the full [W, tq, lp] bucket
@@ -270,17 +277,19 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
                 units=len(units), units_padded=W,
                 rows=int(rows_u.sum()), rows_padded=W * lp,
             )
-        s = np.asarray(s)
-        i_loc = np.asarray(i_loc)  # index within the unit's lp rows (-1 = none)
+        # i_loc: index within the unit's lp rows (-1 = none)
+        s, i_loc = to_host("scan.d2h", s, i_loc)
         kk = s.shape[-1]
-        packed_rows = np.take_along_axis(
-            np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-            np.maximum(i_loc, 0),
-            axis=2,
-        )
-        gidx = arena.gid[packed_rows]
-        gidx = np.where(i_loc < 0, -1, gidx)
-        yield kk, qrow_of[wmask], slot_of[wmask], s[wmask], gidx[wmask]
+        with tracer.span("scan.remap"):
+            packed_rows = np.take_along_axis(
+                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
+                np.maximum(i_loc, 0),
+                axis=2,
+            )
+            gidx = arena.gid[packed_rows]
+            gidx = np.where(i_loc < 0, -1, gidx)
+            out = (kk, qrow_of[wmask], slot_of[wmask], s[wmask], gidx[wmask])
+        yield out
 
 
 def _plan_seg_counts(plan: ExecutionPlan) -> np.ndarray:
@@ -320,29 +329,32 @@ def _execute_plan_f32_segmented(
     seg_of = np.full(C_pad, m, dtype=np.int32)  # pad rows -> dropped segment
     seg_of[:C_total] = np.repeat(np.arange(m, dtype=np.int32), counts)
     _account_candidates(stats, flat_s.nbytes + flat_i.nbytes)
+    tracer = get_tracer()
 
     for kk, qr, sl, s_w, gidx_w in _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
-        rows = offsets[qr] + sl
-        flat_s[rows, :kk] = s_w
-        flat_i[rows, :kk] = gidx_w
+        with tracer.span("merge.scatter"):
+            rows = offsets[qr] + sl
+            flat_s[rows, :kk] = s_w
+            flat_i[rows, :kk] = gidx_w
 
     # extras take the rows after each query's plan slots (same relative order
     # as the dense layout's extra columns)
-    next_extra = plan_counts.copy()
-    for qrows, es, ei in extra:
-        kk = min(k, es.shape[1])
-        rows = offsets[qrows] + next_extra[qrows]
-        next_extra[qrows] += 1
-        flat_s[rows, :kk] = es[:, :kk]
-        flat_i[rows, :kk] = ei[:, :kk]
+    with tracer.span("merge.scatter"):
+        next_extra = plan_counts.copy()
+        for qrows, es, ei in extra:
+            kk = min(k, es.shape[1])
+            rows = offsets[qrows] + next_extra[qrows]
+            next_extra[qrows] += 1
+            flat_s[rows, :kk] = es[:, :kk]
+            flat_i[rows, :kk] = ei[:, :kk]
 
+    operands = to_device("merge.h2d", flat_s, flat_i, seg_of)
     prof = get_profiler()
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("merge.segmented", m=m, candidates=C_total):
-        top_s, top_i = kops.segmented_merge_topk(
-            jnp.asarray(flat_s), jnp.asarray(flat_i), jnp.asarray(seg_of), m, k
-        )
+    with tracer.span("merge.segmented", m=m, candidates=C_total):
+        top_s, top_i = kops.segmented_merge_topk(*operands, m, k)
         top_s, top_i = fence(top_s, top_i)
+    del operands
     if prof.enabled:
         prof.record_dispatch(
             "merge", "segmented", C_pad, t0,
@@ -351,7 +363,8 @@ def _execute_plan_f32_segmented(
             units=m, units_padded=m,
             rows=C_total, rows_padded=C_pad,
         )
-    return np.asarray(top_s, dtype=np.float32), np.asarray(top_i, dtype=np.int64)
+    top_s, top_i = to_host("merge.d2h", top_s, top_i)
+    return top_s.astype(np.float32, copy=False), top_i.astype(np.int64)
 
 
 def _extra_slot_width(extra: Sequence[ExtraCandidates], m: int) -> int:
@@ -375,15 +388,17 @@ def _fold_extras_and_merge(
     between the f32 and pq paths.
     """
     m = out_scores.shape[0]
-    next_extra = np.full(m, base_slots, dtype=np.int64)
-    for qrows, es, ei in extra:
-        kk = min(k, es.shape[1])
-        slot = next_extra[qrows]
-        next_extra[qrows] += 1
-        out_scores[qrows, slot, :kk] = es[:, :kk]
-        out_idx[qrows, slot, :kk] = ei[:, :kk]
+    with get_tracer().span("merge.scatter"):
+        next_extra = np.full(m, base_slots, dtype=np.int64)
+        for qrows, es, ei in extra:
+            kk = min(k, es.shape[1])
+            slot = next_extra[qrows]
+            next_extra[qrows] += 1
+            out_scores[qrows, slot, :kk] = es[:, :kk]
+            out_idx[qrows, slot, :kk] = ei[:, :kk]
     top_s, top_i = _padded_merge(out_scores.reshape(m, -1), out_idx.reshape(m, -1), k)
-    return np.asarray(top_s, dtype=np.float32), np.asarray(top_i, dtype=np.int64)
+    top_s, top_i = to_host("merge.d2h", top_s, top_i)
+    return top_s.astype(np.float32, copy=False), top_i.astype(np.int64)
 
 
 def _padded_merge(
@@ -393,15 +408,18 @@ def _padded_merge(
     repeated workloads reuse a bounded set of compiled merge shapes)."""
     real_width = flat_s.shape[1]
     width = _next_pow2(real_width, k)
+    tracer = get_tracer()
     if width > real_width:
         padc = width - real_width
-        flat_s = np.pad(flat_s, ((0, 0), (0, padc)), constant_values=-np.inf)
-        flat_i = np.pad(flat_i, ((0, 0), (0, padc)), constant_values=-1)
+        with tracer.span("merge.scatter"):
+            flat_s = np.pad(flat_s, ((0, 0), (0, padc)), constant_values=-np.inf)
+            flat_i = np.pad(flat_i, ((0, 0), (0, padc)), constant_values=-1)
     mq = flat_s.shape[0]
+    operands = to_device("merge.h2d", flat_s, flat_i)
     prof = get_profiler()
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("merge.final", m=mq, width=width):
-        s, i = kops.merge_topk(jnp.asarray(flat_s), jnp.asarray(flat_i), k)
+    with tracer.span("merge.final", m=mq, width=width):
+        s, i = kops.merge_topk(*operands, k)
         s, i = fence(s, i)
     if prof.enabled:
         prof.record_dispatch(
@@ -452,7 +470,9 @@ def _execute_plan_pq(
     )
     lut_pos = np.zeros(m, dtype=np.int64)
     lut_pos[used] = np.arange(len(used))
-    luts_dev = jnp.asarray(adc_tables(arena.pq, q_vecs[used]))  # [U, M, 256]
+    with get_tracer().span("scan.gather"):
+        luts = adc_tables(arena.pq, q_vecs[used])
+    (luts_dev,) = to_device("scan.h2d", luts)  # [U, M, 256]
     _account_lut(stats, int(luts_dev.nbytes), expanded=False)
 
     if cfg.merge_layout == "segmented":
@@ -485,32 +505,36 @@ def _pq_stage_a_dense(
     cand_rows = np.full((m, plan.n_slots, kprime), -1, dtype=np.int64)
     _account_candidates(stats, cand_s.nbytes + cand_rows.nbytes)
     prof = get_profiler()
+    tracer = get_tracer()
 
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         W = Vrows.shape[0]
         wmask = qrow_of >= 0
-        # padding slots map to LUT row 0; their outputs are dropped via wmask
-        luts = jnp.take(
-            luts_dev, jnp.asarray(lut_pos[np.maximum(qrow_of, 0)]), axis=0
-        )  # [W, tq, M, 256], gathered on device
+        with tracer.span("scan.gather"):
+            # padding slots map to LUT row 0; their outputs are dropped via wmask
+            lut_idx = lut_pos[np.maximum(qrow_of, 0)]
+            codes = arena.codes[Vrows]  # [W, lp, M] uint8 — the compressed gather
+        lut_idx_d, codes_d, valid_d = to_device("scan.h2d", lut_idx, codes, valid)
+        luts = jnp.take(luts_dev, lut_idx_d, axis=0)  # [W, tq, M, 256], gathered on device
+        del lut_idx_d
         _account_lut(stats, int(luts.nbytes), expanded=True)
-        codes = arena.codes[Vrows]  # [W, lp, M] uint8 — the compressed gather
         if stats is not None:
             stats.bytes_scanned += len(units) * lp * arena.codes.shape[1]
         kk = min(kprime, lp)
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span("dispatch.scan", mode="pq", lp=lp, units=len(units)):
+        with tracer.span("dispatch.scan", mode="pq", lp=lp, units=len(units)):
             s, i_loc = kops.workunit_pq_topk(
-                jnp.asarray(luts),
-                jnp.asarray(codes),
-                jnp.asarray(valid),
+                luts,
+                codes_d,
+                valid_d,
                 kk,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)
+        del codes_d, valid_d
         if prof.enabled:
             # one-hot MXU contraction: 2·M·256 MACs per (query, live row)
             M = codes.shape[2]
@@ -525,24 +549,27 @@ def _pq_stage_a_dense(
                 units=len(units), units_padded=W,
                 rows=int(rows_u.sum()), rows_padded=W * lp,
             )
-        s = np.asarray(s)
-        i_loc = np.asarray(i_loc)  # [W, tq, kk] index into the unit's lp rows
-        packed_rows = np.take_along_axis(
-            np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-            np.maximum(i_loc, 0),
-            axis=2,
-        )
-        packed_rows = np.where(i_loc < 0, -1, packed_rows)
-        qr = qrow_of[wmask]
-        sl = slot_of[wmask]
-        cand_s[qr, sl, :kk] = s[wmask]
-        cand_rows[qr, sl, :kk] = packed_rows[wmask]
+        # i_loc: [W, tq, kk] index into the unit's lp rows
+        s, i_loc = to_host("scan.d2h", s, i_loc)
+        with tracer.span("scan.remap"):
+            packed_rows = np.take_along_axis(
+                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
+                np.maximum(i_loc, 0),
+                axis=2,
+            )
+            packed_rows = np.where(i_loc < 0, -1, packed_rows)
+        with tracer.span("merge.scatter"):
+            qr = qrow_of[wmask]
+            sl = slot_of[wmask]
+            cand_s[qr, sl, :kk] = s[wmask]
+            cand_rows[qr, sl, :kk] = packed_rows[wmask]
 
     # per-query top-k' ADC candidates across every bucket and probe slot
     _, top_rows = _padded_merge(
         cand_s.reshape(m, -1), cand_rows.reshape(m, -1), kprime
     )
-    return np.asarray(top_rows, dtype=np.int64)  # [m, k'] packed rows (-1 pad)
+    (top_rows,) = to_host("merge.d2h", top_rows)
+    return top_rows.astype(np.int64)  # [m, k'] packed rows (-1 pad)
 
 
 def _pq_stage_a_segmented(
@@ -574,28 +601,30 @@ def _pq_stage_a_segmented(
     seg_of[:C_total] = np.repeat(np.arange(m, dtype=np.int32), counts)
     _account_candidates(stats, flat_s.nbytes + flat_rows.nbytes)
     prof = get_profiler()
+    tracer = get_tracer()
 
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         wmask = qrow_of >= 0
-        lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
-        codes = arena.codes[Vrows]  # [W, lp, M] uint8
+        with tracer.span("scan.gather"):
+            lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
+            codes = arena.codes[Vrows]  # [W, lp, M] uint8
+        operands = to_device("scan.h2d", lut_idx, codes, valid)
         if stats is not None:
             stats.bytes_scanned += len(units) * lp * arena.codes.shape[1]
         kk = min(kprime, lp)
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span("dispatch.scan", mode="pq-res", lp=lp, units=len(units)):
+        with tracer.span("dispatch.scan", mode="pq-res", lp=lp, units=len(units)):
             s, i_loc = kops.workunit_pq_topk_resident(
                 luts_dev,
-                jnp.asarray(lut_idx),
-                jnp.asarray(codes),
-                jnp.asarray(valid),
+                *operands,
                 kk,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)
+        del operands
         if prof.enabled:
             # the resident path streams one [M, 256] LUT row per LIVE query
             # slot instead of expanding [W, tq, M, 256]
@@ -612,25 +641,26 @@ def _pq_stage_a_segmented(
                 units=len(units), units_padded=W,
                 rows=int(rows_u.sum()), rows_padded=W * lp,
             )
-        s = np.asarray(s)
-        i_loc = np.asarray(i_loc)
-        packed_rows = np.take_along_axis(
-            np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-            np.maximum(i_loc, 0),
-            axis=2,
-        )
-        packed_rows = np.where(i_loc < 0, -1, packed_rows)
-        qr = qrow_of[wmask]
-        rows_f = offsets[qr] + slot_of[wmask]
-        flat_s[rows_f, :kk] = s[wmask]
-        flat_rows[rows_f, :kk] = packed_rows[wmask]
+        s, i_loc = to_host("scan.d2h", s, i_loc)
+        with tracer.span("scan.remap"):
+            packed_rows = np.take_along_axis(
+                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
+                np.maximum(i_loc, 0),
+                axis=2,
+            )
+            packed_rows = np.where(i_loc < 0, -1, packed_rows)
+        with tracer.span("merge.scatter"):
+            qr = qrow_of[wmask]
+            rows_f = offsets[qr] + slot_of[wmask]
+            flat_s[rows_f, :kk] = s[wmask]
+            flat_rows[rows_f, :kk] = packed_rows[wmask]
 
+    operands = to_device("merge.h2d", flat_s, flat_rows, seg_of)
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("merge.segmented", m=m, candidates=C_total):
-        _, top_rows = kops.segmented_merge_topk(
-            jnp.asarray(flat_s), jnp.asarray(flat_rows), jnp.asarray(seg_of), m, kprime
-        )
+    with tracer.span("merge.segmented", m=m, candidates=C_total):
+        _, top_rows = kops.segmented_merge_topk(*operands, m, kprime)
         top_rows = fence(top_rows)
+    del operands
     if prof.enabled:
         prof.record_dispatch(
             "merge", "segmented", C_pad, t0,
@@ -640,7 +670,8 @@ def _pq_stage_a_segmented(
             units=m, units_padded=m,
             rows=C_total, rows_padded=C_pad,
         )
-    return np.asarray(top_rows, dtype=np.int64)
+    (top_rows,) = to_host("merge.d2h", top_rows)
+    return top_rows.astype(np.int64)
 
 
 def _pq_rerank_and_fold(
@@ -661,28 +692,30 @@ def _pq_rerank_and_fold(
     # Units are per-query (TQ=1) so each query re-scores only ITS candidates;
     # m pads to a power of two for compile-shape reuse.
     mp = _next_pow2(m, 1)
-    Qr = np.zeros((mp, 1, d), dtype=np.float32)
-    Qr[:m, 0] = q_vecs
-    Vr = np.zeros((mp, kprime, d), dtype=np.float32)
-    Vr[:m] = arena.packed[np.maximum(rows, 0)]
-    valid_r = np.zeros((mp, kprime), dtype=bool)
-    valid_r[:m] = rows >= 0
+    tracer = get_tracer()
+    with tracer.span("rerank.gather"):
+        Qr = np.zeros((mp, 1, d), dtype=np.float32)
+        Qr[:m, 0] = q_vecs
+        Vr = np.zeros((mp, kprime, d), dtype=np.float32)
+        Vr[:m] = arena.packed[np.maximum(rows, 0)]
+        valid_r = np.zeros((mp, kprime), dtype=bool)
+        valid_r[:m] = rows >= 0
     if stats is not None:
         # real surviving candidates only (matches the sharded re-rank)
         stats.bytes_scanned += int((rows >= 0).sum()) * d * 4
+    operands = to_device("rerank.h2d", Qr, Vr, valid_r)
     prof = get_profiler()
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("rerank.exact", m=m, kprime=kprime):
+    with tracer.span("rerank.exact", m=m, kprime=kprime):
         s, i_loc = kops.workunit_topk(
-            jnp.asarray(Qr),
-            jnp.asarray(Vr),
-            jnp.asarray(valid_r),
+            *operands,
             min(k, kprime),
             metric=arena.metric,
             use_pallas=cfg.use_pallas,
             interpret=cfg.interpret,
         )
         s, i_loc = fence(s, i_loc)
+    del operands
     if prof.enabled:
         n_real = int((rows >= 0).sum())
         prof.record_dispatch(
@@ -694,12 +727,14 @@ def _pq_rerank_and_fold(
             units=m, units_padded=mp,
             rows=n_real, rows_padded=mp * kprime,
         )
-    s = np.asarray(s)[:m, 0]  # [m, kk] exact scores
-    i_loc = np.asarray(i_loc)[:m, 0]  # [m, kk] index into the k' candidates
+    s, i_loc = to_host("rerank.d2h", s, i_loc)
+    s = s[:m, 0]  # [m, kk] exact scores
+    i_loc = i_loc[:m, 0]  # [m, kk] index into the k' candidates
     kk = s.shape[-1]
-    packed_rows = np.take_along_axis(rows, np.maximum(i_loc, 0).astype(np.int64), axis=1)
-    gidx = np.where(i_loc < 0, -1, arena.gid[np.maximum(packed_rows, 0)])
-    gidx = np.where(packed_rows < 0, -1, gidx)
+    with tracer.span("rerank.remap"):
+        packed_rows = np.take_along_axis(rows, np.maximum(i_loc, 0).astype(np.int64), axis=1)
+        gidx = np.where(i_loc < 0, -1, arena.gid[np.maximum(packed_rows, 0)])
+        gidx = np.where(packed_rows < 0, -1, gidx)
 
     # final merge: re-ranked (exact) plan results in slot 0 + host-side exact
     # extras in the columns after it — the same tail as the f32 path
@@ -707,8 +742,9 @@ def _pq_rerank_and_fold(
     out_scores = np.full((m, n_slots, k), -np.inf, dtype=np.float32)
     out_idx = np.full((m, n_slots, k), -1, dtype=np.int64)
     _account_candidates(stats, out_scores.nbytes + out_idx.nbytes)
-    out_scores[:, 0, :kk] = np.where(gidx >= 0, s, -np.inf)
-    out_idx[:, 0, :kk] = gidx
+    with tracer.span("merge.scatter"):
+        out_scores[:, 0, :kk] = np.where(gidx >= 0, s, -np.inf)
+        out_idx[:, 0, :kk] = gidx
     return _fold_extras_and_merge(out_scores, out_idx, extra, 1, k)
 
 
@@ -851,8 +887,9 @@ def _assemble_bucket_stacked(
     wmask = qrow_of >= 0
     Q = None
     if with_q:
-        Q = np.zeros((R, W, tq, d), dtype=np.float32)
-        Q[wmask] = q_vecs[qrow_of[wmask]]
+        with get_tracer().span("scan.gather"):
+            Q = np.zeros((R, W, tq, d), dtype=np.float32)
+            Q[wmask] = q_vecs[qrow_of[wmask]]
     return unit_lists, Q, valid, qrow_of, slot_of, Vrows, wmask
 
 
@@ -891,17 +928,19 @@ def _gather_merge(
     flat_i = cand_i.reshape(R, m, -1)
     real_width = flat_s.shape[2]
     width = _next_pow2(real_width, k)
+    tracer = get_tracer()
     if width > real_width:
         padc = width - real_width
-        flat_s = np.pad(flat_s, ((0, 0), (0, 0), (0, padc)), constant_values=-np.inf)
-        flat_i = np.pad(flat_i, ((0, 0), (0, 0), (0, padc)), constant_values=-1)
+        with tracer.span("merge.scatter"):
+            flat_s = np.pad(flat_s, ((0, 0), (0, 0), (0, padc)), constant_values=-np.inf)
+            flat_i = np.pad(flat_i, ((0, 0), (0, 0), (0, padc)), constant_values=-1)
+    operands = to_device("merge.h2d", flat_s, flat_i)
     prof = get_profiler()
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("merge.gather", ranks=R, m=m, width=width):
-        ms, mi = kops.sharded_merge_topk(
-            mesh, axis, jnp.asarray(flat_s), jnp.asarray(flat_i), k
-        )
+    with tracer.span("merge.gather", ranks=R, m=m, width=width):
+        ms, mi = kops.sharded_merge_topk(mesh, axis, *operands, k)
         ms, mi = fence(ms, mi)
+    del operands
     if prof.enabled:
         prof.record_dispatch(
             "gather", "sharded", width, t0,
@@ -910,7 +949,8 @@ def _gather_merge(
             units=R * m, units_padded=R * m,
             rows=R * m * real_width, rows_padded=R * m * width,
         )
-    return np.asarray(ms, dtype=np.float32), np.asarray(mi, dtype=np.int64)
+    ms, mi = to_host("merge.d2h", ms, mi)
+    return ms.astype(np.float32, copy=False), mi.astype(np.int64)
 
 
 def _rank_segments(
@@ -981,33 +1021,37 @@ def _execute_sharded_f32(
         cand_i = np.full((R, m, n_slots, k), -1, dtype=np.int64)
         _account_candidates(stats, cand_s.nbytes + cand_i.nbytes)
 
+    tracer = get_tracer()
     for lp in splan.pads:
         unit_lists, Q, valid, qrow_of, slot_of, Vrows, wmask = _assemble_bucket_stacked(
             splan, sharded, lp, q_vecs
         )
-        V = np.zeros(valid.shape + (d,), dtype=np.float32)
-        for r in range(R):
-            if not unit_lists[r]:
-                continue
-            V[r] = arena.packed[Vrows[r]]
-            sstats.per_rank_bytes[r] += len(unit_lists[r]) * lp * d * 4
-            sstats.per_rank_dispatches[r] += 1
+        with tracer.span("scan.gather"):
+            V = np.zeros(valid.shape + (d,), dtype=np.float32)
+            for r in range(R):
+                if not unit_lists[r]:
+                    continue
+                V[r] = arena.packed[Vrows[r]]
+                sstats.per_rank_bytes[r] += len(unit_lists[r]) * lp * d * 4
+                sstats.per_rank_dispatches[r] += 1
         if stats is not None:
             stats.bytes_scanned += int(sum(len(u) for u in unit_lists)) * lp * d * 4
         kk = min(k, lp)
         rank_units = [len(u) for u in unit_lists]
+        operands = to_device("scan.h2d", Q, V, valid)
         prof = get_profiler()
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span(
+        with tracer.span(
             "dispatch.sharded", mode="f32", lp=lp, rank_units=rank_units,
         ):
             s, i_loc = kops.sharded_workunit_topk(
                 mesh, axis,
-                jnp.asarray(Q), jnp.asarray(V), jnp.asarray(valid), kk,
+                *operands, kk,
                 metric=arena.metric,
                 use_pallas=cfg.use_pallas, interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)
+        del operands
         if prof.enabled:
             W_ = valid.shape[1]
             tq_ = splan.plan.tq
@@ -1024,38 +1068,40 @@ def _execute_sharded_f32(
                 rank_units=rank_units,
                 rank_bytes=[n * lp * d * 4 for n in rank_units],
             )
-        s = np.asarray(s)
-        i_loc = np.asarray(i_loc)  # [R, W, tq, kk] index into the unit's lp rows
-        for r in range(R):
-            if not unit_lists[r]:
-                continue
-            packed_rows = np.take_along_axis(
-                np.broadcast_to(Vrows[r][:, None, :], i_loc[r].shape[:2] + (lp,)),
-                np.maximum(i_loc[r], 0),
-                axis=2,
-            )
-            gidx = arena.gid[packed_rows]
-            gidx = np.where(i_loc[r] < 0, -1, gidx)
-            qr, sl = qrow_of[r][wmask[r]], slot_of[r][wmask[r]]
-            if segmented:
-                rows = base[r] + np.searchsorted(rank_keys[r], qr * S + sl)
-                flat_s[rows, :kk] = s[r][wmask[r]]
-                flat_i[rows, :kk] = gidx[wmask[r]]
-            else:
-                cand_s[r, qr, sl, :kk] = s[r][wmask[r]]
-                cand_i[r, qr, sl, :kk] = gidx[wmask[r]]
+        # i_loc: [R, W, tq, kk] index into the unit's lp rows
+        s, i_loc = to_host("scan.d2h", s, i_loc)
+        live = [r for r in range(R) if unit_lists[r]]
+        with tracer.span("scan.remap"):
+            gidx = {}
+            for r in live:
+                packed_rows = np.take_along_axis(
+                    np.broadcast_to(Vrows[r][:, None, :], i_loc[r].shape[:2] + (lp,)),
+                    np.maximum(i_loc[r], 0),
+                    axis=2,
+                )
+                gidx[r] = np.where(i_loc[r] < 0, -1, arena.gid[packed_rows])
+        with tracer.span("merge.scatter"):
+            for r in live:
+                qr, sl = qrow_of[r][wmask[r]], slot_of[r][wmask[r]]
+                if segmented:
+                    rows = base[r] + np.searchsorted(rank_keys[r], qr * S + sl)
+                    flat_s[rows, :kk] = s[r][wmask[r]]
+                    flat_i[rows, :kk] = gidx[r][wmask[r]]
+                else:
+                    cand_s[r, qr, sl, :kk] = s[r][wmask[r]]
+                    cand_i[r, qr, sl, :kk] = gidx[r][wmask[r]]
 
     if segmented:
         # one ragged merge over R·m segments = every rank's local top-k; the
         # gather merge's rank-local reduction over these already-sorted rows
         # is an identity, so the all-gather sees the dense path's operands
+        operands = to_device("merge.h2d", flat_s, flat_i, seg_of)
         prof = get_profiler()
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span("merge.segmented", m=R * m, candidates=int(base[-1])):
-            seg_s, seg_i = kops.segmented_merge_topk(
-                jnp.asarray(flat_s), jnp.asarray(flat_i), jnp.asarray(seg_of), R * m, k
-            )
+        with tracer.span("merge.segmented", m=R * m, candidates=int(base[-1])):
+            seg_s, seg_i = kops.segmented_merge_topk(*operands, R * m, k)
             seg_s, seg_i = fence(seg_s, seg_i)
+        del operands
         if prof.enabled:
             prof.record_dispatch(
                 "merge", "segmented", C_pad, t0,
@@ -1065,10 +1111,11 @@ def _execute_sharded_f32(
                 units=R * m, units_padded=R * m,
                 rows=int(base[-1]), rows_padded=C_pad,
             )
+        seg_s, seg_i = to_host("merge.d2h", seg_s, seg_i)
         ms, mi = _gather_merge(
             mesh, axis,
-            np.asarray(seg_s, dtype=np.float32).reshape(R, m, 1, k),
-            np.asarray(seg_i, dtype=np.int64).reshape(R, m, 1, k),
+            seg_s.astype(np.float32, copy=False).reshape(R, m, 1, k),
+            seg_i.astype(np.int64).reshape(R, m, 1, k),
             k,
         )
     else:
@@ -1115,7 +1162,9 @@ def _execute_sharded_pq(
     )
     lut_pos = np.zeros(m, dtype=np.int64)
     lut_pos[used] = np.arange(len(used))
-    luts_dev = jnp.asarray(adc_tables(arena.pq, q_vecs[used]))  # [U, M, 256]
+    with get_tracer().span("scan.gather"):
+        luts = adc_tables(arena.pq, q_vecs[used])
+    (luts_dev,) = to_device("scan.h2d", luts)  # [U, M, 256]
     _account_lut(stats, int(luts_dev.nbytes), expanded=False)
 
     n_slots = splan.plan.n_slots
@@ -1133,20 +1182,22 @@ def _execute_sharded_pq(
         cand_rows = np.full((R, m, n_slots, kprime), -1, dtype=np.int64)
         _account_candidates(stats, cand_s.nbytes + cand_rows.nbytes)
 
+    tracer = get_tracer()
     for lp in splan.pads:
         unit_lists, _, valid, qrow_of, slot_of, Vrows, wmask = _assemble_bucket_stacked(
             splan, sharded, lp, q_vecs, with_q=False
         )
-        codes = np.zeros(valid.shape + (M,), dtype=np.uint8)
-        for r in range(R):
-            if not unit_lists[r]:
-                continue
-            codes[r] = arena.codes[Vrows[r]]
-            sstats.per_rank_bytes[r] += len(unit_lists[r]) * lp * M
-            sstats.per_rank_dispatches[r] += 1
+        with tracer.span("scan.gather"):
+            codes = np.zeros(valid.shape + (M,), dtype=np.uint8)
+            for r in range(R):
+                if not unit_lists[r]:
+                    continue
+                codes[r] = arena.codes[Vrows[r]]
+                sstats.per_rank_bytes[r] += len(unit_lists[r]) * lp * M
+                sstats.per_rank_dispatches[r] += 1
+            lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # padding slots -> LUT row 0
         if stats is not None:
             stats.bytes_scanned += int(sum(len(u) for u in unit_lists)) * lp * M
-        lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # padding slots -> LUT row 0
         kk = min(kprime, lp)
         rank_units = [len(u) for u in unit_lists]
         if not segmented:
@@ -1158,18 +1209,20 @@ def _execute_sharded_pq(
             _account_lut(
                 stats, R * W * tq * M * 256 * 4, expanded=True
             )
+        operands = to_device("scan.h2d", lut_idx, codes, valid)
         prof = get_profiler()
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span(
+        with tracer.span(
             "dispatch.sharded", mode="pq", lp=lp, rank_units=rank_units
         ):
             s, i_loc = kops.sharded_workunit_pq_topk(
                 mesh, axis,
-                luts_dev, jnp.asarray(lut_idx), jnp.asarray(codes), jnp.asarray(valid), kk,
+                luts_dev, *operands, kk,
                 use_pallas=cfg.use_pallas, interpret=cfg.interpret,
                 stream=segmented,
             )
             s, i_loc = fence(s, i_loc)
+        del operands
         if prof.enabled:
             W_ = valid.shape[1]
             tq_ = splan.plan.tq
@@ -1188,37 +1241,38 @@ def _execute_sharded_pq(
                 rank_units=rank_units,
                 rank_bytes=[n * lp * M for n in rank_units],
             )
-        s = np.asarray(s)
-        i_loc = np.asarray(i_loc)
-        for r in range(R):
-            if not unit_lists[r]:
-                continue
-            packed_rows = np.take_along_axis(
-                np.broadcast_to(Vrows[r][:, None, :], i_loc[r].shape[:2] + (lp,)),
-                np.maximum(i_loc[r], 0),
-                axis=2,
-            )
-            packed_rows = np.where(i_loc[r] < 0, -1, packed_rows)  # global rows
-            qr, sl = qrow_of[r][wmask[r]], slot_of[r][wmask[r]]
-            if segmented:
-                rws = base[r] + np.searchsorted(rank_keys[r], qr * S + sl)
-                flat_s[rws, :kk] = s[r][wmask[r]]
-                flat_rows[rws, :kk] = packed_rows[wmask[r]]
-            else:
-                cand_s[r, qr, sl, :kk] = s[r][wmask[r]]
-                cand_rows[r, qr, sl, :kk] = packed_rows[wmask[r]]
+        s, i_loc = to_host("scan.d2h", s, i_loc)
+        live = [r for r in range(R) if unit_lists[r]]
+        with tracer.span("scan.remap"):
+            packed = {}
+            for r in live:
+                packed_rows = np.take_along_axis(
+                    np.broadcast_to(Vrows[r][:, None, :], i_loc[r].shape[:2] + (lp,)),
+                    np.maximum(i_loc[r], 0),
+                    axis=2,
+                )
+                packed[r] = np.where(i_loc[r] < 0, -1, packed_rows)  # global rows
+        with tracer.span("merge.scatter"):
+            for r in live:
+                qr, sl = qrow_of[r][wmask[r]], slot_of[r][wmask[r]]
+                if segmented:
+                    rws = base[r] + np.searchsorted(rank_keys[r], qr * S + sl)
+                    flat_s[rws, :kk] = s[r][wmask[r]]
+                    flat_rows[rws, :kk] = packed[r][wmask[r]]
+                else:
+                    cand_s[r, qr, sl, :kk] = s[r][wmask[r]]
+                    cand_rows[r, qr, sl, :kk] = packed[r][wmask[r]]
 
     # global top-k' ADC candidates: k'·|model| gather, identical selection to
     # the single-device merge (a global survivor survives locally too)
     if segmented:
+        operands = to_device("merge.h2d", flat_s, flat_rows, seg_of)
         prof = get_profiler()
         t0 = prof.t0() if prof.enabled else 0
-        with get_tracer().span("merge.segmented", m=R * m, candidates=int(base[-1])):
-            seg_s, seg_i = kops.segmented_merge_topk(
-                jnp.asarray(flat_s), jnp.asarray(flat_rows), jnp.asarray(seg_of),
-                R * m, kprime,
-            )
+        with tracer.span("merge.segmented", m=R * m, candidates=int(base[-1])):
+            seg_s, seg_i = kops.segmented_merge_topk(*operands, R * m, kprime)
             seg_s, seg_i = fence(seg_s, seg_i)
+        del operands
         if prof.enabled:
             prof.record_dispatch(
                 "merge", "segmented", C_pad, t0,
@@ -1228,10 +1282,11 @@ def _execute_sharded_pq(
                 units=R * m, units_padded=R * m,
                 rows=int(base[-1]), rows_padded=C_pad,
             )
+        seg_s, seg_i = to_host("merge.d2h", seg_s, seg_i)
         _, top_rows = _gather_merge(
             mesh, axis,
-            np.asarray(seg_s, dtype=np.float32).reshape(R, m, 1, kprime),
-            np.asarray(seg_i, dtype=np.int64).reshape(R, m, 1, kprime),
+            seg_s.astype(np.float32, copy=False).reshape(R, m, 1, kprime),
+            seg_i.astype(np.int64).reshape(R, m, 1, kprime),
             kprime,
         )
     else:
@@ -1241,33 +1296,36 @@ def _execute_sharded_pq(
 
     # sharded exact re-rank: rank r rescans the surviving rows IT stores
     mp = _next_pow2(m, 1)
-    Qr = np.zeros((R, mp, 1, d), dtype=np.float32)
-    Qr[:, :m, 0] = q_vecs[None]
-    Vr = np.zeros((R, mp, kprime, d), dtype=np.float32)
-    valid_r = np.zeros((R, mp, kprime), dtype=bool)
-    owner = sharded.owner_of_row(np.maximum(rows, 0))
-    for r in range(R):
-        own = (owner == r) & (rows >= 0)
-        if not own.any():
-            continue
-        sel = arena.packed[rows[own]]
-        Vr[r, :m][own] = sel
-        valid_r[r, :m] = own
-        sstats.per_rank_bytes[r] += sel.nbytes
-        sstats.per_rank_dispatches[r] += 1
-        if stats is not None:
-            stats.bytes_scanned += sel.nbytes
+    with tracer.span("rerank.gather"):
+        Qr = np.zeros((R, mp, 1, d), dtype=np.float32)
+        Qr[:, :m, 0] = q_vecs[None]
+        Vr = np.zeros((R, mp, kprime, d), dtype=np.float32)
+        valid_r = np.zeros((R, mp, kprime), dtype=bool)
+        owner = sharded.owner_of_row(np.maximum(rows, 0))
+        for r in range(R):
+            own = (owner == r) & (rows >= 0)
+            if not own.any():
+                continue
+            sel = arena.packed[rows[own]]
+            Vr[r, :m][own] = sel
+            valid_r[r, :m] = own
+            sstats.per_rank_bytes[r] += sel.nbytes
+            sstats.per_rank_dispatches[r] += 1
+            if stats is not None:
+                stats.bytes_scanned += sel.nbytes
     kk = min(k, kprime)
+    operands = to_device("rerank.h2d", Qr, Vr, valid_r)
     prof = get_profiler()
     t0 = prof.t0() if prof.enabled else 0
-    with get_tracer().span("rerank.exact", mode="sharded", m=m, kprime=kprime):
+    with tracer.span("rerank.exact", mode="sharded", m=m, kprime=kprime):
         s, i_loc = kops.sharded_workunit_topk(
             mesh, axis,
-            jnp.asarray(Qr), jnp.asarray(Vr), jnp.asarray(valid_r), kk,
+            *operands, kk,
             metric=arena.metric,
             use_pallas=cfg.use_pallas, interpret=cfg.interpret,
         )
         s, i_loc = fence(s, i_loc)
+    del operands
     if prof.enabled:
         n_real = int(valid_r.sum())
         prof.record_dispatch(
@@ -1278,15 +1336,17 @@ def _execute_sharded_pq(
             units=m, units_padded=R * mp,
             rows=n_real, rows_padded=R * mp * kprime,
         )
-    s = np.asarray(s)[:, :m, 0]  # [R, m, kk] exact partial scores
-    i_loc = np.asarray(i_loc)[:, :m, 0]  # [R, m, kk] index into the k' candidates
-    rows_b = np.broadcast_to(rows[None], (R, m, kprime))
-    packed_rows = np.take_along_axis(
-        rows_b, np.maximum(i_loc, 0).astype(np.int64), axis=2
-    )
-    gidx = np.where(i_loc < 0, -1, arena.gid[np.maximum(packed_rows, 0)])
-    gidx = np.where(packed_rows < 0, -1, gidx)
-    sc = np.where(gidx >= 0, s, -np.inf).astype(np.float32)
+    s, i_loc = to_host("rerank.d2h", s, i_loc)
+    s = s[:, :m, 0]  # [R, m, kk] exact partial scores
+    i_loc = i_loc[:, :m, 0]  # [R, m, kk] index into the k' candidates
+    with tracer.span("rerank.remap"):
+        rows_b = np.broadcast_to(rows[None], (R, m, kprime))
+        packed_rows = np.take_along_axis(
+            rows_b, np.maximum(i_loc, 0).astype(np.int64), axis=2
+        )
+        gidx = np.where(i_loc < 0, -1, arena.gid[np.maximum(packed_rows, 0)])
+        gidx = np.where(packed_rows < 0, -1, gidx)
+        sc = np.where(gidx >= 0, s, -np.inf).astype(np.float32)
 
     ms, mi = _gather_merge(
         mesh, axis, sc[:, :, None, :], gidx[:, :, None, :], k

@@ -187,19 +187,6 @@ class KernelProfiler:
                     rr["dispatches"] += 1
                     rr["units"] += int(u)
                     rr["bytes"] += int(b)
-        from .trace import get_tracer
-
-        tracer = get_tracer()
-        if tracer.enabled:
-            tracer.instant(
-                "profile.dispatch",
-                phase=phase,
-                mode=mode,
-                shape=int(shape),
-                device_us=round(dt * 1e6, 2),
-                rows=int(rows),
-                rows_padded=int(rows_padded),
-            )
 
     def _on_issue(self, kind: str, shape) -> None:
         """kernels.ops hook: count every dispatch issued, attributed or not."""

@@ -21,6 +21,22 @@ is free until an operator calls ``enable()``. Device-time honesty: span
 bodies that dispatch async jax work call ``fence(...)`` before closing, which
 ``block_until_ready``s the outputs ONLY when tracing is enabled, so dispatch
 spans measure real device time without perturbing the untraced fast path.
+
+Transfers: every host->device copy on the search path goes through
+``to_device(name, ...)`` and every blocking device->host readback through
+``to_host(name, ...)``. While tracing, each call is one span named
+``<stage>.h2d`` or ``<stage>.d2h`` (``scan``, ``merge``, ``probe``,
+``rerank``) whose ``bytes`` arg is the exact bytes moved: the device arrays'
+bytes, after dtype canonicalization. A ``to_device`` span is fenced, so it
+times the copy itself; a ``to_host`` span opens after its inputs are ready,
+so it times the readback alone, and each one is one host sync. Untraced,
+both are the plain ``jnp.asarray``/``np.asarray`` and build no span
+arguments.
+
+Profiler clock: while it records, the ``Tracer`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name for each ``span``, so a
+profile captured with ``jax.profiler`` shows the program's spans on the host
+plane beside the device ops (the ``NullTracer`` opens none).
 """
 from __future__ import annotations
 
@@ -28,6 +44,8 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+import numpy as np
 
 __all__ = [
     "Tracer",
@@ -37,6 +55,8 @@ __all__ = [
     "enable",
     "disable",
     "fence",
+    "to_device",
+    "to_host",
     "set_thread_name",
     "get_thread_name",
     "validate_chrome_trace",
@@ -114,9 +134,10 @@ class NullTracer:
 
 
 class _Span:
-    """Context manager recording one duration event on exit."""
+    """Context manager recording one duration event on exit; between the two
+    clock readings it holds a profiler annotation of the same name."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "tid", "parent")
+    __slots__ = ("tracer", "name", "args", "t0", "tid", "parent", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]) -> None:
         self.tracer = tracer
@@ -129,9 +150,12 @@ class _Span:
         stack.append(self.name)
         self.tid = threading.get_ident()
         self.t0 = time.perf_counter_ns()
+        self.annotation = self.tracer._annotation(self.name)
+        self.annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self.annotation.__exit__(None, None, None)
         t1 = time.perf_counter_ns()
         stack = self.tracer._stack()
         if stack and stack[-1] == self.name:
@@ -164,6 +188,11 @@ class Tracer:
         # epoch for relative timestamps: the same perf_counter clock the
         # service uses, so add_span can take raw perf_counter floats
         self._t0_ns = time.perf_counter_ns()
+        # each span also annotates the jax profiler's host plane (a no-op
+        # unless a profile is being captured)
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # --------------------------------------------------------------- recording
 
@@ -363,6 +392,37 @@ def fence(*arrays):
 
         jax.block_until_ready(arrays)
     return arrays[0] if len(arrays) == 1 else arrays
+
+
+def to_device(name: str, *arrays) -> list:
+    """``jnp.asarray`` each host array; while tracing, inside one fenced
+    ``name`` span (``<stage>.h2d``) whose ``bytes`` arg is the bytes shipped."""
+    import jax.numpy as jnp
+
+    tracer = _TRACER
+    if not tracer.enabled:
+        return [jnp.asarray(a) for a in arrays]
+    import jax
+
+    with tracer.span(name) as span:
+        out = [jnp.asarray(a) for a in arrays]
+        jax.block_until_ready(out)
+        span.args["bytes"] = sum(int(x.nbytes) for x in out)
+    return out
+
+
+def to_host(name: str, *arrays) -> list:
+    """``np.asarray`` each device array; while tracing, the inputs are made
+    ready first and the copy runs inside one ``name`` span
+    (``<stage>.d2h``: one host sync) whose ``bytes`` arg is the bytes read."""
+    tracer = _TRACER
+    if not tracer.enabled:
+        return [np.asarray(a) for a in arrays]
+    import jax
+
+    jax.block_until_ready(arrays)
+    with tracer.span(name, bytes=sum(int(a.nbytes) for a in arrays)):
+        return [np.asarray(a) for a in arrays]
 
 
 # ---------------------------------------------------------------------------

@@ -6,10 +6,13 @@ M = 8 with k' = 40 over a 2,000-row LUT table), so the TPU compiler's
 refusals (block tiling, VMEM, unaligned slices) show up here instead of on
 the chip. The sharded engine's programs compile over a mesh of the four
 described chips, as ``chip_smoke.py --chips 4`` runs them. Nothing runs:
-shapes only.
+shapes only. The compiled scan kernels and merge programs also keep the
+names the benchmark's device-trace readers match.
 """
 import functools
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +47,21 @@ def one_chip(topo):
 @pytest.fixture(scope="module")
 def four_chips(topo):
     return Mesh(np.asarray(topo.devices[:R]), ("model",))
+
+
+@pytest.fixture(scope="module")
+def readers():
+    """The benchmark's device-trace readers of the scan kernels and the
+    merge programs, and the trace's op record they read."""
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from harness.spec import load_module
+    from harness.trace import Op
+
+    scan = load_module(bench / "metrics" / "scan_roofline.batch.py")
+    merge = load_module(bench / "metrics" / "merge_device_ms.batch.py")
+    return scan.is_scan, merge.is_merge, Op
 
 
 def _compile(fn, sharding, *shapes):
@@ -122,3 +140,32 @@ def test_sharded_gather_merge_compiles_on_four_chips(four_chips):
         ((R, 256, K), jnp.float32), ((R, 256, K), jnp.int32),
     )
     assert "all-gather" in hlo
+
+
+@pytest.mark.parametrize("tv", [32, 512])  # both grids of the engine's scan
+def test_scan_kernel_ops_keep_the_roofline_readers_name(one_chip, readers, tv):
+    is_scan, _, Op = readers
+    hlo = _compile(
+        ops._unit_scan_fn(K, "ip", True, False), one_chip,
+        ((W, TQ, D), jnp.float32), ((W, tv, D), jnp.float32), ((W, tv), jnp.bool_),
+    )
+    kernels = [ln.strip() for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    assert kernels
+    assert all(is_scan(Op(k, "", 0.0, 0.0, 0)) for k in kernels), kernels
+
+
+@pytest.mark.parametrize("merge", ["segmented", "final"])
+def test_merge_programs_keep_the_merge_readers_name(one_chip, readers, merge):
+    _, is_merge, Op = readers
+    rows, n_queries = 16_000, 2_000
+    if merge == "segmented":
+        shapes = ((rows, K), jnp.float32), ((rows, K), jnp.int32), ((rows,), jnp.int32)
+        fn, static = ops._segmented_merge_topk_jnp, dict(n_segments=n_queries, k=K)
+    else:
+        shapes = ((n_queries, 128), jnp.float32), ((n_queries, 128), jnp.int32)
+        fn, static = ops._merge_topk_jnp, dict(k=K)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip) for sh, dt in shapes]
+    hlo = fn.lower(*args, **static).compile().as_text()
+    module = hlo.split(None, 2)[1].rstrip(",")  # "HloModule <name>, ..."
+    # the trace names a program's ops by its module and a fingerprint
+    assert is_merge(Op("%fusion = f32[] fusion()", f"{module}(2233347581744820355)", 0.0, 0.0, 0))
