@@ -5,8 +5,9 @@ work units are bucketed by padded shape across every partition and template.
 This module executes that plan:
 
   1. for each shape bucket, gather ALL its units' posting-list rows through
-     the index-wide ``PackedArena`` (one gather serves every partition) and
-     run them in a single ``kernels.ops.workunit_topk`` dispatch — the
+     the index-wide ``PackedArena`` (one gather serves every partition; in
+     f32 mode on the device, from the arena's resident rows) and run them
+     in a single ``kernels.ops.workunit_topk`` dispatch — the
      single-matmul-per-posting-list of Alg. 3 line 10, fused with the
      Section 4.2 bitmap pushdown, megabatched across the workload;
   2. scatter per-unit top-k into the candidate buffer — by default a flat
@@ -229,48 +230,68 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
     """Run the f32 scan stage bucket by bucket (one ``workunit_topk`` dispatch
     each), yielding (kk, qrows, slots, scores [n, kk], gids [n, kk]) for the
     real unit slots — the scatter destination is the only thing the dense and
-    segmented layouts disagree on, so the scan math lives here once. Each
-    bucket's host stages are spans of their own (``scan.assemble``,
-    ``scan.gather``, ``scan.h2d``, ``dispatch.scan``, ``scan.d2h``,
-    ``scan.remap``); the caller's scatter is ``merge.scatter``."""
+    segmented layouts disagree on, so the scan math lives here once.
+
+    The operands never cross from the host per bucket: the arena's rows are
+    resident on the device (``PackedArena.device_rows``, one upload per
+    arena), the queries are copied once per call (``query.h2d``), and each
+    bucket ships only its units' list starts, query rows and bitmap
+    (``scan.h2d``) for ``ops.gather_unit_operands`` to gather the tiles on
+    the device (``scan.gather``). Each bucket's host stages are spans of
+    their own (``scan.assemble``, ``scan.h2d``, ``scan.gather``,
+    ``dispatch.scan``, ``scan.d2h``, ``scan.remap``); the caller's scatter is
+    ``merge.scatter``."""
+    if not plan.buckets:
+        return
     m, k, tq = plan.m, plan.k, plan.tq
     d = q_vecs.shape[1]
     prof = get_profiler()
     tracer = get_tracer()
+    rows_dev = arena.device_rows()
+    # queries padded to a power of two, so the gather's compiled shapes do
+    # not follow every flush's batch size
+    q_pad = np.zeros((_next_pow2(len(q_vecs), 8), d), dtype=np.float32)
+    q_pad[: len(q_vecs)] = q_vecs
+    (q_dev,) = to_device("query.h2d", q_pad)
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         W = Vrows.shape[0]
-        with tracer.span("scan.gather"):
-            Q = np.zeros((W, tq, d), dtype=np.float32)
-            wmask = qrow_of >= 0  # [W, tq]
-            Q[wmask] = q_vecs[qrow_of[wmask]]
-            V = arena.packed[Vrows]  # [W, lp, d] — one gather across all partitions
+        wmask = qrow_of >= 0  # [W, tq]
         if stats is not None:
             # real work units only (pow2 pad excluded), so the figure is
             # comparable across configurations — the sharded executor counts
             # the same way per rank
             stats.bytes_scanned += len(units) * lp * d * 4
-        operands = to_device("scan.h2d", Q, V, valid)
+        # a unit's rows are min(start + arange(lp), n - 1), so its first row
+        # is its start as the gather reads it (pad units: row 0, all masked)
+        starts_d, qrow_d, valid_d = to_device(
+            "scan.h2d", Vrows[:, 0].astype(np.int32), qrow_of.astype(np.int32), valid
+        )
+        with tracer.span("scan.gather"):
+            Q, V = fence(*kops.gather_unit_operands(rows_dev, q_dev, starts_d, qrow_d, lp=lp))
         t0 = prof.t0() if prof.enabled else 0
         with tracer.span("dispatch.scan", mode="f32", lp=lp, units=len(units)):
             s, i_loc = kops.workunit_topk(
-                *operands,
+                Q,
+                V,
+                valid_d,
                 min(k, lp),
                 metric=arena.metric,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)  # device time is real iff tracing is on
-        del operands  # one bucket's operands on the device at a time
+        del starts_d, qrow_d, valid_d, Q, V  # one bucket's operands at a time
         if prof.enabled:
             # real distance work: 2·d MACs per (query, live row) pair within
             # each unit; padded work covers the full [W, tq, lp] bucket
             nq_u = wmask.sum(axis=1)
             rows_u = valid.sum(axis=1)
+            # the kernel reads the Q and V tiles and the bitmap
             prof.record_dispatch(
                 "scan", "f32", lp, t0,
-                nbytes=Q.nbytes + V.nbytes + valid.nbytes
+                nbytes=W * (tq + lp) * d * 4 + valid.nbytes
                 + W * tq * min(k, lp) * 12,
                 flops=2.0 * d * float((nq_u * rows_u).sum()),
                 flops_padded=2.0 * d * W * tq * lp,

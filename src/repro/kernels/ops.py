@@ -327,6 +327,30 @@ def _workunit_pq_topk_resident_jnp(table, lut_idx, codes, valid, k):
     return _ref.workunit_pq_topk_resident_ref(table, lut_idx, codes, valid, k)
 
 
+@functools.partial(jax.jit, static_argnames=("lp",))
+def gather_unit_operands(
+    rows: jax.Array,  # f32 [N, D] — the arena's resident rows
+    q: jax.Array,  # f32 [MQ, D] — the workload's resident queries
+    starts: jax.Array,  # i32 [W] — first arena row of each unit's posting list
+    qrow_of: jax.Array,  # i32 [W, TQ] — query row per unit slot (-1 pad)
+    lp: int,
+) -> tuple[jax.Array, jax.Array]:
+    """One bucket's ``workunit_topk`` operands, gathered on the device.
+
+    Returns Q f32 [W, TQ, D] (zero rows for pad slots) and V f32 [W, lp, D],
+    unit w's rows being ``min(starts[w] + arange(lp), N - 1)``: the tiles a
+    host gather from the same arrays builds, bit for bit. A program of its
+    own, apart from the scan kernels, so the device trace times the two
+    apart.
+    """
+    n = rows.shape[0]
+    idx = jnp.minimum(starts[:, None] + jnp.arange(lp, dtype=starts.dtype), n - 1)
+    v = jnp.take(rows, idx, axis=0, mode="clip")
+    live = (qrow_of >= 0)[..., None]
+    qt = jnp.take(q, jnp.maximum(qrow_of, 0), axis=0, mode="clip")
+    return jnp.where(live, qt, jnp.zeros((), q.dtype)), v
+
+
 # --------------------------------------------------------------------------
 # Sharded dispatch (device-mesh execution, see core/planner.py's sharded path)
 #

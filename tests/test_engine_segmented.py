@@ -26,10 +26,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import HQIConfig, HQIIndex
+from repro.core import HQIConfig, HQIIndex, planner
+from repro.core.arena import PackedArena
 from repro.core.ivf import IVFIndex, ScanStats
-from repro.core.plan import PlanConfig
-from repro.core.planner import batch_search_ivf
+from repro.core.plan import EngineTask, PlanConfig, build_plan
+from repro.core.planner import batch_search_ivf, execute_plan
 from repro.core.pq import train_pq
 from repro.core.types import Workload
 from repro.kernels import ops, ref
@@ -81,6 +82,63 @@ def test_segmented_vs_dense_engine_parity(metric, mode):
             ivf, q, nprobe=6, k=5, bitmap=bitmap, cfg=_cfg("segmented", mode), pq=pq
         )
         assert_exact(seg, dense, f"{metric}/{mode} bitmap={bitmap is not None}")
+
+
+def _gather_on_host(monkeypatch, arena, q):
+    """Put the host gather back in the executor's place: each bucket's Q and
+    V tiles built in numpy from the rows ``_assemble_bucket`` lays out,
+    then copied, as the executor did before its rows were resident."""
+    assembled = []
+    assemble = planner._assemble_bucket
+
+    def recording(*args, **kw):
+        assembled.append(assemble(*args, **kw))
+        return assembled[-1]
+
+    def host_gather(rows, q_dev, starts, qrow_of_d, lp):
+        Vrows, _, qrow_of, _ = assembled[-1]
+        Q = np.zeros(qrow_of.shape + (q.shape[1],), np.float32)
+        live = qrow_of >= 0
+        Q[live] = q[qrow_of[live]]
+        return jnp.asarray(Q), jnp.asarray(arena.packed[Vrows])
+
+    monkeypatch.setattr(planner, "_assemble_bucket", recording)
+    monkeypatch.setattr(ops, "gather_unit_operands", host_gather)
+
+
+@pytest.mark.parametrize("layout", ["dense", "segmented"])
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "interpret"])
+def test_resident_gather_matches_host_gather(layout, use_pallas, monkeypatch):
+    """f32 execute_plan over the arena's resident rows answers bit for bit
+    as the host gather does: two partitions, a task with a packed bitmap,
+    and a unit whose padded rows run past the arena's last row."""
+    rng = np.random.default_rng(29)
+    d, m, k = 16, 19, 5
+    parts = []
+    for p, n in enumerate((300, 261)):
+        vecs = rng.normal(size=(n, d)).astype(np.float32)
+        ivf = IVFIndex.build(vecs, metric="l2", n_centroids=6, kmeans_iters=4, seed=p)
+        parts.append((300 * p + np.arange(n, dtype=np.int64), ivf))
+    arena = PackedArena.from_partitions(parts)
+    q = rng.normal(size=(m, d)).astype(np.float32)
+    tasks = [
+        EngineTask(part=0, qrows=np.arange(0, 12, dtype=np.int64), nprobe=4,
+                   packed_bitmap=arena.packed_bitmap(0, rng.random(300) < 0.5)),
+        # every list of the last partition, so the arena's last list is scanned
+        EngineTask(part=1, qrows=np.arange(7, m, dtype=np.int64), nprobe=6,
+                   packed_bitmap=None),
+    ]
+    cfg = PlanConfig(tq_unit=8, min_list_pad=8, merge_layout=layout,
+                     use_pallas=use_pallas, interpret=True if use_pallas else None)
+    plan = build_plan(arena, tasks, q, m=m, k=k, cfg=cfg)
+    tail = [int(arena.list_start[u.glist]) + lp - arena.n
+            for lp, units in plan.buckets.items() for u in units]
+    assert max(tail) > 0, "no unit's rows run past the arena's last row"
+    got = execute_plan(plan, arena, q, cfg=cfg)
+    _gather_on_host(monkeypatch, arena, q)
+    want = execute_plan(plan, arena, q, cfg=cfg)
+    assert_exact(got, want, f"{layout} pallas={use_pallas}")
+    assert (got[1] >= 0).all()
 
 
 def _search_layout(hqi, wl, layout, **kw):

@@ -105,6 +105,26 @@ def test_loaded_snapshot_is_warm(tmp_path):
         np.testing.assert_array_equal(bm, loaded.router._bitmap_cache[filt])
 
 
+def test_snapshot_carries_no_device_rows(tmp_path):
+    """The arena's resident device rows are a cache: never in ``to_state``,
+    absent after a load, and uploaded again by the loaded index's first
+    f32 search, which answers as the original does."""
+    import jax
+
+    _, wl, hqi = _build()
+    r0 = hqi.search(wl, nprobe=4)
+    assert hqi.arena._rows_dev is not None  # the f32 search made them resident
+    leaves = jax.tree_util.tree_leaves(hqi.arena.to_state())
+    assert not any(isinstance(x, jax.Array) for x in leaves)
+    save_snapshot(tmp_path, hqi)
+    loaded = load_snapshot(str(tmp_path)).index
+    assert loaded._arena is not None and loaded._arena._rows_dev is None
+    r1 = loaded.search(wl, nprobe=4)
+    assert loaded._arena._rows_dev is not None
+    np.testing.assert_array_equal(r0.ids, r1.ids)
+    np.testing.assert_array_equal(r0.scores, r1.scores)
+
+
 def test_roundtrip_after_extend(tmp_path):
     """A snapshot taken after live folds round-trips the grown index."""
     db, wl, hqi = _build()

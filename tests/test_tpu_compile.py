@@ -169,3 +169,35 @@ def test_merge_programs_keep_the_merge_readers_name(one_chip, readers, merge):
     module = hlo.split(None, 2)[1].rstrip(",")  # "HloModule <name>, ..."
     # the trace names a program's ops by its module and a fingerprint
     assert is_merge(Op("%fusion = f32[] fusion()", f"{module}(2233347581744820355)", 0.0, 0.0, 0))
+
+
+# the benchmark cells' f32 scan buckets (W, lp) over their arenas (N rows of
+# width D), as their warm-up logs them on the chip; queries padded to 2,048
+GATHER_CELLS = {
+    "kg": (1_000_000, 200, [(4, 4096), (64, 2048), (1024, 512), (8192, 32)]),
+    "turing": (1_000_000, 100, [(2, 4096), (256, 2048), (8192, 256), (4096, 32)]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GATHER_CELLS))
+def test_resident_gather_compiles_apart_from_the_scan_kernels(one_chip, readers, cell):
+    is_scan, is_merge, Op = readers
+    n, d, buckets = GATHER_CELLS[cell]
+    for w, lp in buckets:
+        args = [
+            jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in (((n, d), jnp.float32), ((2048, d), jnp.float32),
+                           ((w,), jnp.int32), ((w, TQ), jnp.int32))
+        ]
+        lowered = ops.gather_unit_operands.lower(*args, lp=lp)
+        q, v = lowered.out_info
+        assert q.shape == (w, TQ, d) and v.shape == (w, lp, d)
+        assert q.dtype == v.dtype == jnp.float32
+        hlo = lowered.compile().as_text()
+        # no kernel of its own, so the roofline reader times the scan alone,
+        # and a module name the merge reader does not take
+        assert "tpu_custom_call" not in hlo
+        ops_ = [ln.strip() for ln in hlo.splitlines() if " = " in ln and ln.strip().startswith("%")]
+        assert ops_ and not any(is_scan(Op(o, "", 0.0, 0.0, 0)) for o in ops_)
+        module = hlo.split(None, 2)[1].rstrip(",")
+        assert not is_merge(Op("%fusion = f32[] fusion()", f"{module}(1)", 0.0, 0.0, 0))

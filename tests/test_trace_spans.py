@@ -32,6 +32,7 @@ F32_PARENT = {
     "plan.group": "plan.build",
     "probe.h2d": "plan.probe",
     "probe.d2h": "plan.probe",
+    "query.h2d": "plan.execute",
     "scan.assemble": "plan.execute",
     "scan.gather": "plan.execute",
     "scan.h2d": "plan.execute",
@@ -144,11 +145,15 @@ def test_h2d_bytes_equal_the_plan_shapes(layout):
     want_probe = sum(
         _next_pow2(len(t.qrows), 8) * d * 4 + _next_pow2(n_cent, 8) * d * 4 for t in tasks
     )
-    # scan: per bucket Q [W, tq, d] f32, V [W, lp, d] f32, valid [W, lp] bool
+    # queries once, pow2-padded (>= 8 rows); the arena's rows are resident
+    # already (uploaded by the untraced first run)
+    want_query = _next_pow2(m, 8) * d * 4
+    # scan: per bucket the list starts [W] i32, query rows [W, tq] i32 and
+    # valid [W, lp] bool; the Q and V tiles are gathered on the device
     want_scan = 0
     for lp, units in plan.buckets.items():
         W = _next_pow2(len(units), 1)
-        want_scan += W * plan.tq * d * 4 + W * lp * d * 4 + W * lp
+        want_scan += W * 4 + W * plan.tq * 4 + W * lp
     if layout == "segmented":
         # flat scores [C_pad, k] f32, ids [C_pad, k], segment of each row i32
         c_pad = _next_pow2(int(plan.seg_counts.sum()), 1)
@@ -156,7 +161,8 @@ def test_h2d_bytes_equal_the_plan_shapes(layout):
     else:
         width = _next_pow2(plan.n_slots * k, k)
         want_merge = m * width * (4 + ID_BYTES)
-    assert got == {"probe.h2d": want_probe, "scan.h2d": want_scan, "merge.h2d": want_merge}
+    assert got == {"probe.h2d": want_probe, "query.h2d": want_query, "scan.h2d": want_scan,
+                   "merge.h2d": want_merge}
 
 
 @pytest.mark.parametrize("layout", ["segmented", "dense"])
@@ -167,6 +173,62 @@ def test_d2h_count_is_probes_buckets_and_merges(layout):
     # the final top-k read back: scores f32 and ids [m, k]
     (merge,) = [e for e in events if e["name"] == "merge.d2h"]
     assert merge["args"]["bytes"] == m * k * (4 + ID_BYTES)
+
+
+@pytest.mark.parametrize("layout", ["segmented", "dense"])
+def test_scan_copies_ship_no_rows(hqi_and_workload, layout):
+    hqi, wl = hqi_and_workload
+    hqi.cfg.plan = PlanConfig(merge_layout=layout)
+    try:
+        hqi.search(wl, nprobe=8, batch_vec=True)  # uploads the rows, compiles
+        events = traced(lambda: hqi.search(wl, nprobe=8, batch_vec=True))
+    finally:
+        hqi.cfg.plan = PlanConfig()
+    d = wl.vectors.shape[1]
+    buckets = [e["args"] for e in events if e["name"] == "dispatch.scan"]
+    assert buckets
+    # what the per-bucket copies would be if they carried the V tiles
+    rows_bytes = sum(_next_pow2(b["units"], 1) * b["lp"] * d * 4 for b in buckets)
+    scan_bytes = sum(e["args"]["bytes"] for e in events if e["name"] == "scan.h2d")
+    assert 0 < scan_bytes < rows_bytes
+    # the device gather keeps its span, one per bucket, and the resident
+    # rows are not copied again
+    n = collections.Counter(e["name"] for e in events)
+    assert n["scan.gather"] == len(buckets)
+    assert n["query.h2d"] == 1 and n["arena.h2d"] == 0
+
+
+def test_arena_rows_upload_once_per_arena():
+    from repro.core.types import VectorDatabase
+
+    db = small_db(n=2000, seed=5)
+    wl = small_workload(db, n_queries=80)
+    hqi = HQIIndex.build(db, wl, HQIConfig(min_partition_size=256, max_leaves=8))
+
+    def uploads():
+        events = traced(lambda: hqi.search(wl, nprobe=8, batch_vec=True))
+        return [e["args"]["bytes"] for e in events if e["name"] == "arena.h2d"]
+
+    t = trace.enable(capacity=100_000)
+    try:
+        hqi.search(wl, nprobe=8, batch_vec=True)
+        hqi.search(wl, nprobe=8, batch_vec=True)
+    finally:
+        trace.disable()
+    up = [e for e in t.events() if e["name"] == "arena.h2d"]
+    assert len(up) == 1
+    assert up[0]["args"]["bytes"] == hqi.arena.packed.nbytes
+    assert up[0]["args"]["parent"] == "plan.execute"
+    # an insert folds into a new arena (PackedArena.updated): it uploads anew
+    old = hqi.arena
+    new = db.take(np.arange(9))
+    hqi.extend(VectorDatabase(
+        vectors=new.vectors + 0.01, columns=new.columns, metric=db.metric,
+        ids=db.n + np.arange(9, dtype=np.int64),
+    ))
+    assert hqi.arena is not old and hqi.arena.n == old.n + 9
+    assert uploads() == [hqi.arena.packed.nbytes]
+    assert uploads() == []
 
 
 def test_pq_search_emits_the_shared_spans(hqi_and_workload):
