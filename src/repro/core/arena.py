@@ -22,9 +22,12 @@ incrementally through ``updated()``.
 
 Device residency: ``device_rows()`` uploads ``packed`` to the device once per
 arena, on the first f32 scan that needs it, and the executor gathers each
-bucket's rows there. The copy is f32, bit for bit, lives and dies with the
-arena (``updated()`` and ``from_partitions()`` build new arenas, which upload
-again), and is never part of ``to_state()``.
+bucket's rows there. In pq mode ``device_codes()`` does the same for the
+uint8 codes instead, and the f32 rows stay in host memory, read only by the
+exact re-rank: the layout of an index whose rows outgrow the chip. Either
+copy is bit for bit, lives and dies with the arena (``updated()`` and
+``from_partitions()`` build new arenas, which upload again), and is never
+part of ``to_state()``.
 
 Sharded storage: ``shard()`` splits the arena into contiguous *partition*
 slices, one per model-axis rank of a device mesh. Because partitions are
@@ -87,6 +90,10 @@ class PackedArena:
     _rows_dev: Optional[Any] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
     )
+    # ``codes`` on the device (``device_codes``), likewise
+    _codes_dev: Optional[Any] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False
+    )
 
     @property
     def n(self) -> int:
@@ -134,6 +141,18 @@ class PackedArena:
                     (self._rows_dev,) = to_device("arena.h2d", self.packed)
         return self._rows_dev
 
+    def device_codes(self) -> Any:
+        """``codes`` resident on the device, uint8 [N, M] bit for bit.
+
+        Uploaded on the first pq execute, through the ``arena.h2d`` copy
+        span (N·M bytes), and kept for the arena's lifetime: the ADC stage
+        gathers every bucket's code tiles from it."""
+        if self._codes_dev is None:
+            with _UPLOAD_LOCK:
+                if self._codes_dev is None:
+                    (self._codes_dev,) = to_device("arena.h2d", self.codes)
+        return self._codes_dev
+
     def packed_bitmap(self, part: int, local_bitmap: np.ndarray) -> np.ndarray:
         """Partition-local vector-order bitmap -> that partition's packed order."""
         s, e = int(self.part_row[part]), int(self.part_row[part + 1])
@@ -156,6 +175,7 @@ class PackedArena:
             )
         self.pq = pq
         self.codes = encode_pq(pq, self.packed)
+        self._codes_dev = None
 
     # ------------------------------------------------------------ persistence
 

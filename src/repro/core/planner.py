@@ -25,11 +25,14 @@ This module executes that plan:
      (tests/test_engine_segmented.py).
 
 Compressed execution (``PlanConfig.scan_mode="pq"``): the scan stage reads
-the arena's uint8 PQ codes instead of raw f32 vectors — each bucket is one
-``ops.workunit_pq_topk`` ADC dispatch producing ``refine_factor · k``
-candidates per (query, posting list). Candidates from all buckets then merge
-per query (one device merge), the survivors' f32 rows are gathered from the
-arena ONCE, and a single ``workunit_topk`` dispatch re-ranks them exactly —
+the arena's uint8 PQ codes instead of raw f32 vectors — the codes are
+resident on the device (``PackedArena.device_codes``), each bucket's code
+tiles are gathered there (``ops.gather_unit_codes``), and each bucket is one
+ADC dispatch producing ``refine_factor · k`` candidates per (query, posting
+list) against tables built on the device (``scan.adc``). Candidates from all
+buckets then merge per query (one device merge), the survivors' f32 rows are
+gathered from host memory ONCE (the f32 rows never go to the device in this
+mode), and a single ``workunit_topk`` dispatch re-ranks them exactly —
 so dispatch cost stays O(#buckets) + 1 re-rank, never O(T×L), while scan HBM
 traffic drops by d·4/M× (e.g. 32× at d=64, M=8). Bitmap pushdown composes
 unchanged: the ADC stage applies the same ``valid`` mask, so re-rank
@@ -89,7 +92,8 @@ from .plan import (
     build_plan,
     _next_pow2,
 )
-from .pq import PQCodebook, adc_tables
+from ..kernels.pq_scan import subspace_pad
+from .pq import PQCodebook, adc_tables, adc_tables_device
 
 # Extra per-query candidates merged alongside the plan's output (the adaptive
 # executor's host-side scans): (qrows i64 [mq], scores f32 [mq, k], ids i64 [mq, k])
@@ -248,11 +252,7 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
     prof = get_profiler()
     tracer = get_tracer()
     rows_dev = arena.device_rows()
-    # queries padded to a power of two, so the gather's compiled shapes do
-    # not follow every flush's batch size
-    q_pad = np.zeros((_next_pow2(len(q_vecs), 8), d), dtype=np.float32)
-    q_pad[: len(q_vecs)] = q_vecs
-    (q_dev,) = to_device("query.h2d", q_pad)
+    q_dev = _device_queries(q_vecs)
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
@@ -468,22 +468,25 @@ def _execute_plan_pq(
     code tiles with each unit's VMEM-resident per-query LUTs, keeping
     k′ = refine_factor · k ADC candidates per (query, posting list).
     Stage B — candidates from all buckets merge to the per-query top-k′ (one
-    device merge over ADC scores), their f32 rows are gathered from the arena
-    once, and ONE ``workunit_topk`` dispatch re-scores them exactly. The
+    device merge over ADC scores), their f32 rows are gathered from host
+    memory once, and ONE ``workunit_topk`` dispatch re-scores them exactly. The
     final merge then folds in the adaptive executor's host-side candidates,
     exactly like the f32 path.
     """
     m, k = plan.m, plan.k
     kprime = max(k, int(cfg.refine_factor) * k)
 
-    # ADC tables only for queries the plan actually scans (the adaptive
-    # executor may have routed most of the workload to host-side extras),
-    # shipped to the device ONCE as a resident [U, M, 256] array. The
-    # segmented layout indexes it directly from the dispatch (per-unit-slot
-    # LUT rows DMA-streamed on the Pallas path) so no per-bucket
-    # [W, tq, M, 256] operand ever materializes; the dense layout keeps the
-    # device-side gather expansion as the comparison baseline, which
+    # The deployment's layout: the codes and the codebook live on the
+    # device, the f32 rows stay in host memory for the exact re-rank. ADC
+    # tables only for queries the plan actually scans (the adaptive executor
+    # may have routed most of the workload to host-side extras), built on
+    # the device as one resident [U, Mp, 256] array. The segmented layout
+    # indexes it directly from the dispatch (per-unit-slot LUT rows
+    # DMA-streamed on the Pallas path) so no per-bucket [W, tq, M, 256]
+    # operand ever materializes; the dense layout keeps the device-side
+    # gather expansion as the comparison baseline, which
     # ``DispatchStats.lut_expand_bytes`` meters.
+    codes_dev = arena.device_codes()
     used = np.unique(
         np.concatenate(
             [u.qrows for units in plan.buckets.values() for u in units]
@@ -491,28 +494,64 @@ def _execute_plan_pq(
     )
     lut_pos = np.zeros(m, dtype=np.int64)
     lut_pos[used] = np.arange(len(used))
-    with get_tracer().span("scan.gather"):
-        luts = adc_tables(arena.pq, q_vecs[used])
-    (luts_dev,) = to_device("scan.h2d", luts)  # [U, M, 256]
+    luts_dev = _resident_adc_tables(arena.pq, q_vecs[used])  # [U, Mp, 256]
     _account_lut(stats, int(luts_dev.nbytes), expanded=False)
 
-    if cfg.merge_layout == "segmented":
-        rows = _pq_stage_a_segmented(
-            plan, arena, luts_dev, lut_pos, kprime, cfg=cfg, stats=stats
-        )
-    else:
-        rows = _pq_stage_a_dense(
-            plan, arena, luts_dev, lut_pos, kprime, cfg=cfg, stats=stats
-        )
+    stage_a = _pq_stage_a_segmented if cfg.merge_layout == "segmented" else _pq_stage_a_dense
+    rows = stage_a(
+        plan, arena, codes_dev, luts_dev, lut_pos, kprime, cfg=cfg, stats=stats
+    )
     return _pq_rerank_and_fold(
         arena, q_vecs, rows, k=k, kprime=kprime, cfg=cfg, extra=extra, stats=stats
     )
 
 
+def _device_queries(q_vecs: np.ndarray) -> jnp.ndarray:
+    """The queries on the device, copied once an execute (``query.h2d``) and
+    padded to a power of two, so the compiled shapes that read them do not
+    follow every flush's batch size."""
+    q_pad = np.zeros((_next_pow2(len(q_vecs), 8), q_vecs.shape[1]), dtype=np.float32)
+    q_pad[: len(q_vecs)] = q_vecs
+    (q_dev,) = to_device("query.h2d", q_pad)
+    return q_dev
+
+
+def _resident_adc_tables(pq: PQCodebook, q_used: np.ndarray) -> jnp.ndarray:
+    """The ADC tables of ``q_used`` on the device, f32 [U', Mp, 256] (U'
+    the queries' padded count): one jitted program builds every table from
+    the resident queries and codebook (``scan.adc``)."""
+    q_dev = _device_queries(q_used)
+    with get_tracer().span("scan.adc", queries=len(q_used), m=pq.m):
+        return fence(
+            adc_tables_device(
+                pq.device_centroids(), q_dev, metric=pq.metric, m_pad=subspace_pad(pq.m)
+            )
+        )
+
+
+def _pq_bucket_operands(units, lp, plan, arena, lut_pos):
+    """One bucket's ADC operands: the host assembles it and ships only the
+    units' list starts, LUT rows and bitmap (``scan.h2d``); its code tiles
+    are gathered from the resident codes by the caller (``scan.gather``).
+    Returns (Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d)."""
+    Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
+    lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
+    # a unit's rows are min(start + arange(lp), n - 1), so its first row
+    # is its start as the gather reads it (pad units: row 0, all masked)
+    starts_d, lut_idx_d, valid_d = to_device(
+        "scan.h2d",
+        Vrows[:, 0].astype(np.int32),
+        lut_idx.astype(np.int32),
+        valid,
+    )
+    return Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d
+
+
 def _pq_stage_a_dense(
     plan: ExecutionPlan,
     arena: PackedArena,
-    luts_dev: jnp.ndarray,  # f32 [U, M, 256]
+    codes_dev: jnp.ndarray,  # uint8 [N, M] — the arena's resident codes
+    luts_dev: jnp.ndarray,  # f32 [U, Mp, 256]
     lut_pos: np.ndarray,  # i64 [m] — LUT row per workload query
     kprime: int,
     *,
@@ -522,6 +561,7 @@ def _pq_stage_a_dense(
     """Dense ADC stage A: [m, n_slots, k'] scatter + rectangular merge.
     Returns the surviving global packed rows i64 [m, k'] (-1 pad)."""
     m = plan.m
+    M = int(codes_dev.shape[1])
     cand_s = np.full((m, plan.n_slots, kprime), -np.inf, dtype=np.float32)
     cand_rows = np.full((m, plan.n_slots, kprime), -1, dtype=np.int64)
     _account_candidates(stats, cand_s.nbytes + cand_rows.nbytes)
@@ -530,19 +570,22 @@ def _pq_stage_a_dense(
 
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
-        Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
+        Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d = _pq_bucket_operands(
+            units, lp, plan, arena, lut_pos
+        )
         W = Vrows.shape[0]
         wmask = qrow_of >= 0
         with tracer.span("scan.gather"):
-            # padding slots map to LUT row 0; their outputs are dropped via wmask
-            lut_idx = lut_pos[np.maximum(qrow_of, 0)]
-            codes = arena.codes[Vrows]  # [W, lp, M] uint8 — the compressed gather
-        lut_idx_d, codes_d, valid_d = to_device("scan.h2d", lut_idx, codes, valid)
-        luts = jnp.take(luts_dev, lut_idx_d, axis=0)  # [W, tq, M, 256], gathered on device
-        del lut_idx_d
+            # [W, lp, M] uint8 and [W, tq, Mp, 256]: the compressed gather and
+            # the expanded tables, both on the device
+            codes_d, luts = fence(
+                kops.gather_unit_codes(codes_dev, starts_d, lp=lp),
+                jnp.take(luts_dev, lut_idx_d, axis=0),
+            )
+        del starts_d, lut_idx_d
         _account_lut(stats, int(luts.nbytes), expanded=True)
         if stats is not None:
-            stats.bytes_scanned += len(units) * lp * arena.codes.shape[1]
+            stats.bytes_scanned += len(units) * lp * M
         kk = min(kprime, lp)
         t0 = prof.t0() if prof.enabled else 0
         with tracer.span("dispatch.scan", mode="pq", lp=lp, units=len(units)):
@@ -558,12 +601,11 @@ def _pq_stage_a_dense(
         del codes_d, valid_d
         if prof.enabled:
             # one-hot MXU contraction: 2·M·256 MACs per (query, live row)
-            M = codes.shape[2]
             nq_u = wmask.sum(axis=1)
             rows_u = valid.sum(axis=1)
             prof.record_dispatch(
                 "scan", "pq", lp, t0,
-                nbytes=int(luts.nbytes) + codes.nbytes + valid.nbytes
+                nbytes=int(luts.nbytes) + W * lp * M + valid.nbytes
                 + W * plan.tq * kk * 12,
                 flops=2.0 * M * 256 * float((nq_u * rows_u).sum()),
                 flops_padded=2.0 * M * 256 * W * plan.tq * lp,
@@ -596,7 +638,8 @@ def _pq_stage_a_dense(
 def _pq_stage_a_segmented(
     plan: ExecutionPlan,
     arena: PackedArena,
-    luts_dev: jnp.ndarray,  # f32 [U, M, 256]
+    codes_dev: jnp.ndarray,  # uint8 [N, M] — the arena's resident codes
+    luts_dev: jnp.ndarray,  # f32 [U, Mp, 256]
     lut_pos: np.ndarray,  # i64 [m]
     kprime: int,
     *,
@@ -611,6 +654,7 @@ def _pq_stage_a_segmented(
     Returns the surviving global packed rows i64 [m, k'] (-1 pad).
     """
     m = plan.m
+    M = int(codes_dev.shape[1])
     counts = _plan_seg_counts(plan)  # stage A has no extras; they fold post re-rank
     offsets = np.zeros(m + 1, dtype=np.int64)
     np.cumsum(counts, out=offsets[1:])
@@ -626,36 +670,38 @@ def _pq_stage_a_segmented(
 
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
-        Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
+        Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d = _pq_bucket_operands(
+            units, lp, plan, arena, lut_pos
+        )
         wmask = qrow_of >= 0
         with tracer.span("scan.gather"):
-            lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
-            codes = arena.codes[Vrows]  # [W, lp, M] uint8
-        operands = to_device("scan.h2d", lut_idx, codes, valid)
+            codes_d = fence(kops.gather_unit_codes(codes_dev, starts_d, lp=lp))  # [W, lp, M]
+        del starts_d
         if stats is not None:
-            stats.bytes_scanned += len(units) * lp * arena.codes.shape[1]
+            stats.bytes_scanned += len(units) * lp * M
         kk = min(kprime, lp)
         t0 = prof.t0() if prof.enabled else 0
         with tracer.span("dispatch.scan", mode="pq-res", lp=lp, units=len(units)):
             s, i_loc = kops.workunit_pq_topk_resident(
                 luts_dev,
-                *operands,
+                lut_idx_d,
+                codes_d,
+                valid_d,
                 kk,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)
-        del operands
+        del lut_idx_d, codes_d, valid_d
         if prof.enabled:
             # the resident path streams one [M, 256] LUT row per LIVE query
             # slot instead of expanding [W, tq, M, 256]
-            M = codes.shape[2]
             W = Vrows.shape[0]
             nq_u = wmask.sum(axis=1)
             rows_u = valid.sum(axis=1)
             prof.record_dispatch(
                 "scan", "pq-res", lp, t0,
-                nbytes=codes.nbytes + valid.nbytes
+                nbytes=W * lp * M + valid.nbytes
                 + int(nq_u.sum()) * M * 256 * 4 + W * plan.tq * kk * 12,
                 flops=2.0 * M * 256 * float((nq_u * rows_u).sum()),
                 flops_padded=2.0 * M * 256 * W * plan.tq * lp,
@@ -734,6 +780,7 @@ def _pq_rerank_and_fold(
             metric=arena.metric,
             use_pallas=cfg.use_pallas,
             interpret=cfg.interpret,
+            tag="rerank",
         )
         s, i_loc = fence(s, i_loc)
     del operands

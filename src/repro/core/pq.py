@@ -8,26 +8,42 @@ d=64, M=8). Asymmetric distance computation (ADC): per query, precompute a
 [M, 256] lookup table of partial distances; a database vector's score is a
 sum of M table lookups — no float vector ever read at scan time.
 
-TPU adaptation: the LUT (M·256·4 B ≤ 64 KB) lives in VMEM; the scan is a
-gather+accumulate over uint8 code tiles fused with the same running top-k
-as fused_knn (kernels/pq_scan.py). Bitmap pushdown composes unchanged.
+TPU adaptation: a query's LUT (M·256·4 B: 50 KB at the published M = 50)
+lives in VMEM; the scan is a gather+accumulate over uint8 code tiles fused
+with the same running top-k as fused_knn (kernels/pq_scan.py). The tables
+are one jitted device program (``adc_tables_device``) over the resident
+queries and the resident codebook (``PQCodebook.device_centroids``). Bitmap
+pushdown composes unchanged.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+import threading
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.fused_knn import HIGHEST
+from ..obs.trace import to_device
 from . import kmeans as km
+
+# one upload per codebook when the service's scheduler thread and a
+# foreground search reach it together
+_UPLOAD_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass
 class PQCodebook:
     centroids: np.ndarray  # f32 [M, 256, dsub]
     metric: str
+    # ``centroids`` on the device (``device_centroids``); not an init field,
+    # so it is never part of the state or of a replaced codebook
+    _centroids_dev: Optional[Any] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False
+    )
 
     @property
     def m(self) -> int:
@@ -41,6 +57,18 @@ class PQCodebook:
     def d(self) -> int:
         """Vector dimensionality this codebook encodes (m · dsub)."""
         return self.m * self.dsub
+
+    def device_centroids(self) -> Any:
+        """``centroids`` resident on the device, f32 [M, 256, dsub]: uploaded
+        once per codebook (``codebook.h2d``), read by every execute's
+        ``adc_tables_device``."""
+        if self._centroids_dev is None:
+            with _UPLOAD_LOCK:
+                if self._centroids_dev is None:
+                    (self._centroids_dev,) = to_device(
+                        "codebook.h2d", np.asarray(self.centroids, np.float32)
+                    )
+        return self._centroids_dev
 
     def to_state(self) -> dict:
         """Snapshot state (store/snapshot.py): arrays stay np.ndarray leaves."""
@@ -102,24 +130,44 @@ def decode_pq(cb: PQCodebook, codes: np.ndarray) -> np.ndarray:
     return out
 
 
-def adc_tables(cb: PQCodebook, queries: np.ndarray) -> np.ndarray:
-    """Per-query partial-score LUTs: f32 [nq, M, 256], higher = better.
+@functools.partial(jax.jit, static_argnames=("metric", "m_pad"))
+def adc_tables_device(
+    centroids: jax.Array,  # f32 [M, 256, dsub] — the codebook
+    queries: jax.Array,  # f32 [U, M·dsub]
+    *,
+    metric: str,
+    m_pad: int,
+) -> jax.Array:
+    """Per-query partial-score LUTs, f32 [U, m_pad, 256], higher = better,
+    in one device program; subspace rows past M are zero (the kernels'
+    aligned table layout, ``kernels.pq_scan.subspace_pad``).
 
     l2: -‖q_sub − c‖² summed over subspaces == -‖q − decode(code)‖².
     ip: q_sub · c summed == q · decode(code).
     """
-    nq = queries.shape[0]
-    dsub = cb.dsub
-    luts = np.empty((nq, cb.m, cb.centroids.shape[1]), np.float32)
-    for j in range(cb.m):
-        qs = queries[:, j * dsub : (j + 1) * dsub]  # [nq, dsub]
-        c = cb.centroids[j]  # [256, dsub]
-        ip = qs @ c.T
-        if cb.metric == "l2":
-            luts[:, j] = 2 * ip - (qs * qs).sum(1, keepdims=True) - (c * c).sum(1)[None, :]
-        else:
-            luts[:, j] = ip
-    return luts
+    m, nbook, dsub = centroids.shape
+    qs = queries.reshape(queries.shape[0], m, dsub)
+    ip = jnp.einsum("umd,mcd->umc", qs, centroids, precision=HIGHEST)
+    if metric == "l2":
+        luts = (
+            2 * ip
+            - jnp.sum(qs * qs, axis=2)[:, :, None]
+            - jnp.sum(centroids * centroids, axis=2)[None, :, :]
+        )
+    else:
+        luts = ip
+    return jnp.pad(luts, ((0, 0), (0, m_pad - m), (0, 0)))
+
+
+def adc_tables(cb: PQCodebook, queries: np.ndarray) -> np.ndarray:
+    """``adc_tables_device`` for host callers: f32 [nq, M, 256]."""
+    luts = adc_tables_device(
+        jnp.asarray(cb.centroids, jnp.float32),
+        jnp.asarray(queries, jnp.float32),
+        metric=cb.metric,
+        m_pad=cb.m,
+    )
+    return np.asarray(luts)
 
 
 def adc_scan_ref(

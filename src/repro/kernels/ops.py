@@ -239,6 +239,7 @@ def workunit_topk(
     metric: str = "ip",
     use_pallas: bool | None = None,
     interpret: bool | None = None,
+    tag: str | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Work-unit entry point of the execution engine: one bucket, one dispatch.
 
@@ -246,9 +247,13 @@ def workunit_topk(
     and templates — to a single call. On the Pallas path this picks the
     db-stationary grid of ``fused_knn`` when the vector tile dominates the
     query tile (NV ≫ NQ, the batch-serving shape), and the query-stationary
-    grid otherwise (``_unit_scan_fn``).
+    grid otherwise (``_unit_scan_fn``). ``tag`` names a dispatch that is not
+    a scan bucket in ``DispatchStats.shapes`` (the pq re-rank: ``"rerank"``),
+    leading its shape as the ADC dispatches' tags lead theirs, so a list of
+    one search's shapes still sorts.
     """
-    _DISPATCH.record_knn((q.shape[0], q.shape[1], v.shape[1], int(k)))
+    shape = (q.shape[0], q.shape[1], v.shape[1], int(k))
+    _DISPATCH.record_knn(shape if tag is None else (tag,) + shape)
     use_pallas, interpret = resolve_backend(use_pallas, interpret)
     if use_pallas:
         return _unit_scan_fn(int(k), metric, True, interpret)(q, v, valid)
@@ -256,7 +261,7 @@ def workunit_topk(
 
 
 def workunit_pq_topk(
-    luts: jax.Array,  # f32 [W, TQ, M, 256]  per-query ADC tables per work unit
+    luts: jax.Array,  # f32 [W, TQ, Mt >= M, 256]  per-query ADC tables per work unit
     codes: jax.Array,  # uint8 [W, TV, M]     gathered PQ code rows per unit
     valid: jax.Array,  # bool [W, TV]
     k: int,
@@ -289,7 +294,7 @@ def _workunit_pq_topk_jnp(luts, codes, valid, k):
 
 
 def workunit_pq_topk_resident(
-    table: jax.Array,  # f32 [U, M, 256] — the workload's resident ADC tables
+    table: jax.Array,  # f32 [U, Mt >= M, 256] — the workload's resident ADC tables
     lut_idx: jax.Array,  # i32 [W, TQ] — per-slot row into ``table``
     codes: jax.Array,  # uint8 [W, TV, M]
     valid: jax.Array,  # bool [W, TV]
@@ -327,6 +332,15 @@ def _workunit_pq_topk_resident_jnp(table, lut_idx, codes, valid, k):
     return _ref.workunit_pq_topk_resident_ref(table, lut_idx, codes, valid, k)
 
 
+def _unit_tiles(rows: jax.Array, starts: jax.Array, lp: int) -> jax.Array:
+    """[W, lp, ...] tiles of ``rows``: unit w's are rows
+    ``min(starts[w] + arange(lp), N - 1)``, as ``_assemble_bucket`` lays a
+    bucket's posting lists out."""
+    n = rows.shape[0]
+    idx = jnp.minimum(starts[:, None] + jnp.arange(lp, dtype=starts.dtype), n - 1)
+    return jnp.take(rows, idx, axis=0, mode="clip")
+
+
 @functools.partial(jax.jit, static_argnames=("lp",))
 def gather_unit_operands(
     rows: jax.Array,  # f32 [N, D] — the arena's resident rows
@@ -343,12 +357,22 @@ def gather_unit_operands(
     own, apart from the scan kernels, so the device trace times the two
     apart.
     """
-    n = rows.shape[0]
-    idx = jnp.minimum(starts[:, None] + jnp.arange(lp, dtype=starts.dtype), n - 1)
-    v = jnp.take(rows, idx, axis=0, mode="clip")
+    v = _unit_tiles(rows, starts, lp)
     live = (qrow_of >= 0)[..., None]
     qt = jnp.take(q, jnp.maximum(qrow_of, 0), axis=0, mode="clip")
     return jnp.where(live, qt, jnp.zeros((), q.dtype)), v
+
+
+@functools.partial(jax.jit, static_argnames=("lp",))
+def gather_unit_codes(
+    codes: jax.Array,  # uint8 [N, M] — the arena's resident PQ codes
+    starts: jax.Array,  # i32 [W] — first arena row of each unit's posting list
+    lp: int,
+) -> jax.Array:
+    """One bucket's ADC code tiles, uint8 [W, lp, M], gathered on the device
+    the way ``gather_unit_operands`` gathers the f32 rows (the same rows,
+    bit for bit what ``codes[Vrows]`` gives on the host)."""
+    return _unit_tiles(codes, starts, lp)
 
 
 # --------------------------------------------------------------------------

@@ -4,24 +4,32 @@ Two grids over compressed (PQ) code storage:
 
   * ``pq_scan`` — the original one-query grid: ONE query's [M, 256] LUT stays
     resident in VMEM while uint8 code tiles stream from HBM.
-  * ``workunit_pq_scan`` — the engine's batched work-unit grid ``[W, TQ, TV]``:
-    each unit carries TQ per-query LUTs ([TQ, M, 256] f32 ≤ 64 KB·TQ/256,
-    VMEM-resident across that unit's whole code sweep) and scans uint8 code
-    tiles as M one-hot MXU contractions ``[TQ, 256] @ [256, TV]``, one per
-    subspace, added in the jnp reference's order (``_adc_unit_scores``).
-    Results fold into the same running-top-k VMEM scratch as fused_knn.
+  * ``workunit_pq_scan`` / ``workunit_pq_scan_streamed`` — the engine's
+    batched work-unit grid ``[W, TQ, TV]``: each unit carries TQ per-query
+    LUTs (expanded per unit, or DMA-streamed from the resident [U, Mp, 256]
+    table), VMEM-resident across that unit's whole code sweep, and scans
+    uint8 code tiles with a loop over the M subspaces, one one-hot MXU
+    contraction ``[TQ, 256] @ [256, TV]`` each, added in the jnp
+    reference's order (``_adc_unit_scores``). Results fold into the same
+    running-top-k VMEM scratch as fused_knn.
 
 HBM traffic per scanned row is the CODE bytes (d·4/M× less than raw vectors —
 the FAISS IVF-PQ family scan, TPU-shaped). Codes ship as uint8 end to end and
 widen to int32 in-register; padding them to int32 host-side would quadruple
 the code-tile traffic and defeat the point.
 
+Any M works, not only multiples of 8: a tile's [TV, M] codes are turned
+subspace-major in VMEM, and only the tables' subspace axis is padded (to
+``subspace_pad(M)``, zero rows that are never read), so each row keeps M
+bytes of code in HBM. VMEM per unit at the published width (M = 50,
+TQ = 64): the streamed LUT rows and their subspace-major copy, 3.7 MB each;
+per subspace step a [256, TV] one-hot (512 KB at TV = 512) — the loop
+keeps it one subspace wide for any M.
+
 Gather note: Mosaic supports small-table gathers via one-hot matmul when
 dynamic gather is unavailable; we express the lookup as
-``one_hot(codes) @ lutᵀ`` — an MXU-friendly contraction (interpret mode
-validates numerics either way). On real hardware the [TV, M] uint8 tile wants
-M padded toward the lane width; at HQI's M ∈ {4, 8, 16} the tile is narrow,
-which interpret mode tolerates and Mosaic handles via relayout.
+``lut_j @ one_hot(codes_j)`` — an MXU-friendly contraction (interpret mode
+validates numerics either way).
 """
 from __future__ import annotations
 
@@ -134,59 +142,61 @@ def pq_scan(
 # ---------------------------------------------------------------------------
 
 
+def subspace_pad(m: int) -> int:
+    """A table's subspace axis padded to the f32 sublane tile (8): the
+    streamed kernel DMAs whole ``[Mp, 256]`` slabs, and Mosaic refuses a
+    slice of the tiled axis that is not a multiple of 8 (M = 50 -> 56)."""
+    return -(-int(m) // 8) * 8
+
+
 def _pad_unit_tiles(codes, valid, nv_p):
     """Pad each unit's code rows to ``nv_p`` (uint8) and lay its valid mask
     out as [W, 1, nv_p] int32, so the mask's (1, 1, TV) block keeps the
     last two block dims at (full, TV) as the TPU lowering requires."""
     w, nv, m = codes.shape
-    codes_p = jnp.zeros((w, nv_p, m), jnp.uint8).at[:, :nv].set(codes.astype(jnp.uint8))
+    if nv_p != nv:
+        codes = jnp.zeros((w, nv_p, m), jnp.uint8).at[:, :nv].set(codes.astype(jnp.uint8))
     valid_p = jnp.zeros((w, 1, nv_p), jnp.int32).at[:, 0, :nv].set(valid.astype(jnp.int32))
-    return codes_p, valid_p
+    return codes.astype(jnp.uint8), valid_p
 
 
-def _adc_unit_scores(lut, codes, tv: int, m: int):
-    """[TQ, TV] ADC scores of one unit: lut [TQ, M*256] f32, codes [TV, M]
-    int32. The one-hot [TV, M*256] is built once; each subspace's lane-aligned
-    256-column slice is one MXU contraction that selects exactly one table
-    entry per (query, row), and the M partial scores are added in
-    ``ref.adc_sum``'s order, so the kernel matches the jnp reference bit for
-    bit."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tv, m, 256), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32).reshape(tv, m * 256)
+def _lut_by_subspace(flat, lut_t, m: int) -> None:
+    """Lay one unit's tables out subspace-major: ``flat`` [TQ, Mt·256]
+    (Mt >= M, zero rows past M) -> ``lut_t`` [Mp, TQ, 256], so the
+    subspace loop reads subspace j as one leading-axis slab. Once per unit."""
+    for j in range(m):
+        lut_t[j] = flat[:, j * 256 : (j + 1) * 256]
+
+
+def _adc_unit_scores(lut_t, codes_ref, codes_t, tv: int, m: int):
+    """[TQ, TV] ADC scores of one code tile: ``lut_t`` [Mp, TQ, 256] f32,
+    ``codes_ref`` [1, TV, M] uint8, ``codes_t`` an i32 [Mp, TV] scratch.
+
+    The tile's codes are widened and turned subspace-major once; then a loop
+    over the M subspaces builds subspace j's one-hot [256, TV] and contracts
+    it with j's tables on the MXU, which selects exactly one table entry per
+    (query, row). The loop keeps the program (and VMEM) the same size for
+    any M: the one-hot is one subspace wide, never [TV, M·256]. Terms are
+    added one subspace at a time in order, as ``ref.adc_sum`` adds them, so
+    the kernel matches the jnp reference bit for bit."""
+    codes_t[0:m, :] = codes_ref[0].astype(jnp.int32).T  # [M, TV]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (256, tv), 0)
 
     def term(j):
-        cols = slice(j * 256, (j + 1) * 256)
+        onehot = (iota == codes_t[pl.ds(j, 1), :]).astype(jnp.float32)  # [256, TV]
         return jax.lax.dot_general(
-            lut[:, cols], onehot[:, cols], (((1,), (1,)), ((), ())),
+            lut_t[j], onehot, (((1,), (0,)), ((), ())),
             precision=HIGHEST, preferred_element_type=jnp.float32,
         )
 
-    return adc_sum(term, m)
+    return jax.lax.fori_loop(1, m, lambda j, acc: acc + term(j), term(0))
 
 
-def _workunit_pq_kernel(
-    lut_ref,  # [1, TQ, M*256] f32 — this unit's per-query tables, flattened
-    codes_ref,  # [1, TV, M] uint8
-    valid_ref,  # [1, 1, TV] int32
-    out_s_ref,  # [1, TQ, K]
-    out_i_ref,  # [1, TQ, K]
-    acc_s_ref,  # scratch f32 [TQ, K]
-    acc_i_ref,  # scratch i32 [TQ, K]
-    *,
-    k: int,
-    tv: int,
-    m: int,
-    nv_tiles: int,
-):
-    j = pl.program_id(1)  # code tile (inner) — w outer, so scratch is per-unit
-
-    @pl.when(j == 0)
-    def _init():
-        acc_s_ref[...] = jnp.full(acc_s_ref.shape, NEG_INF, jnp.float32)
-        acc_i_ref[...] = jnp.full(acc_i_ref.shape, -1, jnp.int32)
-
-    codes = codes_ref[0].astype(jnp.int32)  # [TV, M] — uint8 widened in-register
-    scores = _adc_unit_scores(lut_ref[0], codes, tv, m)  # [TQ, TV]
+def _scan_tile(lut_t, codes_ref, codes_t, valid_ref, out_s_ref, out_i_ref,
+               acc_s_ref, acc_i_ref, *, k: int, tv: int, m: int, nv_tiles: int):
+    """Score one code tile and fold it into the unit's running top-k."""
+    j = pl.program_id(1)
+    scores = _adc_unit_scores(lut_t, codes_ref, codes_t, tv, m)  # [TQ, TV]
     valid = valid_ref[0, 0, :] != 0
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -202,9 +212,46 @@ def _workunit_pq_kernel(
         out_i_ref[...] = new_i[None]
 
 
+def _init_acc(acc_s_ref, acc_i_ref) -> None:
+    acc_s_ref[...] = jnp.full(acc_s_ref.shape, NEG_INF, jnp.float32)
+    acc_i_ref[...] = jnp.full(acc_i_ref.shape, -1, jnp.int32)
+
+
+def _workunit_pq_kernel(
+    lut_ref,  # [1, TQ, Mt*256] f32 — this unit's per-query tables, flattened
+    codes_ref,  # [1, TV, M] uint8
+    valid_ref,  # [1, 1, TV] int32
+    out_s_ref,  # [1, TQ, K]
+    out_i_ref,  # [1, TQ, K]
+    lut_t,  # scratch f32 [Mp, TQ, 256] — the unit's tables, subspace-major
+    codes_t,  # scratch i32 [Mp, TV] — the tile's codes, subspace-major
+    acc_s_ref,  # scratch f32 [TQ, K]
+    acc_i_ref,  # scratch i32 [TQ, K]
+    *,
+    k: int,
+    tv: int,
+    m: int,
+    nv_tiles: int,
+):
+    # code tile (inner) — w outer, so scratch is per-unit
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        _init_acc(acc_s_ref, acc_i_ref)
+        _lut_by_subspace(lut_ref[0], lut_t, m)
+
+    _scan_tile(lut_t, codes_ref, codes_t, valid_ref, out_s_ref, out_i_ref,
+               acc_s_ref, acc_i_ref, k=k, tv=tv, m=m, nv_tiles=nv_tiles)
+
+
+# the device trace's op names of the two ADC grids ("%pq_adc_scan.1",
+# "%pq_adc_scan_streamed.1"), which the benchmark's ADC roofline reads
+ADC_SCAN_NAME = "pq_adc_scan"
+ADC_SCAN_STREAMED_NAME = "pq_adc_scan_streamed"
+
+
 @functools.partial(jax.jit, static_argnames=("k", "tv", "interpret"))
 def workunit_pq_scan(
-    luts: jax.Array,  # f32 [W, TQ, M, 256] — per-query ADC tables per unit
+    luts: jax.Array,  # f32 [W, TQ, Mt, 256] — per-query ADC tables per unit (Mt >= M)
     codes: jax.Array,  # uint8 [W, NV, M] — gathered code rows per unit
     valid: jax.Array,  # bool [W, NV]
     *,
@@ -216,16 +263,18 @@ def workunit_pq_scan(
 
     Returns (scores f32 [W, TQ, k] best-first, idx i32 [W, TQ, k]; -1 = none).
     The LUT block of a unit stays VMEM-resident across its code sweep; code
-    tiles ship as uint8 and widen in-register.
+    tiles ship as uint8 and widen in-register. M is the codes' width; a
+    table's subspace rows past M (zero padding) are never read.
     """
-    w, tq, m, nbook = luts.shape
+    w, tq, mt, nbook = luts.shape
     assert nbook == 256, "PQ codebooks are 8-bit (256 entries)"
-    nv = codes.shape[1]
+    nv, m = codes.shape[1], codes.shape[2]
+    assert mt >= m, (mt, m)
     k = int(k)
     tv = fit_tile(tv, nv)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
     codes_p, valid_p = _pad_unit_tiles(codes, valid, nv_p)
-    luts_f = luts.reshape(w, tq, m * nbook)
+    luts_f = luts.reshape(w, tq, mt * nbook)
     nv_tiles = nv_p // tv
 
     kernel = functools.partial(
@@ -235,7 +284,7 @@ def workunit_pq_scan(
         kernel,
         grid=(w, nv_tiles),  # unit outer, code tile inner
         in_specs=[
-            pl.BlockSpec((1, tq, m * nbook), lambda w_, j: (w_, 0, 0)),
+            pl.BlockSpec((1, tq, mt * nbook), lambda w_, j: (w_, 0, 0)),
             pl.BlockSpec((1, tv, m), lambda w_, j: (w_, j, 0)),
             pl.BlockSpec((1, 1, tv), lambda w_, j: (w_, 0, j)),
         ],
@@ -248,10 +297,13 @@ def workunit_pq_scan(
             jax.ShapeDtypeStruct((w, tq, k), jnp.int32),
         ],
         scratch_shapes=[
+            pltpu.VMEM((subspace_pad(m), tq, nbook), jnp.float32),
+            pltpu.VMEM((subspace_pad(m), tv), jnp.int32),
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
         interpret=default_interpret(interpret),
+        name=ADC_SCAN_NAME,
     )
     return call(luts_f, codes_p, valid_p)
 
@@ -263,12 +315,14 @@ def workunit_pq_scan(
 
 def _workunit_pq_streamed_kernel(
     idx_ref,  # SMEM i32 [1, 1, TQ] — this unit's LUT row per query slot
-    table_ref,  # HBM f32 [U, M, 256] — the resident ADC table, NEVER expanded
+    table_ref,  # HBM f32 [U, Mp, 256] — the resident ADC table, NEVER expanded
     codes_ref,  # [1, TV, M] uint8
     valid_ref,  # [1, 1, TV] int32
     out_s_ref,  # [1, TQ, K]
     out_i_ref,  # [1, TQ, K]
-    lut_vmem,  # scratch f32 [TQ, M, 256] — this unit's streamed LUT rows
+    lut_vmem,  # scratch f32 [TQ, Mp, 256] — this unit's streamed LUT rows
+    lut_t,  # scratch f32 [Mp, TQ, 256] — the same, subspace-major
+    codes_t,  # scratch i32 [Mp, TV]
     acc_s_ref,  # scratch f32 [TQ, K]
     acc_i_ref,  # scratch i32 [TQ, K]
     sem,  # DMA completion semaphore
@@ -277,19 +331,19 @@ def _workunit_pq_streamed_kernel(
     tv: int,
     tq: int,
     m: int,
+    mp: int,
     nv_tiles: int,
 ):
-    j = pl.program_id(1)  # code tile (inner) — w outer, so scratch is per-unit
-
-    @pl.when(j == 0)
+    # code tile (inner) — w outer, so scratch is per-unit
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_s_ref[...] = jnp.full(acc_s_ref.shape, NEG_INF, jnp.float32)
-        acc_i_ref[...] = jnp.full(acc_i_ref.shape, -1, jnp.int32)
+        _init_acc(acc_s_ref, acc_i_ref)
         # gather this unit's TQ LUT rows HBM -> VMEM, addressed through the
-        # unit's index block in SMEM: the per-unit [TQ, M, 256] block is BUILT
-        # in VMEM by DMA, so no [W, TQ, M, 256] operand is ever materialized.
-        # Each copy lands a whole [M, 256] slab at a leading-dim offset (a
-        # single row of a 2-D [TQ, M*256] buffer would cut the (8, 128) VMEM
+        # unit's index block in SMEM: the per-unit [TQ, Mp, 256] block is
+        # BUILT in VMEM by DMA, so no [W, TQ, M, 256] operand is ever
+        # materialized. Each copy lands a whole [Mp, 256] slab at a
+        # leading-dim offset (a single row of a 2-D [TQ, Mp*256] buffer, or
+        # a slab of M rows not a multiple of 8, would cut the (8, 128) VMEM
         # tiling); all TQ copies are in flight before the first wait
         dmas = [
             pltpu.make_async_copy(table_ref.at[idx_ref[0, 0, t]], lut_vmem.at[t], sem)
@@ -299,28 +353,15 @@ def _workunit_pq_streamed_kernel(
             dma.start()
         for dma in dmas:
             dma.wait()
+        _lut_by_subspace(lut_vmem[...].reshape(tq, mp * 256), lut_t, m)
 
-    codes = codes_ref[0].astype(jnp.int32)  # [TV, M] — uint8 widened in-register
-    lut = lut_vmem[...].reshape(tq, m * 256)
-    scores = _adc_unit_scores(lut, codes, tv, m)  # [TQ, TV]
-    valid = valid_ref[0, 0, :] != 0
-    scores = jnp.where(valid[None, :], scores, NEG_INF)
-    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    gidx = jnp.where(valid[None, :], col + j * tv, -1)
-
-    new_s, new_i = _merge_topk(acc_s_ref[...], acc_i_ref[...], scores, gidx, k)
-    acc_s_ref[...] = new_s
-    acc_i_ref[...] = new_i
-
-    @pl.when(j == nv_tiles - 1)
-    def _flush():
-        out_s_ref[...] = new_s[None]
-        out_i_ref[...] = new_i[None]
+    _scan_tile(lut_t, codes_ref, codes_t, valid_ref, out_s_ref, out_i_ref,
+               acc_s_ref, acc_i_ref, k=k, tv=tv, m=m, nv_tiles=nv_tiles)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tv", "interpret"))
 def workunit_pq_scan_streamed(
-    table: jax.Array,  # f32 [U, M, 256] — resident per-query ADC tables
+    table: jax.Array,  # f32 [U, Mt, 256] — resident per-query ADC tables (Mt >= M)
     lut_idx: jax.Array,  # i32 [W, TQ] — LUT row per unit slot (0 for padding)
     codes: jax.Array,  # uint8 [W, NV, M] — gathered code rows per unit
     valid: jax.Array,  # bool [W, NV]
@@ -334,19 +375,28 @@ def workunit_pq_scan_streamed(
 
     Same [W, TV] sweep and one-hot MXU contraction as ``workunit_pq_scan``,
     but the per-unit LUT block is assembled in VMEM by per-row DMA from the
-    [U, M, 256] HBM table, addressed through the unit's [TQ] index block,
+    [U, Mp, 256] HBM table, addressed through the unit's [TQ] index block,
     which the grid pipelines into SMEM unit by unit (prefetching all W·TQ
     indices at once would outgrow SMEM at W in the tens of thousands). The
     [W, TQ, M, 256] expansion — W·TQ/U×
     redundant HBM traffic plus its allocation — is gone; each unit reads
     exactly the TQ rows it scans with.
 
+    M is the codes' width. The table's subspace axis is DMAed in slabs of
+    ``subspace_pad(M)`` rows: the engine builds it that wide, zero-padded
+    (``core.pq.adc_tables_device``); a narrower one is padded here, a copy
+    per call.
+
     Returns (scores f32 [W, TQ, k] best-first, idx i32 [W, TQ, k]; -1 = none).
     """
-    _, m, nbook = table.shape
+    _, mt, nbook = table.shape
     assert nbook == 256, "PQ codebooks are 8-bit (256 entries)"
     w, tq = lut_idx.shape
-    nv = codes.shape[1]
+    nv, m = codes.shape[1], codes.shape[2]
+    mp = subspace_pad(m)
+    assert mt >= m, (mt, m)
+    if mt != mp:
+        table = jnp.pad(table[:, :m], ((0, 0), (0, mp - m), (0, 0)))
     k = int(k)
     tv = fit_tile(tv, nv)
     nv_p = max(tv, ((nv + tv - 1) // tv) * tv)
@@ -354,7 +404,7 @@ def workunit_pq_scan_streamed(
     nv_tiles = nv_p // tv
 
     kernel = functools.partial(
-        _workunit_pq_streamed_kernel, k=k, tv=tv, tq=tq, m=m, nv_tiles=nv_tiles
+        _workunit_pq_streamed_kernel, k=k, tv=tv, tq=tq, m=m, mp=mp, nv_tiles=nv_tiles
     )
     call = pl.pallas_call(
         kernel,
@@ -374,12 +424,15 @@ def workunit_pq_scan_streamed(
             jax.ShapeDtypeStruct((w, tq, k), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, m, nbook), jnp.float32),
+            pltpu.VMEM((tq, mp, nbook), jnp.float32),
+            pltpu.VMEM((mp, tq, nbook), jnp.float32),
+            pltpu.VMEM((mp, tv), jnp.int32),
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=default_interpret(interpret),
+        name=ADC_SCAN_STREAMED_NAME,
     )
     idx = lut_idx.astype(jnp.int32).reshape(w, 1, tq)
     return call(idx, table, codes_p, valid_p)

@@ -127,7 +127,7 @@ def adc_sum(term, m: int):
 
 
 def adc_topk_ref(
-    luts: jax.Array,  # f32 [nq, M, 256] — per-query ADC lookup tables
+    luts: jax.Array,  # f32 [nq, Mt >= M, 256] — per-query ADC lookup tables
     codes: jax.Array,  # uint8/int32 [nv, M]
     valid: jax.Array,  # bool [nv]
     k: int,
@@ -138,8 +138,8 @@ def adc_topk_ref(
     negates l2). Returns (scores f32 [nq, k] best-first, idx int32 [nq, k]);
     masked-out or absent entries are (-inf-ish, -1).
     """
-    c = codes.astype(jnp.int32)  # [nv, M]
-    scores = adc_sum(lambda j: luts[:, j, c[:, j]], luts.shape[1])  # [nq, nv]
+    c = codes.astype(jnp.int32)  # [nv, M]; subspace rows of luts past M are padding
+    scores = adc_sum(lambda j: luts[:, j, c[:, j]], c.shape[1])  # [nq, nv]
     scores = jnp.where(valid[None, :], scores, NEG_INF)
     top, idx = jax.lax.top_k(scores, k)
     idx = jnp.where(top <= NEG_INF / 2, -1, idx).astype(jnp.int32)
@@ -147,7 +147,7 @@ def adc_topk_ref(
 
 
 def workunit_pq_topk_ref(
-    luts: jax.Array,  # f32 [W, TQ, M, 256]
+    luts: jax.Array,  # f32 [W, TQ, Mt >= M, 256]
     codes: jax.Array,  # uint8/int32 [W, TV, M]
     valid: jax.Array,  # bool [W, TV]
     k: int,
@@ -157,7 +157,7 @@ def workunit_pq_topk_ref(
 
 
 def workunit_pq_topk_resident_ref(
-    table: jax.Array,  # f32 [U, M, 256] — resident ADC tables
+    table: jax.Array,  # f32 [U, Mt >= M, 256] — resident ADC tables
     lut_idx: jax.Array,  # int [W, TQ] — table row per unit slot
     codes: jax.Array,  # uint8/int32 [W, TV, M]
     valid: jax.Array,  # bool [W, TV]
@@ -171,7 +171,7 @@ def workunit_pq_topk_resident_ref(
     rows = lut_idx[:, :, None]  # [W, TQ, 1]
     # one [W, TQ, TV] term per subspace: an [..., M] minor axis would pad M
     # to the TPU's 128 lanes (16x at M = 8)
-    scores = adc_sum(lambda j: table[rows, j, c[:, None, :, j]], table.shape[1])
+    scores = adc_sum(lambda j: table[rows, j, c[:, None, :, j]], c.shape[-1])
     scores = jnp.where(valid[:, None, :], scores, NEG_INF)  # [W, TQ, TV]
     top, idx = jax.lax.top_k(scores, k)
     idx = jnp.where(top <= NEG_INF / 2, -1, idx).astype(jnp.int32)

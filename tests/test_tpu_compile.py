@@ -2,7 +2,9 @@
 
 Each test lowers and compiles one kernel for a described ``v5e:2x2`` chip at
 ``chip_smoke.py``'s widths (d = 200, TQ = 64, TV in {32, 512}, k = 10; PQ at
-M = 8 with k' = 40 over a 2,000-row LUT table), so the TPU compiler's
+M = 8 with k' = 40 over a 2,000-row LUT table, and at the published M = 50 of
+the ``kg-batch-pq50`` cell, whose table is zero-padded to 56 subspace rows),
+so the TPU compiler's
 refusals (block tiling, VMEM, unaligned slices) show up here instead of on
 the chip. The sharded engine's programs compile over a mesh of the four
 described chips, as ``chip_smoke.py --chips 4`` runs them. Nothing runs:
@@ -22,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.kernels import ops
 from repro.kernels.fused_knn import fused_knn, fused_knn_db_stationary
-from repro.kernels.pq_scan import workunit_pq_scan, workunit_pq_scan_streamed
+from repro.kernels.pq_scan import subspace_pad, workunit_pq_scan, workunit_pq_scan_streamed
 
 W, TQ, D, K, M, K_PQ, U = 4, 64, 200, 10, 8, 40, 2_000
 R = 4  # mesh ranks
@@ -64,6 +66,19 @@ def readers():
     return scan.is_scan, merge.is_merge, Op
 
 
+@pytest.fixture(scope="module")
+def is_adc():
+    """The ADC roofline reader's match of the two ADC kernels' ops, and the
+    trace's op record it reads."""
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from harness.spec import load_module
+    from harness.trace import Op
+
+    return load_module(bench / "metrics" / "pq_scan_roofline.pq.py").is_adc, Op
+
+
 def _compile(fn, sharding, *shapes):
     """``shapes`` are (shape, dtype) or (shape, dtype, sharding) for an
     operand placed other than ``sharding``."""
@@ -82,23 +97,36 @@ def test_f32_scan_kernels_compile(one_chip, kernel, tv):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("tv", [32, 512])
-def test_workunit_pq_scan_compiles(one_chip, tv):
+# (M, TV): both tile sizes at M = 8, the larger one at the published M = 50
+PQ_SHAPES = pytest.mark.parametrize("m,tv", [(8, 32), (8, 512), (50, 512)],
+                                    ids=["32", "512", "m50-512"])
+
+
+def _adc_kernels(hlo):
+    return [ln.strip() for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+
+
+@PQ_SHAPES
+def test_workunit_pq_scan_compiles(one_chip, is_adc, m, tv):
     hlo = _compile(
         functools.partial(workunit_pq_scan, k=K_PQ, interpret=False), one_chip,
-        ((W, TQ, M, 256), jnp.float32), ((W, tv, M), jnp.uint8), ((W, tv), jnp.bool_),
+        ((W, TQ, subspace_pad(m), 256), jnp.float32), ((W, tv, m), jnp.uint8), ((W, tv), jnp.bool_),
     )
-    assert "tpu_custom_call" in hlo
+    match, Op = is_adc
+    kernels = _adc_kernels(hlo)
+    assert kernels and all(match(Op(k, "", 0.0, 0.0, 0)) for k in kernels), kernels
 
 
-@pytest.mark.parametrize("tv", [32, 512])
-def test_workunit_pq_scan_streamed_compiles(one_chip, tv):
+@PQ_SHAPES
+def test_workunit_pq_scan_streamed_compiles(one_chip, is_adc, m, tv):
     hlo = _compile(
         functools.partial(workunit_pq_scan_streamed, k=K_PQ, interpret=False), one_chip,
-        ((U, M, 256), jnp.float32), ((W, TQ), jnp.int32),
-        ((W, tv, M), jnp.uint8), ((W, tv), jnp.bool_),
+        ((U, subspace_pad(m), 256), jnp.float32), ((W, TQ), jnp.int32),
+        ((W, tv, m), jnp.uint8), ((W, tv), jnp.bool_),
     )
-    assert "tpu_custom_call" in hlo
+    match, Op = is_adc
+    kernels = _adc_kernels(hlo)
+    assert kernels and all(match(Op(k, "", 0.0, 0.0, 0)) for k in kernels), kernels
 
 
 def test_segmented_merge_compiles(one_chip):
@@ -201,3 +229,29 @@ def test_resident_gather_compiles_apart_from_the_scan_kernels(one_chip, readers,
         assert ops_ and not any(is_scan(Op(o, "", 0.0, 0.0, 0)) for o in ops_)
         module = hlo.split(None, 2)[1].rstrip(",")
         assert not is_merge(Op("%fusion = f32[] fusion()", f"{module}(1)", 0.0, 0.0, 0))
+
+
+@pytest.mark.parametrize("w,lp", GATHER_CELLS["kg"][2])
+def test_resident_code_gather_compiles(one_chip, w, lp):
+    """The kg-batch-pq50 cell's code tiles: the same buckets as kg-batch-t0
+    (one corpus, one index build), gathered from 1M resident M = 50 codes."""
+    n, m = GATHER_CELLS["kg"][0], 50
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in (((n, m), jnp.uint8), ((w,), jnp.int32))]
+    lowered = ops.gather_unit_codes.lower(*args, lp=lp)
+    (codes,) = jax.tree_util.tree_leaves(lowered.out_info)
+    assert codes.shape == (w, lp, m) and codes.dtype == jnp.uint8
+    assert "tpu_custom_call" not in lowered.compile().as_text()
+
+
+def test_adc_tables_compile(one_chip):
+    """The tables of 2,048 padded queries at d = 200, M = 50, padded to 56
+    subspace rows, in one program."""
+    from repro.core.pq import adc_tables_device
+
+    args = [jax.ShapeDtypeStruct(sh, jnp.float32, sharding=one_chip)
+            for sh in ((50, 256, 4), (2048, D))]
+    lowered = adc_tables_device.lower(*args, metric="ip", m_pad=subspace_pad(50))
+    (luts,) = jax.tree_util.tree_leaves(lowered.out_info)
+    assert luts.shape == (2048, 56, 256) and luts.dtype == jnp.float32
+    lowered.compile()
