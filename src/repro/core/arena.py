@@ -22,12 +22,14 @@ incrementally through ``updated()``.
 
 Device residency: ``device_rows()`` uploads ``packed`` to the device once per
 arena, on the first f32 scan that needs it, and the executor gathers each
-bucket's rows there. In pq mode ``device_codes()`` does the same for the
-uint8 codes instead, and the f32 rows stay in host memory, read only by the
-exact re-rank: the layout of an index whose rows outgrow the chip. Either
-copy is bit for bit, lives and dies with the arena (``updated()`` and
-``from_partitions()`` build new arenas, which upload again), and is never
-part of ``to_state()``.
+bucket's rows there; ``device_gid()`` uploads ``gid`` (as int32) beside
+them, and the executor maps the merged top-k to ids there. In pq mode
+``device_codes()`` does the same for the uint8 codes instead, and the f32
+rows stay in host memory, read only by the exact re-rank: the layout of an
+index whose rows outgrow the chip. Each copy holds the host array's values,
+lives and dies with the arena (``updated()`` and ``from_partitions()``
+build new arenas, which upload again), and is never part of
+``to_state()``.
 
 Sharded storage: ``shard()`` splits the arena into contiguous *partition*
 slices, one per model-axis rank of a device mesh. Because partitions are
@@ -94,6 +96,10 @@ class PackedArena:
     _codes_dev: Optional[Any] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
     )
+    # ``gid`` on the device (``device_gid``), likewise
+    _gid_dev: Optional[Any] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False
+    )
 
     @property
     def n(self) -> int:
@@ -140,6 +146,19 @@ class PackedArena:
                 if self._rows_dev is None:
                     (self._rows_dev,) = to_device("arena.h2d", self.packed)
         return self._rows_dev
+
+    def device_gid(self) -> Any:
+        """``gid`` resident on the device, int32 [N].
+
+        Uploaded on the first f32 execute, through the ``arena.h2d`` copy
+        span (N·4 bytes), and kept for the arena's lifetime: the f32
+        executor maps the merged top-k's arena rows to ids there
+        (``ops.candidate_ids``) instead of on the host."""
+        if self._gid_dev is None:
+            with _UPLOAD_LOCK:
+                if self._gid_dev is None:
+                    (self._gid_dev,) = to_device("arena.h2d", self.gid.astype(np.int32))
+        return self._gid_dev
 
     def device_codes(self) -> Any:
         """``codes`` resident on the device, uint8 [N, M] bit for bit.

@@ -10,27 +10,34 @@ This module executes that plan:
      in a single ``kernels.ops.workunit_topk`` dispatch — the
      single-matmul-per-posting-list of Alg. 3 line 10, fused with the
      Section 4.2 bitmap pushdown, megabatched across the workload;
-  2. scatter per-unit top-k into the candidate buffer — by default a flat
+  2. keep each bucket's per-unit top-k on the device as a block of
+     candidate rows (scores and arena rows, ``ops.unit_topk_rows``), and
+     lay the blocks out as the candidate buffer in one device gather
+     (``ops.gather_candidates``) from the buffer row of every unit slot,
+     which the host knows from the plan. By default the buffer is a flat
      segmented (CSR-style) [Σ seg_counts, k] buffer whose per-query segment
      widths come from ``ExecutionPlan.seg_counts``
      (``merge_layout="segmented"``); ``merge_layout="dense"`` keeps the
-     legacy [m, n_slots, k] tensor padded to the widest query — then fold in
-     any per-query scan results the adaptive executor produced host-side;
+     legacy [m, n_slots, k] layout padded to the widest query. Any per-query
+     scan results the adaptive executor produced host-side are one more
+     block;
   3. reduce candidates to the final per-query top-k with ONE device-side
      reduction (``ops.segmented_merge_topk`` / ``ops.merge_topk``) — Alg. 3
      line 12 for the whole workload, replacing the per-(template ×
-     partition) numpy merge loop. Both layouts are bit-identical: the
-     segmented merge's first-in-order tie break reproduces ``lax.top_k``'s
-     tie rule over the same slot-major candidate order
-     (tests/test_engine_segmented.py).
+     partition) numpy merge loop — map its arena rows to ids through the
+     arena's resident ``gid``, and read back only that top-k, once an
+     execute. Both layouts are bit-identical: the segmented merge's
+     first-in-order tie break reproduces ``lax.top_k``'s tie rule over the
+     same slot-major candidate order (tests/test_engine_segmented.py).
 
 Compressed execution (``PlanConfig.scan_mode="pq"``): the scan stage reads
 the arena's uint8 PQ codes instead of raw f32 vectors — the codes are
 resident on the device (``PackedArena.device_codes``), each bucket's code
 tiles are gathered there (``ops.gather_unit_codes``), and each bucket is one
 ADC dispatch producing ``refine_factor · k`` candidates per (query, posting
-list) against tables built on the device (``scan.adc``). Candidates from all
-buckets then merge per query (one device merge), the survivors' f32 rows are
+list) against tables built on the device (``scan.adc``), kept on the device
+as packed rows. Candidates from all buckets then merge per query (one
+device merge), the survivors' rows are read back, their f32 rows are
 gathered from host memory ONCE (the f32 rows never go to the device in this
 mode), and a single ``workunit_topk`` dispatch re-ranks them exactly —
 so dispatch cost stays O(#buckets) + 1 re-rank, never O(T×L), while scan HBM
@@ -75,6 +82,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -204,7 +212,7 @@ def execute_plan(
         raise ValueError(f"unknown scan_mode {cfg.scan_mode!r}")
     if cfg.merge_layout not in ("segmented", "dense"):
         raise ValueError(f"unknown merge_layout {cfg.merge_layout!r}")
-    m, k, tq = plan.m, plan.k, plan.tq
+    m, k = plan.m, plan.k
     # extras get per-query-dense slot columns after the plan's own slots
     n_slots = plan.n_slots + _extra_slot_width(extra, m)
     if m == 0 or n_slots == 0:
@@ -212,57 +220,184 @@ def execute_plan(
             np.full((m, k), -np.inf, np.float32),
             np.full((m, k), -1, np.int64),
         )
-    if cfg.merge_layout == "segmented":
-        return _execute_plan_f32_segmented(
-            plan, arena, q_vecs, cfg=cfg, extra=extra, stats=stats
+    n_rows = 0 if arena is None else arena.n
+    cand = _DeviceCandidates(plan, extra, k, segmented=cfg.merge_layout == "segmented")
+    _scan_f32_buckets(cand, plan, arena, q_vecs, cfg, stats)
+    cand.add_extras(extra, id_base=n_rows)
+    top_s, top_v = cand.merge(k, stats)
+    gid = arena.device_gid() if plan.buckets else None
+    top_s, top_i = to_host("merge.d2h", top_s, kops.candidate_ids(top_v, n_rows, gid))
+    return top_s.astype(np.float32, copy=False), top_i.astype(np.int64)
+
+
+class _DeviceCandidates:
+    """One execute's merge candidates, kept on the device until the merged
+    top-k is read back.
+
+    Each bucket's results stay on the device as a block of candidate rows
+    (``ops.unit_topk_rows``: scores and arena rows), and the host notes
+    which block row fills each row of the merge buffer (``src``, from the
+    units' query slots alone). The merge's inputs are then one copy of
+    ``src`` and one gather on the device (``ops.gather_candidates``).
+    Values are arena rows, which the f32 path maps to ids after the merge
+    (``ops.candidate_ids``: k per query, not every candidate); a host-side
+    extra's id is stored as N + id, so one merge holds both (int32 on the
+    device, as ids always were: ids below 2^31 - N).
+
+    Query q owns buffer rows ``first[q] + slot``: its plan slots, then one
+    row per host-side extra. Segmented layout: ``first`` is the CSR offset
+    of q's segment (``_seg_offsets``) and the rows pad to a power of two.
+    Dense layout: ``first[q] = q · n_slots``. Either way each query's
+    candidates keep the slot-major order, so the two layouts' merges select
+    the same top-k."""
+
+    def __init__(self, plan, extra, width, *, segmented):
+        m = plan.m
+        if segmented:
+            plan_counts = _plan_seg_counts(plan)
+            _, offsets = _seg_offsets(plan_counts, extra, m)
+            self.first, self.extra_base = offsets[:-1], plan_counts
+            self.n_real = int(offsets[-1])
+            self.rows = _next_pow2(self.n_real, 1)
+            self._ends = offsets[1:].astype(np.int32)
+        else:
+            n_slots = plan.n_slots + _extra_slot_width(extra, m)
+            self.first = np.arange(m, dtype=np.int64) * n_slots
+            self.extra_base = np.full(m, plan.n_slots, dtype=np.int64)
+            self.n_real = self.rows = m * n_slots
+            self._ends = None
+        self.m, self.width, self.segmented = m, width, segmented
+        # a row no block fills reads past the blocks' end: (-inf, -1)
+        self.src = np.full(self.rows, np.iinfo(np.int32).max, dtype=np.int32)
+        self.blocks: list = []
+        self._block_rows = 0
+
+    def _add(self, block, dest: np.ndarray, at: np.ndarray) -> None:
+        """Buffer rows ``dest`` take rows ``at`` of ``block``."""
+        self.src[dest] = self._block_rows + at
+        self._block_rows += int(block[0].shape[0])
+        self.blocks.append(block)
+
+    def add_bucket(self, s, i_loc, starts_d, qrow_of: np.ndarray, slot_of: np.ndarray, *,
+                   n_rows: int) -> None:
+        """One bucket's top-k (``ops.unit_topk_rows``), unit slot (w, t) at
+        block row w·tq + t; pad slots fill no buffer row."""
+        live = qrow_of >= 0
+        with get_tracer().span("merge.scatter", rows=int(live.sum())):
+            block = fence(*kops.unit_topk_rows(s, i_loc, starts_d, n_rows, width=self.width))
+            dest = self.first[qrow_of[live]] + slot_of[live]
+            self._add(block, dest, np.flatnonzero(live.ravel()))
+
+    def add_extras(self, extra: Sequence[ExtraCandidates], *, id_base: int) -> None:
+        """The adaptive executor's host-side candidates, one block copied
+        to the device, in the rows after each query's plan slots in their
+        order; each id stored as ``id_base + id``."""
+        if not extra:
+            return
+        next_extra = self.extra_base.copy()
+        dest, ss, vv = [], [], []
+        for qrows, es, ei in extra:
+            w = min(self.width, es.shape[1])
+            dest.append(self.first[qrows] + next_extra[qrows])
+            next_extra[qrows] += 1
+            s_pad = np.full((len(qrows), self.width), -np.inf, dtype=np.float32)
+            v_pad = np.full((len(qrows), self.width), -1, dtype=np.int32)
+            s_pad[:, :w] = es[:, :w]
+            v_pad[:, :w] = np.where(ei[:, :w] >= 0, id_base + ei[:, :w], -1)
+            ss.append(s_pad)
+            vv.append(v_pad)
+        dest = np.concatenate(dest)
+        block = tuple(to_device("merge.h2d", np.concatenate(ss), np.concatenate(vv)))
+        self._add(block, dest, np.arange(len(dest)))
+
+    def merge(self, k: int, stats: Optional[ScanStats]) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """Every query's top-k of the buffer, (scores, values) [m, k] on the
+        device: the buffer gathered from the blocks, then one
+        ``segmented_merge_topk`` over the CSR segments or one ``merge_topk``
+        over the dense [m, n_slots · width] rows."""
+        m, width = self.m, self.width
+        if not self.blocks:
+            self.blocks.append((jnp.full((1, width), -jnp.inf, jnp.float32),
+                                jnp.full((1, width), -1, jnp.int32)))
+        blocks_s, blocks_v = (list(x) for x in zip(*self.blocks))
+        src_d, *ends_d = to_device(
+            "merge.h2d", self.src, *([self._ends] if self.segmented else [])
         )
+        with get_tracer().span("merge.scatter", rows=int((self.src < self._block_rows).sum())):
+            flat_s, flat_v, seg_of = fence(
+                *kops.gather_candidates(blocks_s, blocks_v, src_d, *ends_d)
+            )
+        # the blocks are freed once the gather has read them
+        del blocks_s, blocks_v
+        self.blocks = []
+        _account_candidates(stats, int(flat_s.nbytes + flat_v.nbytes))
+        prof = get_profiler()
+        tracer = get_tracer()
+        t0 = prof.t0() if prof.enabled else 0
+        if self.segmented:
+            with tracer.span("merge.segmented", m=m, candidates=self.n_real):
+                out = fence(*kops.segmented_merge_topk(flat_s, flat_v, seg_of, m, k))
+            kind, cols = "segmented", self.rows
+            rows = dict(rows=self.n_real, rows_padded=self.rows)
+        else:
+            cols = self.rows // m * width  # n_slots · width
+            with tracer.span("merge.final", m=m, width=cols):
+                out = fence(*kops.merge_topk(
+                    flat_s.reshape(m, cols), flat_v.reshape(m, cols), k
+                ))
+            kind = "final"
+            rows = dict(rows=m * cols, rows_padded=m * cols)
+        if prof.enabled:
+            prof.record_dispatch(
+                "merge", kind, cols, t0,
+                nbytes=int(flat_s.nbytes + flat_v.nbytes) + m * k * 12,
+                flops=0.0, flops_padded=0.0,
+                units=m, units_padded=m, **rows,
+            )
+        return out
 
-    out_scores = np.full((m, n_slots, k), -np.inf, dtype=np.float32)
-    out_idx = np.full((m, n_slots, k), -1, dtype=np.int64)
-    _account_candidates(stats, out_scores.nbytes + out_idx.nbytes)
-    tracer = get_tracer()
 
-    for kk, qr, sl, s_w, gidx_w in _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
-        with tracer.span("merge.scatter"):
-            out_scores[qr, sl, :kk] = s_w
-            out_idx[qr, sl, :kk] = gidx_w
-
-    return _fold_extras_and_merge(out_scores, out_idx, extra, plan.n_slots, k)
+def _wait_for(prev) -> None:
+    """Before a bucket's copy and gather, wait for the previous bucket's
+    scan: the host reads nothing back per bucket and would otherwise queue
+    every bucket's gathered tiles on the device at once. One bucket's tiles
+    are live at a time, as when each bucket was read back, and the host
+    still assembles the next bucket while the device scans this one."""
+    if prev is not None:
+        jax.block_until_ready(prev)
 
 
-def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
-    """Run the f32 scan stage bucket by bucket (one ``workunit_topk`` dispatch
-    each), yielding (kk, qrows, slots, scores [n, kk], gids [n, kk]) for the
-    real unit slots — the scatter destination is the only thing the dense and
-    segmented layouts disagree on, so the scan math lives here once.
+def _scan_f32_buckets(cand, plan, arena, q_vecs, cfg, stats) -> None:
+    """Run the f32 scan stage bucket by bucket (one ``workunit_topk``
+    dispatch each) and add each bucket's top-k to ``cand``, on the device,
+    the dense and segmented layouts alike.
 
-    The operands never cross from the host per bucket: the arena's rows are
-    resident on the device (``PackedArena.device_rows``, one upload per
-    arena), the queries are copied once per call (``query.h2d``), and each
-    bucket ships only its units' list starts, query rows and bitmap
-    (``scan.h2d``) for ``ops.gather_unit_operands`` to gather the tiles on
-    the device (``scan.gather``). Each bucket's host stages are spans of
-    their own (``scan.assemble``, ``scan.h2d``, ``scan.gather``,
-    ``dispatch.scan``, ``scan.d2h``, ``scan.remap``); the caller's scatter is
-    ``merge.scatter``."""
+    Nothing crosses per bucket but its unit table, and nothing comes back:
+    the arena's rows are resident on the device (``PackedArena.device_rows``,
+    one upload per arena), the queries are copied once per call
+    (``query.h2d``), and each bucket ships its units' list starts, query
+    rows and bitmap (``scan.h2d``) for ``ops.gather_unit_operands`` to
+    gather the tiles (``scan.gather``); its results stay on the device as
+    a block of merge candidates (``merge.scatter``)."""
     if not plan.buckets:
         return
-    m, k, tq = plan.m, plan.k, plan.tq
+    k, tq = plan.k, plan.tq
     d = q_vecs.shape[1]
     prof = get_profiler()
     tracer = get_tracer()
     rows_dev = arena.device_rows()
     q_dev = _device_queries(q_vecs)
+    prev = None
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
         Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         W = Vrows.shape[0]
-        wmask = qrow_of >= 0  # [W, tq]
         if stats is not None:
             # real work units only (pow2 pad excluded), so the figure is
             # comparable across configurations — the sharded executor counts
             # the same way per rank
             stats.bytes_scanned += len(units) * lp * d * 4
+        _wait_for(prev)
         # a unit's rows are min(start + arange(lp), n - 1), so its first row
         # is its start as the gather reads it (pad units: row 0, all masked)
         starts_d, qrow_d, valid_d = to_device(
@@ -282,11 +417,11 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
                 interpret=cfg.interpret,
             )
             s, i_loc = fence(s, i_loc)  # device time is real iff tracing is on
-        del starts_d, qrow_d, valid_d, Q, V  # one bucket's operands at a time
+        del qrow_d, valid_d, Q, V  # one bucket's operands at a time
         if prof.enabled:
             # real distance work: 2·d MACs per (query, live row) pair within
             # each unit; padded work covers the full [W, tq, lp] bucket
-            nq_u = wmask.sum(axis=1)
+            nq_u = (qrow_of >= 0).sum(axis=1)
             rows_u = valid.sum(axis=1)
             # the kernel reads the Q and V tiles and the bitmap
             prof.record_dispatch(
@@ -299,18 +434,8 @@ def _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
                 rows=int(rows_u.sum()), rows_padded=W * lp,
             )
         # i_loc: index within the unit's lp rows (-1 = none)
-        s, i_loc = to_host("scan.d2h", s, i_loc)
-        kk = s.shape[-1]
-        with tracer.span("scan.remap"):
-            packed_rows = np.take_along_axis(
-                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-                np.maximum(i_loc, 0),
-                axis=2,
-            )
-            gidx = arena.gid[packed_rows]
-            gidx = np.where(i_loc < 0, -1, gidx)
-            out = (kk, qrow_of[wmask], slot_of[wmask], s[wmask], gidx[wmask])
-        yield out
+        cand.add_bucket(s, i_loc, starts_d, qrow_of, slot_of, n_rows=arena.n)
+        prev = s
 
 
 def _plan_seg_counts(plan: ExecutionPlan) -> np.ndarray:
@@ -320,72 +445,6 @@ def _plan_seg_counts(plan: ExecutionPlan) -> np.ndarray:
     if len(plan.seg_counts) == plan.m:
         return plan.seg_counts
     return np.full(plan.m, plan.n_slots, dtype=np.int64)
-
-
-def _execute_plan_f32_segmented(
-    plan: ExecutionPlan,
-    arena: Optional[PackedArena],
-    q_vecs: np.ndarray,
-    *,
-    cfg: PlanConfig,
-    extra: Sequence[ExtraCandidates],
-    stats: Optional[ScanStats],
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Segmented (CSR) counterpart of the dense f32 path.
-
-    Per-unit top-ks scatter into ONE flat [C_pad, k] buffer at
-    offsets[q] + slot — query q's segment holds exactly its own plan slots
-    plus its host-side extras, so peak merge memory is Σ seg_counts·k
-    instead of m·n_slots·k. One ``segmented_merge_topk`` dispatch reduces
-    every ragged segment; within each segment candidates keep the dense
-    layout's slot-major order, so results are bit-identical (parity suite).
-    """
-    m, k = plan.m, plan.k
-    plan_counts = _plan_seg_counts(plan)
-    counts, offsets = _seg_offsets(plan_counts, extra, m)
-    C_total = int(offsets[-1])
-    C_pad = _next_pow2(C_total, 1)
-    flat_s = np.full((C_pad, k), -np.inf, dtype=np.float32)
-    flat_i = np.full((C_pad, k), -1, dtype=np.int64)
-    seg_of = np.full(C_pad, m, dtype=np.int32)  # pad rows -> dropped segment
-    seg_of[:C_total] = np.repeat(np.arange(m, dtype=np.int32), counts)
-    _account_candidates(stats, flat_s.nbytes + flat_i.nbytes)
-    tracer = get_tracer()
-
-    for kk, qr, sl, s_w, gidx_w in _iter_f32_buckets(plan, arena, q_vecs, cfg, stats):
-        with tracer.span("merge.scatter"):
-            rows = offsets[qr] + sl
-            flat_s[rows, :kk] = s_w
-            flat_i[rows, :kk] = gidx_w
-
-    # extras take the rows after each query's plan slots (same relative order
-    # as the dense layout's extra columns)
-    with tracer.span("merge.scatter"):
-        next_extra = plan_counts.copy()
-        for qrows, es, ei in extra:
-            kk = min(k, es.shape[1])
-            rows = offsets[qrows] + next_extra[qrows]
-            next_extra[qrows] += 1
-            flat_s[rows, :kk] = es[:, :kk]
-            flat_i[rows, :kk] = ei[:, :kk]
-
-    operands = to_device("merge.h2d", flat_s, flat_i, seg_of)
-    prof = get_profiler()
-    t0 = prof.t0() if prof.enabled else 0
-    with tracer.span("merge.segmented", m=m, candidates=C_total):
-        top_s, top_i = kops.segmented_merge_topk(*operands, m, k)
-        top_s, top_i = fence(top_s, top_i)
-    del operands
-    if prof.enabled:
-        prof.record_dispatch(
-            "merge", "segmented", C_pad, t0,
-            nbytes=flat_s.nbytes + flat_i.nbytes + seg_of.nbytes + m * k * 12,
-            flops=0.0, flops_padded=0.0,
-            units=m, units_padded=m,
-            rows=C_total, rows_padded=C_pad,
-        )
-    top_s, top_i = to_host("merge.d2h", top_s, top_i)
-    return top_s.astype(np.float32, copy=False), top_i.astype(np.int64)
 
 
 def _extra_slot_width(extra: Sequence[ExtraCandidates], m: int) -> int:
@@ -405,8 +464,9 @@ def _fold_extras_and_merge(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Fold the adaptive executor's host-side candidates in, then final-merge.
 
-    Shared tail of both scan modes, so extras handling can never diverge
-    between the f32 and pq paths.
+    The host-side tail of the pq re-rank and the sharded paths, whose
+    candidates are on the host already (the single-device f32 path merges
+    its extras on the device: ``_DeviceCandidates.add_extras``).
     """
     m = out_scores.shape[0]
     with get_tracer().span("merge.scatter"):
@@ -497,8 +557,7 @@ def _execute_plan_pq(
     luts_dev = _resident_adc_tables(arena.pq, q_vecs[used])  # [U, Mp, 256]
     _account_lut(stats, int(luts_dev.nbytes), expanded=False)
 
-    stage_a = _pq_stage_a_segmented if cfg.merge_layout == "segmented" else _pq_stage_a_dense
-    rows = stage_a(
+    rows = _pq_stage_a(
         plan, arena, codes_dev, luts_dev, lut_pos, kprime, cfg=cfg, stats=stats
     )
     return _pq_rerank_and_fold(
@@ -529,25 +588,7 @@ def _resident_adc_tables(pq: PQCodebook, q_used: np.ndarray) -> jnp.ndarray:
         )
 
 
-def _pq_bucket_operands(units, lp, plan, arena, lut_pos):
-    """One bucket's ADC operands: the host assembles it and ships only the
-    units' list starts, LUT rows and bitmap (``scan.h2d``); its code tiles
-    are gathered from the resident codes by the caller (``scan.gather``).
-    Returns (Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d)."""
-    Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
-    lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
-    # a unit's rows are min(start + arange(lp), n - 1), so its first row
-    # is its start as the gather reads it (pad units: row 0, all masked)
-    starts_d, lut_idx_d, valid_d = to_device(
-        "scan.h2d",
-        Vrows[:, 0].astype(np.int32),
-        lut_idx.astype(np.int32),
-        valid,
-    )
-    return Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d
-
-
-def _pq_stage_a_dense(
+def _pq_stage_a(
     plan: ExecutionPlan,
     arena: PackedArena,
     codes_dev: jnp.ndarray,  # uint8 [N, M] — the arena's resident codes
@@ -558,185 +599,89 @@ def _pq_stage_a_dense(
     cfg: PlanConfig,
     stats: Optional[ScanStats],
 ) -> np.ndarray:
-    """Dense ADC stage A: [m, n_slots, k'] scatter + rectangular merge.
-    Returns the surviving global packed rows i64 [m, k'] (-1 pad)."""
-    m = plan.m
+    """ADC stage A: per bucket one ADC dispatch keeping k' candidates per
+    (query, posting list), kept on the device as merge candidates of packed
+    rows (``_DeviceCandidates``: the re-rank gathers rows); one merge, and
+    the per-query top-k' rows read back once. Returns them, global packed
+    rows i64 [m, k'] (-1 pad).
+
+    Each bucket ships only its units' list starts, LUT rows and bitmap
+    (``scan.h2d``); its code tiles are gathered from the
+    resident codes (``scan.gather``). The segmented layout dispatches
+    ``workunit_pq_topk_resident``, which indexes the resident LUT table by
+    per-slot row, so no per-bucket [W, tq, M, 256] operand materializes
+    (``lut_expand_bytes`` stays 0); the dense layout expands it with a
+    device gather for ``workunit_pq_topk``.
+    """
+    segmented = cfg.merge_layout == "segmented"
+    mode = "pq-res" if segmented else "pq"
     M = int(codes_dev.shape[1])
-    cand_s = np.full((m, plan.n_slots, kprime), -np.inf, dtype=np.float32)
-    cand_rows = np.full((m, plan.n_slots, kprime), -1, dtype=np.int64)
-    _account_candidates(stats, cand_s.nbytes + cand_rows.nbytes)
+    # stage A has no extras: they fold in after the re-rank
+    cand = _DeviceCandidates(plan, (), kprime, segmented=segmented)
     prof = get_profiler()
     tracer = get_tracer()
-
+    prev = None
     for lp in sorted(plan.buckets):
         units = plan.buckets[lp]
-        Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d = _pq_bucket_operands(
-            units, lp, plan, arena, lut_pos
-        )
+        Vrows, valid, qrow_of, slot_of = _assemble_bucket(units, lp, plan, arena)
         W = Vrows.shape[0]
-        wmask = qrow_of >= 0
+        lut_idx = lut_pos[np.maximum(qrow_of, 0)]  # [W, tq]; pads -> LUT row 0
+        _wait_for(prev)
+        # a unit's rows are min(start + arange(lp), n - 1), so its first row
+        # is its start as the gather reads it (pad units: row 0, all masked)
+        starts_d, lut_idx_d, valid_d = to_device(
+            "scan.h2d", Vrows[:, 0].astype(np.int32), lut_idx.astype(np.int32), valid
+        )
+        luts = None
         with tracer.span("scan.gather"):
-            # [W, lp, M] uint8 and [W, tq, Mp, 256]: the compressed gather and
-            # the expanded tables, both on the device
-            codes_d, luts = fence(
-                kops.gather_unit_codes(codes_dev, starts_d, lp=lp),
-                jnp.take(luts_dev, lut_idx_d, axis=0),
-            )
-        del starts_d, lut_idx_d
-        _account_lut(stats, int(luts.nbytes), expanded=True)
+            # [W, lp, M] uint8 from the resident codes; the dense layout also
+            # expands the tables, [W, tq, Mp, 256], on the device
+            codes_d = kops.gather_unit_codes(codes_dev, starts_d, lp=lp)
+            if segmented:
+                codes_d = fence(codes_d)
+            else:
+                codes_d, luts = fence(codes_d, jnp.take(luts_dev, lut_idx_d, axis=0))
+                _account_lut(stats, int(luts.nbytes), expanded=True)
         if stats is not None:
             stats.bytes_scanned += len(units) * lp * M
         kk = min(kprime, lp)
         t0 = prof.t0() if prof.enabled else 0
-        with tracer.span("dispatch.scan", mode="pq", lp=lp, units=len(units)):
-            s, i_loc = kops.workunit_pq_topk(
-                luts,
-                codes_d,
-                valid_d,
-                kk,
-                use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret,
-            )
+        with tracer.span("dispatch.scan", mode=mode, lp=lp, units=len(units)):
+            if segmented:
+                s, i_loc = kops.workunit_pq_topk_resident(
+                    luts_dev, lut_idx_d, codes_d, valid_d, kk,
+                    use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+                )
+            else:
+                s, i_loc = kops.workunit_pq_topk(
+                    luts, codes_d, valid_d, kk,
+                    use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+                )
             s, i_loc = fence(s, i_loc)
-        del codes_d, valid_d
+        del lut_idx_d, codes_d, valid_d
         if prof.enabled:
-            # one-hot MXU contraction: 2·M·256 MACs per (query, live row)
-            nq_u = wmask.sum(axis=1)
+            # one-hot MXU contraction: 2·M·256 MACs per (query, live row); the
+            # resident path streams one [M, 256] LUT row per LIVE query slot
+            # instead of reading the expanded [W, tq, M, 256] operand
+            nq_u = (qrow_of >= 0).sum(axis=1)
             rows_u = valid.sum(axis=1)
+            lut_bytes = int(nq_u.sum()) * M * 256 * 4 if segmented else int(luts.nbytes)
             prof.record_dispatch(
-                "scan", "pq", lp, t0,
-                nbytes=int(luts.nbytes) + W * lp * M + valid.nbytes
+                "scan", mode, lp, t0,
+                nbytes=lut_bytes + W * lp * M + valid.nbytes
                 + W * plan.tq * kk * 12,
                 flops=2.0 * M * 256 * float((nq_u * rows_u).sum()),
                 flops_padded=2.0 * M * 256 * W * plan.tq * lp,
                 units=len(units), units_padded=W,
                 rows=int(rows_u.sum()), rows_padded=W * lp,
             )
+        del luts
         # i_loc: [W, tq, kk] index into the unit's lp rows
-        s, i_loc = to_host("scan.d2h", s, i_loc)
-        with tracer.span("scan.remap"):
-            packed_rows = np.take_along_axis(
-                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-                np.maximum(i_loc, 0),
-                axis=2,
-            )
-            packed_rows = np.where(i_loc < 0, -1, packed_rows)
-        with tracer.span("merge.scatter"):
-            qr = qrow_of[wmask]
-            sl = slot_of[wmask]
-            cand_s[qr, sl, :kk] = s[wmask]
-            cand_rows[qr, sl, :kk] = packed_rows[wmask]
+        cand.add_bucket(s, i_loc, starts_d, qrow_of, slot_of, n_rows=arena.n)
+        prev = s
 
     # per-query top-k' ADC candidates across every bucket and probe slot
-    _, top_rows = _padded_merge(
-        cand_s.reshape(m, -1), cand_rows.reshape(m, -1), kprime
-    )
-    (top_rows,) = to_host("merge.d2h", top_rows)
-    return top_rows.astype(np.int64)  # [m, k'] packed rows (-1 pad)
-
-
-def _pq_stage_a_segmented(
-    plan: ExecutionPlan,
-    arena: PackedArena,
-    codes_dev: jnp.ndarray,  # uint8 [N, M] — the arena's resident codes
-    luts_dev: jnp.ndarray,  # f32 [U, Mp, 256]
-    lut_pos: np.ndarray,  # i64 [m]
-    kprime: int,
-    *,
-    cfg: PlanConfig,
-    stats: Optional[ScanStats],
-) -> np.ndarray:
-    """Segmented ADC stage A: flat [Σ seg_counts, k'] scatter + ragged merge.
-
-    Each bucket dispatches ``workunit_pq_topk_resident`` — the kernel indexes
-    the resident LUT table by per-slot row, so the dense path's per-bucket
-    [W, tq, M, 256] expansion never materializes (lut_expand_bytes stays 0).
-    Returns the surviving global packed rows i64 [m, k'] (-1 pad).
-    """
-    m = plan.m
-    M = int(codes_dev.shape[1])
-    counts = _plan_seg_counts(plan)  # stage A has no extras; they fold post re-rank
-    offsets = np.zeros(m + 1, dtype=np.int64)
-    np.cumsum(counts, out=offsets[1:])
-    C_total = int(offsets[-1])
-    C_pad = _next_pow2(C_total, 1)
-    flat_s = np.full((C_pad, kprime), -np.inf, dtype=np.float32)
-    flat_rows = np.full((C_pad, kprime), -1, dtype=np.int64)
-    seg_of = np.full(C_pad, m, dtype=np.int32)
-    seg_of[:C_total] = np.repeat(np.arange(m, dtype=np.int32), counts)
-    _account_candidates(stats, flat_s.nbytes + flat_rows.nbytes)
-    prof = get_profiler()
-    tracer = get_tracer()
-
-    for lp in sorted(plan.buckets):
-        units = plan.buckets[lp]
-        Vrows, valid, qrow_of, slot_of, starts_d, lut_idx_d, valid_d = _pq_bucket_operands(
-            units, lp, plan, arena, lut_pos
-        )
-        wmask = qrow_of >= 0
-        with tracer.span("scan.gather"):
-            codes_d = fence(kops.gather_unit_codes(codes_dev, starts_d, lp=lp))  # [W, lp, M]
-        del starts_d
-        if stats is not None:
-            stats.bytes_scanned += len(units) * lp * M
-        kk = min(kprime, lp)
-        t0 = prof.t0() if prof.enabled else 0
-        with tracer.span("dispatch.scan", mode="pq-res", lp=lp, units=len(units)):
-            s, i_loc = kops.workunit_pq_topk_resident(
-                luts_dev,
-                lut_idx_d,
-                codes_d,
-                valid_d,
-                kk,
-                use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret,
-            )
-            s, i_loc = fence(s, i_loc)
-        del lut_idx_d, codes_d, valid_d
-        if prof.enabled:
-            # the resident path streams one [M, 256] LUT row per LIVE query
-            # slot instead of expanding [W, tq, M, 256]
-            W = Vrows.shape[0]
-            nq_u = wmask.sum(axis=1)
-            rows_u = valid.sum(axis=1)
-            prof.record_dispatch(
-                "scan", "pq-res", lp, t0,
-                nbytes=W * lp * M + valid.nbytes
-                + int(nq_u.sum()) * M * 256 * 4 + W * plan.tq * kk * 12,
-                flops=2.0 * M * 256 * float((nq_u * rows_u).sum()),
-                flops_padded=2.0 * M * 256 * W * plan.tq * lp,
-                units=len(units), units_padded=W,
-                rows=int(rows_u.sum()), rows_padded=W * lp,
-            )
-        s, i_loc = to_host("scan.d2h", s, i_loc)
-        with tracer.span("scan.remap"):
-            packed_rows = np.take_along_axis(
-                np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
-                np.maximum(i_loc, 0),
-                axis=2,
-            )
-            packed_rows = np.where(i_loc < 0, -1, packed_rows)
-        with tracer.span("merge.scatter"):
-            qr = qrow_of[wmask]
-            rows_f = offsets[qr] + slot_of[wmask]
-            flat_s[rows_f, :kk] = s[wmask]
-            flat_rows[rows_f, :kk] = packed_rows[wmask]
-
-    operands = to_device("merge.h2d", flat_s, flat_rows, seg_of)
-    t0 = prof.t0() if prof.enabled else 0
-    with tracer.span("merge.segmented", m=m, candidates=C_total):
-        _, top_rows = kops.segmented_merge_topk(*operands, m, kprime)
-        top_rows = fence(top_rows)
-    del operands
-    if prof.enabled:
-        prof.record_dispatch(
-            "merge", "segmented", C_pad, t0,
-            nbytes=flat_s.nbytes + flat_rows.nbytes + seg_of.nbytes
-            + m * kprime * 12,
-            flops=0.0, flops_padded=0.0,
-            units=m, units_padded=m,
-            rows=C_total, rows_padded=C_pad,
-        )
+    _, top_rows = cand.merge(kprime, stats)
     (top_rows,) = to_host("merge.d2h", top_rows)
     return top_rows.astype(np.int64)
 

@@ -375,6 +375,71 @@ def gather_unit_codes(
     return _unit_tiles(codes, starts, lp)
 
 
+@functools.partial(jax.jit, static_argnames=("width",))
+def unit_topk_rows(
+    s: jax.Array,  # f32 [W, TQ, kk] — one bucket's per-unit top-k
+    i_loc: jax.Array,  # i32 [W, TQ, kk] — index into the unit's rows (-1: none)
+    starts: jax.Array,  # i32 [W] — first arena row of each unit's posting list
+    n_rows,  # the arena's row count N
+    width: int,  # the merge buffer's width, >= kk
+) -> tuple[jax.Array, jax.Array]:
+    """One bucket's results as merge candidate rows, on the device: scores
+    f32 and arena rows i32 [W·TQ, width], slot (w, t) at row w·TQ + t.
+
+    A unit's rows are ``min(starts[w] + arange(lp), N - 1)`` (as
+    ``gather_unit_operands`` reads them), so the result at ``i_loc`` is
+    arena row ``min(starts[w] + i_loc, N - 1)``, and -1 where ``i_loc``
+    is; columns past kk pad with (-inf, -1), what an empty candidate holds.
+    Elementwise work only: the rows reach their merge order in one gather
+    (``gather_candidates``), since a scatter of rows narrower than the
+    buffer runs one row at a time on a TPU.
+    """
+    row = jnp.minimum(starts[:, None, None] + jnp.maximum(i_loc, 0), n_rows - 1)
+    row = jnp.where(i_loc < 0, -1, row)
+    kk = s.shape[-1]
+    pad = ((0, 0), (0, width - kk))
+    return (
+        jnp.pad(s.reshape(-1, kk), pad, constant_values=-jnp.inf),
+        jnp.pad(row.reshape(-1, kk), pad, constant_values=-1),
+    )
+
+
+@jax.jit
+def gather_candidates(
+    blocks_s: list,  # f32 [n_b, width] per block — candidate scores
+    blocks_v: list,  # i32 [n_b, width] per block — their values (rows or ids)
+    src: jax.Array,  # i32 [C] — block row (blocks concatenated) of each buffer row
+    ends: jax.Array | None = None,  # i32 [m] — CSR end of each segment, ascending
+) -> tuple[jax.Array, jax.Array, jax.Array | None]:
+    """The merge buffer laid out from candidate blocks in one row gather:
+    buffer row c is row ``src[c]`` of the concatenated blocks, and (-inf,
+    -1) where ``src[c]`` is past their end. With ``ends``, also each
+    row's segment, i32 [C]: segment q holds rows ``ends[q-1] .. ends[q]-1``,
+    rows past the last end go to segment ``len(ends)``, the merge's
+    dropped one."""
+    s = jnp.concatenate(blocks_s)
+    v = jnp.concatenate(blocks_v)
+    out_s = jnp.take(s, src, axis=0, mode="fill", fill_value=-jnp.inf)
+    out_v = jnp.take(v, src, axis=0, mode="fill", fill_value=-1)
+    if ends is None:
+        return out_s, out_v, None
+    starts_here = jnp.zeros(src.shape[0] + 1, jnp.int32).at[ends].add(1)
+    return out_s, out_v, jnp.cumsum(starts_here[:-1]).astype(jnp.int32)
+
+
+@jax.jit
+def candidate_ids(
+    v: jax.Array,  # i32 [m, k] — merged candidate values
+    n_rows,  # the arena's row count N
+    gid: jax.Array | None = None,  # i32 [N] — arena row -> id
+) -> jax.Array:
+    """Ids of merged candidates: an arena row r < N is ``gid[r]``; a value
+    N + id is a host-side extra's id; a negative value is none (-1)."""
+    ids = v if gid is None else jnp.take(gid, jnp.clip(v, 0, gid.shape[0] - 1), axis=0)
+    ids = jnp.where(v >= n_rows, v - n_rows, ids)
+    return jnp.where(v < 0, -1, ids)
+
+
 # --------------------------------------------------------------------------
 # Sharded dispatch (device-mesh execution, see core/planner.py's sharded path)
 #

@@ -11,7 +11,9 @@ Covered: {ip, l2} × {f32, pq} engine parity with forced score ties and
 bitmap pushdown; skewed per-template routing through HQIIndex (1-vs-all
 nprobe dicts → ragged segment widths); empty segments (templates matching
 nothing); k larger than every segment; the adaptive executor's extras
-folding (batch_vec="auto"); the resident-LUT invariant (segmented pq never
+folding (batch_vec="auto"); the merge buffer laid out on the device from
+each bucket's results against the host remap and scatter it replaced; the
+resident-LUT invariant (segmented pq never
 materializes a [W, TQ, M, 256] operand: DispatchStats.lut_expand_bytes == 0);
 kernel-level oracle checks for ``segmented_merge_topk`` and the streamed
 Pallas ADC grid; and a hypothesis property over random segment shapes.
@@ -29,7 +31,7 @@ import jax.numpy as jnp
 from repro.core import HQIConfig, HQIIndex, planner
 from repro.core.arena import PackedArena
 from repro.core.ivf import IVFIndex, ScanStats
-from repro.core.plan import EngineTask, PlanConfig, build_plan
+from repro.core.plan import EngineTask, PlanConfig, _next_pow2, build_plan
 from repro.core.planner import batch_search_ivf, execute_plan
 from repro.core.pq import train_pq
 from repro.core.types import Workload
@@ -139,6 +141,172 @@ def test_resident_gather_matches_host_gather(layout, use_pallas, monkeypatch):
     want = execute_plan(plan, arena, q, cfg=cfg)
     assert_exact(got, want, f"{layout} pallas={use_pallas}")
     assert (got[1] >= 0).all()
+
+
+def _host_remap_scatter(arena, plan, buckets, extra, layout, mode, kk):
+    """The candidate buffer as the executor built it on the host before its
+    scatter moved to the device: each bucket's top-k read back, the local
+    indices mapped through the unit's packed rows (and ``arena.gid`` in
+    f32), scattered at offsets[q] + slot (segmented) or [q, slot] (dense),
+    the extras in the rows after each query's plan slots. Returns (scores,
+    ids, seg_of) as the merge reads them (seg_of None in dense)."""
+    m = plan.m
+    extra_slots = np.zeros(m, dtype=np.int64)
+    for qrows, _, _ in extra:
+        extra_slots[qrows] += 1
+    if layout == "segmented":
+        counts = plan.seg_counts + extra_slots
+        offsets = np.concatenate([[0], np.cumsum(counts)])
+        C_pad = _next_pow2(int(offsets[-1]), 1)
+        out_s = np.full((C_pad, kk), -np.inf, dtype=np.float32)
+        out_i = np.full((C_pad, kk), -1, dtype=np.int64)
+        seg_of = np.full(C_pad, m, dtype=np.int32)
+        seg_of[: offsets[-1]] = np.repeat(np.arange(m, dtype=np.int32), counts)
+    else:
+        n_slots = plan.n_slots + int(extra_slots.max())
+        out_s = np.full((m, n_slots, kk), -np.inf, dtype=np.float32)
+        out_i = np.full((m, n_slots, kk), -1, dtype=np.int64)
+        seg_of = None
+    for Vrows, _, qrow_of, slot_of, s, i_loc in buckets:
+        lp = Vrows.shape[1]
+        w = s.shape[-1]
+        wmask = qrow_of >= 0
+        packed_rows = np.take_along_axis(
+            np.broadcast_to(Vrows[:, None, :], i_loc.shape[:2] + (lp,)),
+            np.maximum(i_loc, 0),
+            axis=2,
+        )
+        ids = arena.gid[packed_rows] if mode == "f32" else packed_rows
+        ids = np.where(i_loc < 0, -1, ids)
+        qr, sl = qrow_of[wmask], slot_of[wmask]
+        if layout == "segmented":
+            out_s[offsets[qr] + sl, :w] = s[wmask]
+            out_i[offsets[qr] + sl, :w] = ids[wmask]
+        else:
+            out_s[qr, sl, :w] = s[wmask]
+            out_i[qr, sl, :w] = ids[wmask]
+    next_extra = plan.seg_counts.copy() if layout == "segmented" else np.full(m, plan.n_slots)
+    for qrows, es, ei in extra:
+        w = min(kk, es.shape[1])
+        slot = next_extra[qrows]
+        next_extra[qrows] += 1
+        if layout == "segmented":
+            out_s[offsets[qrows] + slot, :w] = es[:, :w]
+            out_i[offsets[qrows] + slot, :w] = ei[:, :w]
+        else:
+            out_s[qrows, slot, :w] = es[:, :w]
+            out_i[qrows, slot, :w] = ei[:, :w]
+    if layout == "dense":
+        out_s, out_i = out_s.reshape(m, -1), out_i.reshape(m, -1)
+    return out_s, out_i, seg_of
+
+
+def _scatter_case(metric, mode, layout):
+    """Two partitions, one task behind a bitmap and one over every list of
+    the other, k above most list lengths (so results run out: i_loc = -1),
+    queries that probe nothing, and host-side extras of two widths, one of
+    them for a query with no plan slots."""
+    rng = np.random.default_rng(31)
+    d, m, k = 16, 21, 40
+    parts = []
+    for p, n in enumerate((300, 261)):
+        vecs = rng.normal(size=(n, d)).astype(np.float32)
+        ivf = IVFIndex.build(vecs, metric=metric, n_centroids=8, kmeans_iters=4, seed=p)
+        parts.append((300 * p + np.arange(n, dtype=np.int64), ivf))
+    arena = PackedArena.from_partitions(parts)
+    if mode == "pq":
+        arena.attach_pq(train_pq(arena.packed, 4, metric=metric, iters=4, seed=0))
+    q = rng.normal(size=(m, d)).astype(np.float32)
+    tasks = [
+        EngineTask(part=0, qrows=np.arange(0, 12, dtype=np.int64), nprobe=4,
+                   packed_bitmap=arena.packed_bitmap(0, rng.random(300) < 0.5)),
+        # queries 17..20 probe nothing
+        EngineTask(part=1, qrows=np.arange(7, 17, dtype=np.int64), nprobe=8,
+                   packed_bitmap=None),
+    ]
+
+    def extras(qrows, width):
+        s = -np.sort(rng.random((len(qrows), width)).astype(np.float32), axis=1)
+        i = rng.integers(0, 561, size=(len(qrows), width)).astype(np.int64)
+        i[:, -2:] = -1
+        return np.asarray(qrows, dtype=np.int64), s, i
+
+    extra = [extras([3, 17, 18], k), extras([3, 18], k - 5)]
+    cfg = PlanConfig(tq_unit=8, min_list_pad=8, merge_layout=layout, scan_mode=mode,
+                     refine_factor=2, use_pallas=False)
+    plan = build_plan(arena, tasks, q, m=m, k=k, cfg=cfg)
+    return arena, plan, q, cfg, extra
+
+
+@pytest.mark.parametrize("layout", ["segmented", "dense"])
+@pytest.mark.parametrize("mode", ["f32", "pq"])
+@pytest.mark.parametrize("metric", ["ip", "l2"])
+def test_device_scatter_matches_host_remap_and_scatter(metric, mode, layout, monkeypatch):
+    """Each bucket's results kept on the device as candidate rows
+    (``ops.unit_topk_rows``) and laid out as the merge buffer in one gather
+    (``ops.gather_candidates``) fill it bit for bit as the host remap and
+    scatter did: scores, values (arena rows, mapped to ids after the merge
+    in f32; a host-side extra's id stored as N + id) and the segments the
+    merge reads, so the answers are the host path's too. Pad units,
+    results that run out, k above the list length, empty segments and
+    host-side extras all occur."""
+    arena, plan, q, cfg, extra = _scatter_case(metric, mode, layout)
+    buckets, merges = [], []
+    assemble, unit_rows = planner._assemble_bucket, ops.unit_topk_rows
+    seg_merge, final_merge = ops.segmented_merge_topk, ops.merge_topk
+
+    def recording_assemble(*args, **kw):
+        buckets.append(list(assemble(*args, **kw)))
+        return tuple(buckets[-1])
+
+    def recording_rows(s, i_loc, *rest, **kw):
+        buckets[-1] += [np.asarray(s), np.asarray(i_loc)]
+        return unit_rows(s, i_loc, *rest, **kw)
+
+    def recording_seg(flat_s, flat_i, seg_of, n_segments, k):
+        merges.append((np.asarray(flat_s), np.asarray(flat_i), np.asarray(seg_of)))
+        return seg_merge(flat_s, flat_i, seg_of, n_segments, k)
+
+    def recording_final(scores, idx, k):
+        merges.append((np.asarray(scores), np.asarray(idx), None))
+        return final_merge(scores, idx, k)
+
+    monkeypatch.setattr(planner, "_assemble_bucket", recording_assemble)
+    monkeypatch.setattr(ops, "unit_topk_rows", recording_rows)
+    monkeypatch.setattr(ops, "segmented_merge_topk", recording_seg)
+    monkeypatch.setattr(ops, "merge_topk", recording_final)
+    got = execute_plan(plan, arena, q, cfg=cfg, extra=extra)
+    assert len(buckets) == len(plan.buckets) and all(len(b) == 6 for b in buckets)
+    # f32 merges the extras with the scan; pq's stage A merges the ADC
+    # candidates alone (its extras fold after the re-rank)
+    got_s, got_v, got_seg = merges[0]
+    k = plan.k * (2 if mode == "pq" else 1)
+    want_s, want_i, want_seg = _host_remap_scatter(
+        arena, plan, buckets, extra if mode == "f32" else (), layout, mode, k
+    )
+    if mode == "f32":
+        n = arena.n
+        got_i = np.where(got_v >= n, got_v - n, arena.gid[np.clip(got_v, 0, n - 1)])
+        got_i = np.where(got_v < 0, -1, got_i)
+    else:
+        got_i = got_v
+    assert np.array_equal(got_s, want_s), "scores diverge"
+    assert np.array_equal(got_i, want_i), "ids diverge"
+    if layout == "segmented":
+        assert np.array_equal(got_seg, want_seg), "segments diverge"
+        want = seg_merge(jnp.asarray(want_s), jnp.asarray(want_i), jnp.asarray(want_seg),
+                         plan.m, plan.k)
+    else:
+        want = final_merge(jnp.asarray(want_s), jnp.asarray(want_i), plan.k)
+    if mode == "f32":
+        assert_exact(got, tuple(np.asarray(x) for x in want), f"{metric}/{layout}")
+    assert (got[1][19:] == -1).all() and np.isneginf(got[0][19:]).all()
+    # the cases the buffer has to get right all occur
+    assert any((b[2] < 0).all(axis=1).any() for b in buckets), "no pad unit"
+    assert any((b[5][b[2] >= 0] < 0).any() for b in buckets), "no result ran out"
+    lens = arena.list_len[[u.glist for units in plan.buckets.values() for u in units]]
+    assert (lens < plan.k).any(), "k within every list"
+    assert (plan.seg_counts == 0).any(), "no empty segment"
 
 
 def _search_layout(hqi, wl, layout, **kw):

@@ -255,3 +255,41 @@ def test_adc_tables_compile(one_chip):
     (luts,) = jax.tree_util.tree_leaves(lowered.out_info)
     assert luts.shape == (2048, 56, 256) and luts.dtype == jnp.float32
     lowered.compile()
+
+
+# the merge buffer of a kg pass (2^19 candidate rows) laid out from the kg
+# cell's buckets (W units of TQ slots), and the widest step: the (8192, 32)
+# bucket, k = 10 in f32, k' = 40 columns of which 32 filled in pq
+@pytest.mark.parametrize("mode", ["f32", "pq"])
+def test_merge_candidates_compile_without_a_loop(one_chip, readers, mode):
+    """A TPU lowers a scatter of rows narrower than its buffer to a loop
+    over the rows (a pq pass took seconds so): the candidate steps must
+    compile to no loop, and keep names the merge reader does not take."""
+    _, is_merge, Op = readers
+    rows, n, kk = 1 << 19, 1_000_000, (10 if mode == "f32" else 32)
+    width = K if mode == "f32" else K_PQ
+
+    def arg(sh, dt):
+        return jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+
+    lowered = ops.unit_topk_rows.lower(
+        arg((8192, TQ, kk), jnp.float32), arg((8192, TQ, kk), jnp.int32),
+        arg((8192,), jnp.int32), n, width=width,
+    )
+    assert [o.shape for o in lowered.out_info] == [(8192 * TQ, width)] * 2
+    hlos = [lowered.compile().as_text()]
+    blocks = [(w * TQ, width) for w, _ in GATHER_CELLS["kg"][2]]
+    lowered = ops.gather_candidates.lower(
+        [arg(b, jnp.float32) for b in blocks], [arg(b, jnp.int32) for b in blocks],
+        arg((rows,), jnp.int32), arg((2_000,), jnp.int32),
+    )
+    s, v, seg = lowered.out_info
+    assert s.shape == v.shape == (rows, width) and seg.shape == (rows,)
+    hlos.append(lowered.compile().as_text())
+    hlos.append(ops.candidate_ids.lower(
+        arg((2_048, K), jnp.int32), n, arg((n,), jnp.int32)
+    ).compile().as_text())
+    for hlo in hlos:
+        assert "while(" not in hlo and "tpu_custom_call" not in hlo
+        module = hlo.split(None, 2)[1].rstrip(",")
+        assert not is_merge(Op("%fusion = f32[] fusion()", f"{module}(1)", 0.0, 0.0, 0))

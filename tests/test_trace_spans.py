@@ -2,10 +2,13 @@
 
 A traced search names each piece of host work under ``plan.build`` and
 ``plan.execute``: the quantizer probes and the grouping of the plan, and per
-bucket the assembly, the gather, the host->device copies, the readbacks and
-the id remap and scatter. The copies carry the exact bytes they ship, each
-readback is one host sync, and an untraced search builds nothing for any of
-it.
+bucket the assembly, the gather, the host->device copy and the step that
+keeps its results on the device as merge candidates (``merge.scatter``,
+with the rows it placed; one more lays the buffer out for the merge). The
+copies carry the exact bytes they ship, each readback is one host sync, a
+single-device search reads nothing back per bucket (the id remap and
+readback spans are the sharded path's alone), and an untraced search
+builds nothing for the host stages.
 """
 import collections
 import os
@@ -37,15 +40,15 @@ F32_PARENT = {
     "scan.gather": "plan.execute",
     "scan.h2d": "plan.execute",
     "dispatch.scan": "plan.execute",
-    "scan.d2h": "plan.execute",
-    "scan.remap": "plan.execute",
     "merge.scatter": "plan.execute",
     "merge.h2d": "plan.execute",
     "merge.d2h": "plan.execute",
 }
-# the host stages that are spans of their own (copies and readbacks aside)
-HOST_STAGES = {"plan.probe", "plan.group", "scan.assemble", "scan.gather", "scan.remap",
-               "merge.scatter"}
+# the host stages that are spans of their own (copies, readbacks and device
+# dispatches aside)
+HOST_STAGES = {"plan.probe", "plan.group", "scan.assemble", "scan.gather"}
+# spans of the sharded path alone: the host id remap and its readback
+SHARDED_ONLY = ("scan.remap", "scan.d2h")
 # ids shipped to the device: int64 on the host, canonical width on the device
 ID_BYTES = jax.dtypes.canonicalize_dtype(np.int64).itemsize
 
@@ -100,7 +103,11 @@ def test_traced_f32_search_emits_every_span(hqi_and_workload, layout):
     # at most one span per bucket and stage: never one per work unit
     n = collections.Counter(e["name"] for e in events)
     assert n["scan.assemble"] == n["scan.gather"] == n["scan.h2d"] == n["dispatch.scan"]
-    assert n["scan.d2h"] == n["scan.remap"] == n["dispatch.scan"]
+    # each bucket's results stay on the device: one step a bucket and one
+    # gather of the buffer, no readback, no host remap, one small copy
+    assert n["merge.scatter"] == n["dispatch.scan"] + 1
+    assert all(e["args"]["rows"] > 0 for e in events if e["name"] == "merge.scatter")
+    assert not any(n[name] for name in SHARDED_ONLY) and n["merge.h2d"] == 1
     for e in events:
         if e["name"].endswith((".h2d", ".d2h")):
             assert e["args"]["bytes"] > 0
@@ -154,13 +161,13 @@ def test_h2d_bytes_equal_the_plan_shapes(layout):
     for lp, units in plan.buckets.items():
         W = _next_pow2(len(units), 1)
         want_scan += W * 4 + W * plan.tq * 4 + W * lp
+    # the merge buffer is laid out on the device: the host ships only the
+    # block row of each buffer row [C] i32 and, segmented, the segment ends
+    # [m] i32, never the candidates
     if layout == "segmented":
-        # flat scores [C_pad, k] f32, ids [C_pad, k], segment of each row i32
-        c_pad = _next_pow2(int(plan.seg_counts.sum()), 1)
-        want_merge = c_pad * k * (4 + ID_BYTES) + c_pad * 4
+        want_merge = _next_pow2(int(plan.seg_counts.sum()), 1) * 4 + m * 4
     else:
-        width = _next_pow2(plan.n_slots * k, k)
-        want_merge = m * width * (4 + ID_BYTES)
+        want_merge = m * plan.n_slots * 4
     assert got == {"probe.h2d": want_probe, "query.h2d": want_query, "scan.h2d": want_scan,
                    "merge.h2d": want_merge}
 
@@ -169,7 +176,8 @@ def test_h2d_bytes_equal_the_plan_shapes(layout):
 def test_d2h_count_is_probes_buckets_and_merges(layout):
     events, plan, arena, tasks, q, m, k = _run_tiny(layout)
     n = collections.Counter(e["name"] for e in events if e["name"].endswith(".d2h"))
-    assert n == {"probe.d2h": len(tasks), "scan.d2h": len(plan.buckets), "merge.d2h": 1}
+    # one readback per probe and the merged top-k: none per bucket
+    assert n == {"probe.d2h": len(tasks), "merge.d2h": 1}
     # the final top-k read back: scores f32 and ids [m, k]
     (merge,) = [e for e in events if e["name"] == "merge.d2h"]
     assert merge["args"]["bytes"] == m * k * (4 + ID_BYTES)
@@ -215,10 +223,10 @@ def test_arena_rows_upload_once_per_arena():
         hqi.search(wl, nprobe=8, batch_vec=True)
     finally:
         trace.disable()
+    # the rows, then the ids (``device_gid``): once each
     up = [e for e in t.events() if e["name"] == "arena.h2d"]
-    assert len(up) == 1
-    assert up[0]["args"]["bytes"] == hqi.arena.packed.nbytes
-    assert up[0]["args"]["parent"] == "plan.execute"
+    assert [e["args"]["bytes"] for e in up] == [hqi.arena.packed.nbytes, hqi.arena.n * 4]
+    assert {e["args"]["parent"] for e in up} == {"plan.execute"}
     # an insert folds into a new arena (PackedArena.updated): it uploads anew
     old = hqi.arena
     new = db.take(np.arange(9))
@@ -227,8 +235,36 @@ def test_arena_rows_upload_once_per_arena():
         ids=db.n + np.arange(9, dtype=np.int64),
     ))
     assert hqi.arena is not old and hqi.arena.n == old.n + 9
-    assert uploads() == [hqi.arena.packed.nbytes]
+    assert uploads() == [hqi.arena.packed.nbytes, hqi.arena.n * 4]
     assert uploads() == []
+
+
+def test_arena_gid_uploads_once_per_arena():
+    """``device_gid`` puts the arena's ids on the device once, as int32
+    through one ``arena.h2d`` of N·4 bytes; a second traced search uploads
+    no ids, and the pq path, whose stage A keeps packed rows, none at all."""
+    db = small_db(n=2000, seed=6)
+    wl = small_workload(db, n_queries=80)
+    hqi = HQIIndex.build(db, wl, HQIConfig(min_partition_size=256, max_leaves=8))
+    arena = hqi.arena
+    assert arena._gid_dev is None
+    t = trace.enable(capacity=100_000)
+    try:
+        gid = arena.device_gid()
+        assert arena.device_gid() is gid
+    finally:
+        trace.disable()
+    (up,) = [e for e in t.events() if e["name"] == "arena.h2d"]
+    assert up["args"]["bytes"] == arena.n * 4
+    assert gid.dtype == np.int32 and np.array_equal(np.asarray(gid), arena.gid)
+    hqi.search(wl, nprobe=8, batch_vec=True)  # uploads the rows
+    events = traced(lambda: hqi.search(wl, nprobe=8, batch_vec=True))
+    assert not [e for e in events if e["name"] == "arena.h2d"]
+    assert arena.device_gid() is gid
+
+    pq = HQIIndex.build(db, wl, HQIConfig(min_partition_size=256, max_leaves=8, scan_mode="pq"))
+    pq.search(wl, nprobe=8, batch_vec=True)
+    assert pq.arena._codes_dev is not None and pq.arena._gid_dev is None
 
 
 def test_pq_search_emits_the_shared_spans(hqi_and_workload):
@@ -238,11 +274,19 @@ def test_pq_search_emits_the_shared_spans(hqi_and_workload):
     hqi.search(wl, nprobe=8, batch_vec=True)
     events = traced(lambda: hqi.search(wl, nprobe=8, batch_vec=True))
     parents = parent_of(events)
-    for name in ("scan.assemble", "scan.gather", "scan.h2d", "scan.d2h", "scan.remap",
-                 "merge.scatter", "merge.h2d", "merge.d2h", "rerank.gather", "rerank.h2d",
-                 "rerank.d2h", "rerank.remap"):
+    # the final fold after the re-rank still copies its small host buffer
+    # (``merge.h2d``); stage A scatters on the device and reads back once
+    for name in ("scan.assemble", "scan.gather", "scan.h2d", "merge.scatter", "merge.h2d",
+                 "merge.d2h", "rerank.gather", "rerank.h2d", "rerank.d2h", "rerank.remap"):
         assert parents[name] == {"plan.execute"}, (name, parents[name])
     assert parents["probe.d2h"] == {"plan.probe"}
+    assert not any(name in parents for name in SHARDED_ONLY)
+    n = collections.Counter(e["name"] for e in events)
+    scatters = [e["args"]["rows"] for e in events
+                if e["name"] == "merge.scatter" and "rows" in e.get("args", {})]
+    assert len(scatters) == n["dispatch.scan"] + 1 and all(r > 0 for r in scatters)
+    # stage A's top-k' rows, then the final top-k
+    assert n["merge.d2h"] == 2
 
 
 def test_sharded_search_emits_the_shared_spans():
@@ -299,8 +343,9 @@ def test_untraced_search_builds_nothing_for_the_spans(hqi_and_workload, monkeypa
     tracemalloc.stop()
     names = {n for n, _ in opened}
     # the host stages open the shared no-op span, with no arguments built;
-    # copies and readbacks open none at all
-    assert HOST_STAGES <= names
+    # copies and readbacks open none at all (the device scatter's span
+    # carries its row count, as the scan dispatch's carries its shape)
+    assert HOST_STAGES | {"merge.scatter", "dispatch.scan"} <= names
     assert all(not args for n, args in opened if n in HOST_STAGES)
     assert not any(n.endswith((".h2d", ".d2h")) for n in names)
     keep = [tracemalloc.Filter(True, trace.__file__)]
